@@ -107,7 +107,7 @@ def _throughput_row() -> dict:
     tok = jnp.zeros((B, 1), jnp.int32)
 
     fused = jax.jit(lambda t, s: M.resident_decode_step(
-        params, t, s, cfg, interpret=True)[0])
+        params, t, s, cfg)[0])
 
     def rehydrated_step(t, s):
         cache = pool.rehydrate(s)                  # full-cache decompress

@@ -47,6 +47,8 @@ def main() -> None:
                     help="comma-separated module substrings to run")
     args = ap.parse_args()
     only = [s for s in args.only.split(",") if s]
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     rows = []
 

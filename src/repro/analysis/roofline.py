@@ -55,7 +55,9 @@ def collective_bytes_from_hlo(hlo_text: str) -> Dict[str, int]:
     out = {k: 0 for k in _COLLECTIVES}
     for line in hlo_text.splitlines():
         ls = line.strip()
-        m = re.search(r"=\s*\S*\s*(" + "|".join(_COLLECTIVES) + r")(-start|-done)?\(", ls)
+        # the result may be a tuple with spaces (TPU async ops): the op name
+        # is the first collective token after '=' that opens a call
+        m = re.search(r"=.*?\s(" + "|".join(_COLLECTIVES) + r")(-start|-done)?\(", ls)
         if not m:
             continue
         kind = m.group(1)

@@ -11,7 +11,8 @@ is cross-checked against the host wire codec in tests.
 Layout for a tensor of N elements (N padded to a chunk multiple):
 
   sign_mantissa : u8[N]              exact `a_i` bytes (dense stream 1)
-  packed        : u8[N//2]           two 4-bit codes per byte (dense stream 2)
+  packed        : u8[N//2]           two 4-bit codes per byte (dense stream 2;
+                                     chunk halves share a byte)
   esc_pos       : u16[C, cap]        chunk-relative escape positions
   esc_val       : u8[C, cap]         raw escaped exponents
   esc_count     : i32[C]             true escapes per chunk (may exceed cap)
@@ -146,17 +147,18 @@ def decode_codes(code: jax.Array, exponents: tuple) -> jax.Array:
     return jnp.sum(onehot.astype(jnp.uint8) * cb, axis=-1)
 
 
-def pack_nibbles(code: jax.Array) -> jax.Array:
-    """[N] 4-bit codes -> [N//2] bytes; element 2i low nibble, 2i+1 high."""
-    lo = code[0::2].astype(jnp.uint8)
-    hi = code[1::2].astype(jnp.uint8)
-    return lo | (hi << 4)
+def pack_nibbles(code: jax.Array, chunk: int) -> jax.Array:
+    """[N] 4-bit codes -> [N//2] bytes, chunk by chunk: element ``c`` of a
+    chunk in the low nibble of byte ``c``, element ``c + chunk/2`` in the
+    high one.  Both halves are lane-aligned on the TPU, so the kernels pack
+    and unpack without a lane shuffle (docs/wire_format.md §2)."""
+    halves = code.astype(jnp.uint8).reshape(-1, 2, chunk // 2)
+    return (halves[:, 0] | (halves[:, 1] << 4)).reshape(-1)
 
 
-def unpack_nibbles(packed: jax.Array) -> jax.Array:
-    lo = packed & 0xF
-    hi = packed >> 4
-    return jnp.stack([lo, hi], axis=-1).reshape(-1)
+def unpack_nibbles(packed: jax.Array, chunk: int) -> jax.Array:
+    p = packed.reshape(-1, chunk // 2)
+    return jnp.concatenate([p & 0xF, p >> 4], axis=-1).reshape(-1)
 
 
 # ---------------------------------------------------------------------------
@@ -334,7 +336,7 @@ def encode(
 
     e, a = split_fields(bits, fmt)
     code, member = assign_codes(e, codebook.exponents)
-    packed = pack_nibbles(code) if codebook.k <= 16 else code
+    packed = pack_nibbles(code, chunk) if codebook.k <= 16 else code
     if layout == "global":
         cap = default_global_cap(bits.shape[0]) if cap == DEFAULT_CAP else cap
         esc_pos, esc_val, esc_count, ok = collect_escapes_global(e, member, cap)
@@ -362,7 +364,8 @@ def decode_to_bits(ct: CompressedTensor) -> jax.Array:
     dense unpack + LUT + reassemble, then sparse overwrite.  The transfer
     engine consumes bits directly (it ships bit streams); ``decode`` adds
     only the reshape + bitcast back to the original dtype."""
-    code = unpack_nibbles(ct.packed) if len(ct.exponents) <= 16 else ct.packed
+    code = (unpack_nibbles(ct.packed, ct.chunk) if len(ct.exponents) <= 16
+            else ct.packed)
     e = decode_codes(code, ct.exponents)
     if ct.layout == "global":
         e = scatter_escapes_global(e, ct.esc_pos, ct.esc_val)
@@ -468,7 +471,7 @@ def encode_sentinel(
     e, a = split_fields(bits, fmt)
     code, member = assign_codes(e, exps)
     code = jnp.where(member, code, jnp.uint8(SENTINEL))
-    packed = pack_nibbles(code)
+    packed = pack_nibbles(code, chunk)
     # values-only compaction, occurrence order per chunk
     _, esc_val, esc_count, ok = collect_escapes(e, member, chunk, cap)
     return SentinelCompressed(
@@ -482,7 +485,7 @@ def decode_sentinel(ct: SentinelCompressed) -> jax.Array:
     """Irregular decode path: every element must inspect the code stream for
     the sentinel, rank sentinels per chunk, and gather from the value stream.
     This models the paper's measured 3.5× decode slowdown structurally."""
-    code = unpack_nibbles(ct.packed)
+    code = unpack_nibbles(ct.packed, ct.chunk)
     is_esc = code == SENTINEL
     e = decode_codes(jnp.where(is_esc, 0, code), ct.exponents)
     c = ct.esc_val.shape[0]
@@ -538,7 +541,7 @@ def encode_with_dynamic_codebook(
     eq = e[..., None] == cb
     member = jnp.any(eq, axis=-1)
     code = jnp.sum(eq.astype(jnp.uint8) * jnp.arange(k, dtype=jnp.uint8), axis=-1)
-    packed = pack_nibbles(code)
+    packed = pack_nibbles(code, chunk)
     esc_pos, esc_val, esc_count, ok = collect_escapes(e, member, chunk, cap)
     return (a, packed, esc_pos, esc_val, esc_count, ok), cb
 
@@ -546,7 +549,7 @@ def encode_with_dynamic_codebook(
 def decode_with_dynamic_codebook(streams, cb, shape, dtype, fmt="bf16",
                                  chunk: int = DEFAULT_CHUNK):
     a, packed, esc_pos, esc_val, esc_count, ok = streams
-    code = unpack_nibbles(packed)
+    code = unpack_nibbles(packed, chunk)
     k = cb.shape[0]
     onehot = code[..., None] == jnp.arange(k, dtype=code.dtype)
     e = jnp.sum(onehot.astype(jnp.uint8) * cb, axis=-1)

@@ -1,3 +1,10 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels: the SplitZip codec and attention over compressed
+KV pages.  Every entry point takes ``interpret=None``, which compiles the
+kernel to Mosaic on a TPU and runs it in the Pallas interpreter elsewhere."""
+
+import jax
+
+
+def auto_interpret(interpret: bool | None) -> bool:
+    """Resolve ``interpret=None`` by platform: compiled on a TPU only."""
+    return jax.default_backend() != "tpu" if interpret is None else interpret

@@ -33,6 +33,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import auto_interpret
+
 DEFAULT_BLK_Q = 256
 DEFAULT_BLK_K = 512
 NEG_INF = -1e30
@@ -97,7 +99,7 @@ def flash_attention(
     scale: Optional[float] = None,
     blk_q: int = DEFAULT_BLK_Q,
     blk_k: int = DEFAULT_BLK_K,
-    interpret: bool = True,        # Mosaic on TPU; Python semantics on CPU
+    interpret: bool | None = None,  # None: Mosaic on TPU, interpreted elsewhere
 ) -> jax.Array:
     """Fused multi-head attention; value head dim may differ (MLA)."""
     b, sq, h, d = q.shape
@@ -142,7 +144,7 @@ def flash_attention(
             pltpu.VMEM((blk_q, 1), jnp.float32),   # l: running denominator
             pltpu.VMEM((blk_q, dv), jnp.float32),  # acc: running numerator
         ],
-        interpret=interpret,
+        interpret=auto_interpret(interpret),
     )(qh, kh, vh)
 
     out = out[:, :sq].reshape(b, h, sq, dv).transpose(0, 2, 1, 3)

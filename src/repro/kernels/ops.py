@@ -8,8 +8,8 @@ the dense kernel and emits final container bits — no post-kernel full-stream
 pass (field re-extract, cumsum, scatter, join) remains on either side.  The
 pre-fusion structure survives in :mod:`repro.kernels.twostage` for A/B
 comparison (``PallasBackend(fused=False)``) and for escape capacities above
-``MAX_FUSED_CAP``, where unrolling the in-kernel compaction loop would
-dominate the kernel.
+``MAX_FUSED_CAP``, where the in-kernel compaction passes would dominate
+the kernel.
 
 On non-TPU backends the kernels run in ``interpret=True`` mode (Python
 semantics of the kernel body), which is how this repo validates them on CPU;
@@ -40,15 +40,7 @@ import numpy as np
 from repro.core import codec as core_codec
 from repro.core.codebook import FORMATS, Codebook
 from repro.kernels import splitzip_decode, splitzip_encode, twostage
-from repro.kernels.splitzip_encode import MAX_FUSED_CAP, fit_block_rows
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _auto_interpret(interpret):
-    return (not _on_tpu()) if interpret is None else interpret
+from repro.kernels.splitzip_encode import MAX_FUSED_CAP
 
 
 def encode(
@@ -62,12 +54,11 @@ def encode(
     fused: bool = True,
 ) -> core_codec.CompressedTensor:
     """SplitZip encode with the single-pass fused Pallas kernel."""
-    interp = _auto_interpret(interpret)
     if not fused or (layout != "global" and cap > MAX_FUSED_CAP):
-        # two-stage A/B path, or a capacity too large to unroll in-kernel
+        # two-stage A/B path, or a capacity too large to compact in-kernel
         return twostage.encode(x, codebook, chunk=chunk, cap=cap,
                                layout=layout, block_rows=block_rows,
-                               interpret=interp)
+                               interpret=interpret)
     fmt = codebook.fmt
     orig_shape, orig_dtype = x.shape, x.dtype
     bits = core_codec.to_bits(x, fmt).reshape(-1)
@@ -84,8 +75,8 @@ def encode(
         fmt=fmt,
         chunk=chunk,
         cap=kcap,
-        block_rows=fit_block_rows(rows, block_rows),
-        interpret=interp,
+        block_rows=block_rows,
+        interpret=interpret,
     )
     esc_count = cnt.reshape(-1)
     if layout == "global":
@@ -145,15 +136,13 @@ def decode_bits(
     fused: bool = True,
 ) -> jax.Array:
     """Fused decode to the FLAT container bit stream (length n_elements)."""
-    interp = _auto_interpret(interpret)
     if not fused:
         return twostage.decode_to_bits(ct, block_rows=block_rows,
-                                       interpret=interp)
+                                       interpret=interpret)
     chunk = ct.chunk
     rows = ct.n_padded // chunk
     packed2 = ct.packed.reshape(rows, chunk // 2)
     a2 = ct.sign_mantissa.reshape(rows, chunk)
-    br = fit_block_rows(rows, block_rows)
     if ct.layout == "chunked" and ct.cap <= MAX_FUSED_CAP:
         # fully fused: the kernel applies the sparse correction and emits
         # final bits; the clipped per-row counts bound its slot loop
@@ -161,11 +150,11 @@ def decode_bits(
         bits2 = splitzip_decode.decode_fused(
             packed2, a2, ct.esc_pos, ct.esc_val, cnt.reshape(rows, 1),
             tuple(ct.exponents), fmt=ct.fmt, chunk=chunk,
-            block_rows=br, interpret=interp)
+            block_rows=block_rows, interpret=interpret)
         return bits2.reshape(-1)[:ct.n_elements]
     bits2 = splitzip_decode.decode_dense(
         packed2, a2, tuple(ct.exponents), fmt=ct.fmt, chunk=chunk,
-        block_rows=br, interpret=interp)
+        block_rows=block_rows, interpret=interpret)
     bits = _patch_escape_bits(bits2.reshape(-1), ct)
     return bits[:ct.n_elements]
 

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.codebook import FORMATS
+from repro.core.codec import pack_nibbles, unpack_nibbles
 
 
 def encode_dense_ref(bits: jax.Array, exponents: tuple, fmt: str = "bf16"):
@@ -27,8 +28,7 @@ def encode_dense_ref(bits: jax.Array, exponents: tuple, fmt: str = "bf16"):
     code = jnp.sum(eq.astype(jnp.int32) * jnp.arange(len(exponents)), axis=-1)
 
     r, c = code.shape
-    pairs = code.reshape(r, c // 2, 2)
-    packed = (pairs[..., 0] | (pairs[..., 1] << 4)).astype(jnp.uint8)
+    packed = pack_nibbles(code, c).reshape(r, c // 2)
     return a.astype(jnp.uint8), packed, (~member).astype(jnp.uint8)
 
 
@@ -37,11 +37,9 @@ def decode_dense_ref(packed: jax.Array, sign_mantissa: jax.Array,
     """(rows, chunk//2) packed + (rows, chunk) sign-mantissa -> container bits."""
     spec = FORMATS[fmt]
     mbits, width = spec["mbits"], spec["bits"]
-    p = packed.astype(jnp.int32)
     a = sign_mantissa.astype(jnp.int32)
-    lo, hi = p & 0xF, (p >> 4) & 0xF
-    r, half = p.shape
-    code = jnp.stack([lo, hi], axis=-1).reshape(r, half * 2)
+    code = unpack_nibbles(packed.astype(jnp.uint8), a.shape[1]).reshape(
+        a.shape).astype(jnp.int32)
     cb = jnp.asarray(exponents, dtype=jnp.int32)
     onehot = code[..., None] == jnp.arange(len(exponents))
     e = jnp.sum(onehot.astype(jnp.int32) * cb, axis=-1)

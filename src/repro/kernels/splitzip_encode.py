@@ -26,20 +26,26 @@ In-kernel escape compaction (the fused stage 2) is gather/scatter-free:
            fewer than j+1 escapes contributes an empty mask, so the slot
            naturally lands on the padding convention (pos == chunk, val == 0).
 
-The slot loop is statically unrolled to ``cap`` iterations but predicated by
-``pl.when(j < max escape count in this block)``: at the paper's escape rates
-(ε ≈ 0.16%, ~2 escapes per 1024-chunk) only a handful of slots execute, so
-the fused stage adds ~O(blockmax) VPU passes — comparable to the 16-compare
-dense stage — instead of cap passes.  Capacities above ``MAX_FUSED_CAP`` are
-not fused (the unroll would dominate); :mod:`repro.kernels.ops` routes those
-to the two-stage path.
+The slot loop runs up to the block's largest escape count (at most ``cap``):
+at the paper's escape rates (ε ≈ 0.16%, ~2 escapes per 1024-chunk) only a
+handful of slots execute, so the fused stage adds ~O(blockmax) VPU passes —
+comparable to the 16-compare dense stage — instead of cap passes.
+Capacities above ``MAX_FUSED_CAP`` are not fused (the slot passes would
+dominate); :mod:`repro.kernels.ops` routes those to the two-stage path.
+
+Nibble packing puts element ``c`` of a chunk row in the low nibble and
+element ``c + chunk/2`` in the high one: both are lane-aligned halves of the
+tile, which Mosaic packs with plain VPU ops (an adjacent-pair pack needs a
+lane shuffle Mosaic cannot express).
 
 Tiling: the flat bit stream is viewed as (rows, CHUNK) with CHUNK = the
 escape-chunk size (1024 = 8 sublanes × 128 lanes, hardware-aligned).  Each
-grid step processes BLOCK_ROWS rows; with BLOCK_ROWS = 256 the working set is
+grid step processes BLOCK_ROWS rows (a multiple of 32, the u8 sublane tile;
+the last step may be partial); with BLOCK_ROWS = 256 the working set is
   in  : 256×1024×4B (i32 upcast of the u16 bits)     = 1.0 MiB
   out : a (1B) + packed (0.5B) + escapes (~3B·cap/chunk) = 0.6 MiB
-comfortably inside a v5e core's ~16 MiB VMEM with double buffering.
+inside the 16 MiB of VMEM a v5e kernel may use by default, with double
+buffering.
 """
 
 from __future__ import annotations
@@ -51,21 +57,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.codebook import FORMATS
+from repro.kernels import auto_interpret
 
 DEFAULT_BLOCK_ROWS = 256
 
-#: Largest per-chunk escape capacity the fused kernel will unroll; above
-#: this the compaction loop would dominate the kernel and the two-stage
+#: Largest per-chunk escape capacity the fused kernel compacts; above
+#: this the compaction passes would dominate the kernel and the two-stage
 #: path wins (see kernels/ops.py dispatch).
 MAX_FUSED_CAP = 128
 
 
+#: Rows of a grid step are a multiple of this: the kernels write ``uint8``
+#: blocks, which the TPU tiles in (32, 128) slabs.
+ROW_ALIGN = 32
+
+
 def fit_block_rows(rows: int, want: int) -> int:
-    """Largest divisor of ``rows`` that is <= want (grid must tile exactly)."""
-    br = min(want, rows)
-    while rows % br:
-        br -= 1
-    return max(br, 1)
+    """Rows per grid step: ``want`` rounded down to :data:`ROW_ALIGN`, or
+    all of ``rows`` when that is no more.  The grid is ``cdiv(rows, br)``;
+    the last step may be partial (its out-of-range rows are never written)."""
+    br = max(ROW_ALIGN, want // ROW_ALIGN * ROW_ALIGN)
+    return rows if rows <= br else br
 
 
 def _split_and_code(x, *, exponents, mbits, ebits):
@@ -81,11 +93,11 @@ def _split_and_code(x, *, exponents, mbits, ebits):
     return e, a, code, member
 
 
-def _pack_pairs(code):
-    """Pack two 4-bit codes per byte: (R, C) -> (R, C//2, 2) -> lo | hi<<4."""
-    r, c = code.shape
-    pairs = code.reshape(r, c // 2, 2)
-    return (pairs[..., 0] | (pairs[..., 1] << 4)).astype(jnp.uint8)
+def _pack_halves(code):
+    """Pack two 4-bit codes per byte: element ``c`` of a chunk row in the low
+    nibble, element ``c + chunk/2`` in the high one (lane-aligned halves)."""
+    half = code.shape[1] // 2
+    return (code[:, :half] | (code[:, half:] << 4)).astype(jnp.uint8)
 
 
 def _inclusive_cumsum_lanes(x, chunk):
@@ -108,7 +120,7 @@ def _encode_kernel(bits_ref, a_ref, packed_ref, esc_ref, *, exponents, mbits, eb
         x, exponents=exponents, mbits=mbits, ebits=ebits)
     a_ref[...] = a.astype(jnp.uint8)
     esc_ref[...] = (~member).astype(jnp.uint8)
-    packed_ref[...] = _pack_pairs(code)
+    packed_ref[...] = _pack_halves(code)
 
 
 def _encode_fused_kernel(
@@ -119,33 +131,35 @@ def _encode_fused_kernel(
     e, a, code, member = _split_and_code(
         x, exponents=exponents, mbits=mbits, ebits=ebits)
     a_ref[...] = a.astype(jnp.uint8)
-    packed_ref[...] = _pack_pairs(code)
+    packed_ref[...] = _pack_halves(code)
 
     # ---- fused stage 2: per-row escape compaction, gather/scatter-free ----
     r = x.shape[0]
     is_esc = (~member).astype(jnp.int32)
     s = _inclusive_cumsum_lanes(is_esc, chunk)      # rank+1 at each escape
     count = s[:, chunk - 1:chunk]                   # (r, 1) TRUE per-row count
-    esc_cnt_ref[...] = count.astype(jnp.int32)
+    esc_cnt_ref[...] = count
     se = s * is_esc                                 # 0 off-escape, rank+1 on
 
-    # padding convention first (pos == chunk -> dropped on decode, val == 0);
-    # slots j >= the block's max count keep it without executing their pass
-    esc_pos_ref[...] = jnp.full((r, cap), chunk, dtype=jnp.uint16)
-    esc_val_ref[...] = jnp.zeros((r, cap), dtype=jnp.uint8)
-
-    blockmax = jnp.max(count)
     # chunk - c per lane: one masked reduction gives both the position and
     # the padding fallback (empty mask -> pos = chunk) without a gather
     wpos = chunk - jax.lax.broadcasted_iota(jnp.int32, (r, chunk), 1)
-    for j in range(cap):  # static unroll; predicated off beyond blockmax
-        @pl.when(j < blockmax)
-        def _(j=j):
-            m = (se == j + 1).astype(jnp.int32)
-            pos_j = chunk - jnp.sum(wpos * m, axis=-1, keepdims=True)
-            val_j = jnp.sum(e * m, axis=-1, keepdims=True)
-            esc_pos_ref[:, j:j + 1] = pos_j.astype(jnp.uint16)
-            esc_val_ref[:, j:j + 1] = val_j.astype(jnp.uint8)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (r, cap), 1)
+
+    def fill_slot(j, carry):
+        pos, val = carry
+        m = (se == j + 1).astype(jnp.int32)
+        pos_j = chunk - jnp.sum(wpos * m, axis=-1, keepdims=True)
+        val_j = jnp.sum(e * m, axis=-1, keepdims=True)
+        return (jnp.where(slot == j, pos_j, pos), jnp.where(slot == j, val_j, val))
+
+    # padding convention first (pos == chunk -> dropped on decode, val == 0);
+    # only slots below the block's largest count run a pass
+    pos, val = jax.lax.fori_loop(
+        0, jnp.minimum(jnp.max(count), cap), fill_slot,
+        (jnp.full((r, cap), chunk, jnp.int32), jnp.zeros((r, cap), jnp.int32)))
+    esc_pos_ref[...] = pos.astype(jnp.uint16)
+    esc_val_ref[...] = val.astype(jnp.uint8)
 
 
 @functools.partial(
@@ -157,7 +171,7 @@ def encode_dense(
     fmt: str = "bf16",
     chunk: int = 1024,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Dense-only encode of a (rows, chunk) bit tensor (two-stage A/B path).
 
@@ -168,10 +182,8 @@ def encode_dense(
     rows, c = bits.shape
     if c != chunk:
         raise ValueError(f"expected trailing dim == chunk ({chunk}), got {c}")
-    br = min(block_rows, rows)
-    if rows % br:
-        raise ValueError(f"block_rows ({br}) must divide rows ({rows})")
-    grid = (rows // br,)
+    br = fit_block_rows(rows, block_rows)
+    grid = (pl.cdiv(rows, br),)
     kernel = functools.partial(
         _encode_kernel,
         exponents=tuple(int(e) for e in exponents),
@@ -192,7 +204,7 @@ def encode_dense(
             jax.ShapeDtypeStruct((rows, chunk // 2), jnp.uint8),
             jax.ShapeDtypeStruct((rows, chunk), jnp.uint8),
         ],
-        interpret=interpret,
+        interpret=auto_interpret(interpret),
     )(bits)
 
 
@@ -207,7 +219,7 @@ def encode_fused(
     chunk: int = 1024,
     cap: int = 64,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Single-pass fused encode of a (rows, chunk) bit tensor.
 
@@ -226,10 +238,8 @@ def encode_fused(
         raise ValueError(
             f"cap ({cap}) exceeds MAX_FUSED_CAP ({MAX_FUSED_CAP}); use the "
             "two-stage path (repro.kernels.twostage) for oversized capacities")
-    br = min(block_rows, rows)
-    if rows % br:
-        raise ValueError(f"block_rows ({br}) must divide rows ({rows})")
-    grid = (rows // br,)
+    br = fit_block_rows(rows, block_rows)
+    grid = (pl.cdiv(rows, br),)
     kernel = functools.partial(
         _encode_fused_kernel,
         exponents=tuple(int(e) for e in exponents),
@@ -256,5 +266,5 @@ def encode_fused(
             jax.ShapeDtypeStruct((rows, cap), jnp.uint8),
             jax.ShapeDtypeStruct((rows, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=auto_interpret(interpret),
     )(bits)

@@ -6,8 +6,8 @@ over the full stream (the paper's literal two-stage description).  It is kept
 for A/B benchmarking against the fused single-pass path
 (:mod:`repro.kernels.ops`, ``PallasBackend(fused=False)``) and as the
 dispatch target for escape capacities above
-:data:`repro.kernels.splitzip_encode.MAX_FUSED_CAP`, where unrolling the
-in-kernel compaction loop would dominate the kernel.
+:data:`repro.kernels.splitzip_encode.MAX_FUSED_CAP`, where the in-kernel
+compaction passes would dominate the kernel.
 
 Cost model (why the fused path exists): per codec call this path re-reads
 the full bit stream to re-derive the exponent field (encode: ``split_fields``
@@ -26,7 +26,6 @@ import numpy as np
 from repro.core import codec as core_codec
 from repro.core.codebook import FORMATS, Codebook
 from repro.kernels import splitzip_decode, splitzip_encode
-from repro.kernels.splitzip_encode import fit_block_rows
 
 
 def encode(
@@ -36,7 +35,7 @@ def encode(
     cap: int = core_codec.DEFAULT_CAP,
     layout: str = "chunked",
     block_rows: int = splitzip_encode.DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> core_codec.CompressedTensor:
     """Two-stage encode: Pallas dense kernel + XLA escape collection."""
     fmt = codebook.fmt
@@ -53,7 +52,7 @@ def encode(
         tuple(codebook.exponents),
         fmt=fmt,
         chunk=chunk,
-        block_rows=fit_block_rows(rows, block_rows),
+        block_rows=block_rows,
         interpret=interpret,
     )
     # stage 2 (XLA): full-stream field re-extract + cumsum + bounded scatter
@@ -87,7 +86,7 @@ def encode(
 def decode_to_bits(
     ct: core_codec.CompressedTensor,
     block_rows: int = splitzip_decode.DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Two-stage decode to flat bits: dense kernel + XLA sparse correction."""
     chunk = ct.chunk
@@ -100,7 +99,7 @@ def decode_to_bits(
         tuple(ct.exponents),
         fmt=ct.fmt,
         chunk=chunk,
-        block_rows=fit_block_rows(rows, block_rows),
+        block_rows=block_rows,
         interpret=interpret,
     )
     # stage 2 (XLA): re-extract the exponent field over the full stream,
@@ -120,7 +119,7 @@ def decode_to_bits(
 def decode(
     ct: core_codec.CompressedTensor,
     block_rows: int = splitzip_decode.DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Two-stage decode: dense Pallas kernel + XLA sparse correction."""
     bits = decode_to_bits(ct, block_rows=block_rows, interpret=interpret)

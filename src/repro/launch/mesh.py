@@ -13,17 +13,25 @@ over DCN.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (elastic replans, tests on small device counts)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh (elastic replans, tests on small device counts).
+
+    Every axis is ``Auto``: shardings are constraints the compiler
+    propagates (``with_sharding_constraint``, ``shard_map`` specs), which
+    is what the sharding policy and the transfer plans are written for.
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, which type every array
+    by its sharding and refuse both."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def describe(mesh) -> str:

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.configs.base import ShapeConfig, get_config
 from repro.distributed import checkpoint as CKPT
 from repro.distributed.sharding import ShardingPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.training import grad_compress as GC
 from repro.training import optimizer as OPT
@@ -43,6 +44,7 @@ def main(argv=None):
     ap.add_argument("--mesh", default="", help="e.g. '2,2' => data=2,model=2")
     ap.add_argument("--log-every", type=int, default=1)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

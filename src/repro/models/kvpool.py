@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.codebook import FORMATS, Codebook
+from repro.core import codec as C
 from repro.core.backend import CodecBackend
 
 # Default raw-payload bytes per page per leaf.  32 KiB ≅ 128 tokens for the
@@ -140,8 +141,8 @@ class PagedLeaf:
 
     sign_mantissa: jax.Array   # u8 (n_pages, page_chunks, chunk)
     packed: jax.Array          # u8 (n_pages, page_chunks, chunk // 2)
-    esc_pos: jax.Array         # u16 (n_pages, escape_cap), pad = page_elems
-    esc_val: jax.Array         # u8 (n_pages, escape_cap)
+    esc_pos: jax.Array         # u16 (n_pages, 1, escape_cap), pad = page_elems
+    esc_val: jax.Array         # u8 (n_pages, 1, escape_cap)
     esc_cnt: jax.Array         # i32 (n_pages, 1)
     page_table: jax.Array      # i32 (L, B, P)
     tail: jax.Array            # dtype (L, B, tokens_per_page, m)
@@ -236,10 +237,8 @@ def _decode_pool_pages(leaf: PagedLeaf, lg: LeafGeometry,
     mbits, bits_width = spec["mbits"], spec["bits"]
     npg = leaf.sign_mantissa.shape[0]
     pe = lg.page_elems
-    packed = leaf.packed.reshape(npg, pe // 2).astype(jnp.int32)
-    lo = packed & 0xF
-    hi = (packed >> 4) & 0xF
-    code = jnp.stack([lo, hi], axis=-1).reshape(npg, pe)
+    code = C.unpack_nibbles(leaf.packed, geom.chunk).reshape(npg, pe).astype(
+        jnp.int32)
     e = jnp.zeros_like(code)
     for i, exp in enumerate(geom.exponents):
         e = jnp.where(code == i, exp, e)
@@ -249,14 +248,14 @@ def _decode_pool_pages(leaf: PagedLeaf, lg: LeafGeometry,
     # patch page-level escapes
     keep = ((1 << bits_width) - 1) ^ (((1 << (bits_width - mbits - 1)) - 1)
                                       << mbits)
-    cap = leaf.esc_pos.shape[1]
+    cap = leaf.esc_pos.shape[-1]
     slot = jnp.arange(cap)
-    pos = leaf.esc_pos.astype(jnp.int32)
+    pos = leaf.esc_pos[:, 0].astype(jnp.int32)
     occupied = slot[None, :] < leaf.esc_cnt            # (npg, cap)
     pos = jnp.where(occupied, pos, pe)
     rows = jnp.broadcast_to(jnp.arange(npg)[:, None], pos.shape)
     old = jnp.take_along_axis(bits, jnp.minimum(pos, pe - 1), axis=1)
-    new = (old & keep) | (leaf.esc_val.astype(jnp.int32) << mbits)
+    new = (old & keep) | (leaf.esc_val[:, 0].astype(jnp.int32) << mbits)
     return bits.at[rows, pos].set(jnp.where(occupied, new, 0), mode="drop")
 
 
@@ -340,9 +339,9 @@ class KVPool:
                                     jnp.uint8),
             packed=jnp.zeros((lg.n_pages, lg.page_chunks, g.chunk // 2),
                              jnp.uint8),
-            esc_pos=jnp.full((lg.n_pages, lg.escape_cap), lg.page_elems,
+            esc_pos=jnp.full((lg.n_pages, 1, lg.escape_cap), lg.page_elems,
                              jnp.uint16),
-            esc_val=jnp.zeros((lg.n_pages, lg.escape_cap), jnp.uint8),
+            esc_val=jnp.zeros((lg.n_pages, 1, lg.escape_cap), jnp.uint8),
             esc_cnt=jnp.zeros((lg.n_pages, 1), jnp.int32),
             page_table=jnp.full((g.n_layers, g.batch, g.max_pages), -1,
                                 jnp.int32),
@@ -439,8 +438,10 @@ class KVPool:
                 sign_mantissa=leaf.sign_mantissa.at[pids].set(
                     sm[idx_l, idx_b, idx_p]),
                 packed=leaf.packed.at[pids].set(packed[idx_l, idx_b, idx_p]),
-                esc_pos=leaf.esc_pos.at[pids].set(pos_pg[idx_l, idx_b, idx_p]),
-                esc_val=leaf.esc_val.at[pids].set(val_pg[idx_l, idx_b, idx_p]),
+                esc_pos=leaf.esc_pos.at[pids, 0].set(
+                    pos_pg[idx_l, idx_b, idx_p]),
+                esc_val=leaf.esc_val.at[pids, 0].set(
+                    val_pg[idx_l, idx_b, idx_p]),
                 esc_cnt=leaf.esc_cnt.at[pids, 0].set(
                     cnt_pg[idx_l, idx_b, idx_p]),
                 page_table=leaf.page_table.at[idx_l, idx_b, idx_p].set(pids))
@@ -455,7 +456,6 @@ class KVPool:
                           n_full: np.ndarray) -> jax.Array:
         """Gather each (layer, row)'s tail page-group chunks into a small
         CompressedTensor and decode it through the registered backend."""
-        import repro.core.codec as C  # host path; step path never does this
         g = self.geom
         L_, B = g.n_layers, g.batch
         pc, chunk = lg.page_chunks, g.chunk
@@ -542,8 +542,8 @@ class KVPool:
                     sign_mantissa=leaf.sign_mantissa.at[pids].set(
                         sm[idx_l, idx_b]),
                     packed=leaf.packed.at[pids].set(packed[idx_l, idx_b]),
-                    esc_pos=leaf.esc_pos.at[pids].set(pos_pg[idx_l, idx_b]),
-                    esc_val=leaf.esc_val.at[pids].set(val_pg[idx_l, idx_b]),
+                    esc_pos=leaf.esc_pos.at[pids, 0].set(pos_pg[idx_l, idx_b]),
+                    esc_val=leaf.esc_val.at[pids, 0].set(val_pg[idx_l, idx_b]),
                     esc_cnt=leaf.esc_cnt.at[pids, 0].set(
                         cnt_pg[idx_l, idx_b]),
                     page_table=leaf.page_table.at[idx_l, idx_b, idx_p].set(
@@ -667,7 +667,7 @@ def _append_tail(tail: jax.Array, new: jax.Array, t: jax.Array) -> jax.Array:
 def paged_decode_attention_block(p, x, k_streams, v_streams, pt_k, pt_v,
                                  tail_k, tail_v, cache_len, theta, *,
                                  geom: PoolGeometry, fmt: str = "bf16",
-                                 interpret: bool = True):
+                                 interpret: bool | None = None):
     """Mirror of ``layers.decode_attention_block`` with a compressed prefix.
 
     The prefix (``cache_len // Tp`` full pages) is attended by the fused
@@ -720,7 +720,7 @@ def paged_decode_attention_block(p, x, k_streams, v_streams, pt_k, pt_v,
 def paged_mla_decode(p, x, ckv_streams, kr_streams, pt_c, pt_r,
                      tail_c, tail_r, cache_len, cfg, theta, *,
                      geom: PoolGeometry, fmt: str = "bf16",
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Mirror of ``mla.mla_decode`` over compressed latent pages.
 
     Scores/context run in the latent space inside the kernel (absorbed

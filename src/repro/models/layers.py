@@ -178,9 +178,12 @@ def decode_attention(
     if window is not None:
         valid &= pos[None, :] >= (cache_len[:, None] - window)
     sc = jnp.where(valid[:, None, None, None, :], sc, NEG_INF)
-    p = jax.nn.softmax(sc, axis=-1)
+    # softmax in the flash form of ``attention``: un-normalized
+    # probabilities meet V in its dtype, the sum divides after the matmul
+    p = jnp.exp(sc - sc.max(axis=-1, keepdims=True))
     out = jnp.einsum("bqhgk,bkhd->bqhgd", p.astype(v_cache.dtype), v_cache,
                      preferred_element_type=jnp.float32)
+    out = out / p.sum(axis=-1)[..., None]
     return out.reshape(b, sq, h, dv).astype(q.dtype)
 
 

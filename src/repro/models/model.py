@@ -480,7 +480,7 @@ def decode_step(params, tokens: jax.Array, state: DecodeState, cfg: ArchConfig
 
 
 def resident_decode_step(params, tokens: jax.Array, state, cfg: ArchConfig,
-                         *, interpret: bool = True):
+                         *, interpret: bool | None = None):
     """One autoregressive step over a compressed-resident cache.
 
     ``state`` is a ``kvpool.ResidentState``: the prefix lives as splitzip

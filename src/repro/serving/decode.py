@@ -47,9 +47,14 @@ def make_decode_fn(cfg: ArchConfig, num_steps: int):
     return fn
 
 
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _resident_step(params, tok, state, cfg, interpret):
+    return M.resident_decode_step(params, tok, state, cfg, interpret=interpret)
+
+
 def resident_decode_loop(params, first_token: jax.Array, state, pool,
                          cfg: ArchConfig, num_steps: int, *,
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     """Greedy generation over a compressed-resident cache.
 
     A Python loop of one reused jitted step (page tables and tails are
@@ -65,15 +70,11 @@ def resident_decode_loop(params, first_token: jax.Array, state, pool,
     final_state, demoted)``."""
     from repro.models.kvpool import ResidencyError
 
-    @jax.jit
-    def step_fn(p, tok, st):
-        return M.resident_decode_step(p, tok, st, cfg, interpret=interpret)
-
     tok = first_token
     toks = []
     st = state
     for i in range(num_steps):
-        logits, st = step_fn(params, tok[:, None], st)
+        logits, st = _resident_step(params, tok[:, None], st, cfg, interpret)
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         toks.append(tok)
         try:

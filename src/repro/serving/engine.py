@@ -38,8 +38,8 @@ from repro.models.kvcache import DecodeState, cache_bytes
 from repro.serving import transfer as T
 from repro.serving.plan import TransferPlan
 from repro.serving.session import TransferSession
-from repro.serving.decode import decode_loop
-from repro.serving.prefill import prefill_step
+from repro.serving.decode import make_decode_fn
+from repro.serving.prefill import make_prefill_fn
 
 
 @dataclasses.dataclass
@@ -160,6 +160,16 @@ class DisaggregatedEngine:
         self.stats = EngineStats()
         self._session: Optional[TransferSession] = None
         self._pool = None   # KVPool of the last admitted batch
+        # jitted prefill/decode programs, one per (stage, static size): a
+        # repeated call reuses its executable instead of re-tracing
+        self._programs: Dict[Tuple[str, Optional[int]], object] = {}
+
+    def _program(self, stage: str, size: Optional[int]):
+        key = (stage, size)
+        if key not in self._programs:
+            make = make_prefill_fn if stage == "prefill" else make_decode_fn
+            self._programs[key] = make(self.cfg, size)
+        return self._programs[key]
 
     # -- plan/session caching ------------------------------------------------
     def _session_for(self, cache) -> TransferSession:
@@ -231,7 +241,7 @@ class DisaggregatedEngine:
 
     # -- the three pipeline stages ------------------------------------------
     def prefill(self, batch: Dict, max_seq: Optional[int] = None):
-        out = prefill_step(self.params, batch, self.cfg, max_seq=max_seq)
+        out = self._program("prefill", max_seq)(self.params, batch)
         self.stats.prefill_calls += 1
         return out
 
@@ -359,8 +369,8 @@ class DisaggregatedEngine:
                 num_steps)
             self.stats.resident_demotions += int(demoted)
         else:
-            toks, _ = decode_loop(self.params, first_token, state, self.cfg,
-                                  num_steps)
+            toks, _ = self._program("decode", num_steps)(
+                self.params, first_token, state)
         self.stats.decode_tokens += int(toks.size)
         return toks
 

@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.backend import CodecBackend, WireCompressed, get_backend
 from repro.core.pipeline import ChunkSchedule
 from repro.core.wire import WireIntegrityError, WireStats, fletcher32
@@ -695,7 +695,7 @@ class TransferSession:
         if self._mesh_fn is None:
             self._mesh_fn = self._build_mesh_fn()
         leaves = jax.tree_util.tree_leaves(cache)
-        return jax.jit(self._mesh_fn).lower(*leaves).compile().as_text()
+        return self._mesh_fn.lower(*leaves).compile().as_text()
 
     # -- persistent executor -------------------------------------------------
     def save(self, path: str, tree, *, extra: Optional[Dict] = None,
@@ -1460,8 +1460,9 @@ class TransferSession:
 
         from jax.sharding import PartitionSpec as P
         out_specs = tuple(P("pod", *s) for s in plan.in_specs)
-        return shard_map(body, mesh=plan.mesh, in_specs=plan.in_specs,
-                         out_specs=out_specs, check_vma=False)
+        # jitted, so every call after the first reuses the executable
+        return jax.jit(shard_map(body, mesh=plan.mesh, in_specs=plan.in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
     def _mesh_chunked_body(self, lp: TransferPlan, local, perm):
         """Per-chunk collective with double-buffering: at any schedule step

@@ -460,7 +460,8 @@ class TestFusedAttention:
                 corr = jnp.exp(mm - m_new)
                 ll = ll * corr + pexp.sum(axis=-1)
                 aa = aa * corr[..., None] + jnp.einsum(
-                    "qhgt,thd->qhgd", pexp, vt,
+                    "qhgt,thd->qhgd", pexp.astype(jnp.bfloat16),
+                    vt.astype(jnp.bfloat16),
                     preferred_element_type=jnp.float32)
                 mm = m_new
             accs.append(aa.reshape(1, H, hd))
@@ -517,14 +518,18 @@ class TestFusedAttention:
             for p in range(int(n_full[b])):
                 kt = kf[b, p * tp:(p + 1) * tp].astype(jnp.float32)
                 vt = vf[b, p * tp:(p + 1) * tp].astype(jnp.float32)
-                s = jnp.einsum("qhgd,thd->qhgt", qr[b], kt,
-                               preferred_element_type=jnp.float32) * scale
+                # the kernel's op order: one (g, hd) x (Tp, hd)^T dot per head
+                s = jnp.stack([jax.lax.dot_general(
+                    qr[b, 0, hh], kt[:, hh], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                    for hh in range(hkv)])[None] * scale
                 m_new = jnp.maximum(mm, s.max(axis=-1))
                 pexp = jnp.exp(s - m_new[..., None])
                 corr = jnp.exp(mm - m_new)
                 ll = ll * corr + pexp.sum(axis=-1)
                 aa = aa * corr[..., None] + jnp.einsum(
-                    "qhgt,thd->qhgd", pexp, vt,
+                    "qhgt,thd->qhgd", pexp.astype(jnp.bfloat16),
+                    vt.astype(jnp.bfloat16),
                     preferred_element_type=jnp.float32)
                 mm = m_new
             np.testing.assert_array_equal(
@@ -679,7 +684,8 @@ class TestFusedAttentionMLA:
                 corr = jnp.exp(mm - m_new)
                 ll = ll * corr + pexp.sum(axis=-1)
                 aa = aa * corr[..., None] + jnp.einsum(
-                    "qht,tr->qhr", pexp, ct,
+                    "qht,tr->qhr", pexp.astype(jnp.bfloat16),
+                    ct.astype(jnp.bfloat16),
                     preferred_element_type=jnp.float32)
                 mm = m_new
             np.testing.assert_array_equal(np.asarray(m[b]), np.asarray(mm))
