@@ -124,6 +124,10 @@ def main(argv=None):
     print(f"cache wire bytes     : {eng.stats.wire_bytes:,.0f}")
     print(f"transfer ratio       : {eng.stats.transfer_ratio:.3f}x")
     print(f"codec ok (no overflow): {eng.stats.codec_ok}")
+    print(f"host reads           : transfer {eng.stats.transfer_host_reads}"
+          f" in {eng.stats.transfer_calls} call(s), resident decode "
+          f"{eng.stats.resident_host_reads} in {eng.stats.resident_steps} "
+          "step(s)")
     resolved = eng.tc.get_backend().name
     print(f"codec backend        : {args.codec_backend}"
           + (f" (resolved: {resolved})" if args.codec_backend == "auto" else ""))

@@ -45,6 +45,7 @@ import numpy as np
 from repro.core.codebook import FORMATS, Codebook
 from repro.core import codec as C
 from repro.core.backend import CodecBackend
+from repro.core.spans import host_read
 
 # Default raw-payload bytes per page per leaf.  32 KiB ≅ 128 tokens for the
 # benchmark GQA arch (m = 128 elem/token) and keeps the per-page escape
@@ -279,6 +280,10 @@ class KVPool:
             raise ValueError("codebook/geometry exponent mismatch")
         self._free: Dict[str, list] = {
             lg.key: list(range(lg.n_pages - 1, -1, -1)) for lg in geom.leaves}
+        # device-to-host reads made by admission and flushes, and flush
+        # calls (one per resident decode step)
+        self.host_reads = 0
+        self.flushes = 0
         self.state = ResidentState(
             leaves={lg.key: self._empty_leaf(lg) for lg in geom.leaves},
             cache_len=jnp.zeros((geom.batch,), jnp.int32),
@@ -381,7 +386,7 @@ class KVPool:
         shape or page-escape overflow."""
         g = self.geom
         cache_len = jnp.asarray(cache_len, jnp.int32)
-        lens = np.asarray(cache_len)
+        lens = host_read(cache_len, "cache_len", self)
         n_full = lens // g.tokens_per_page
         leaves = {}
         for lg in g.leaves:
@@ -427,7 +432,7 @@ class KVPool:
             idx_l = np.array(idx_l)
             idx_b = np.array(idx_b)
             idx_p = np.array(idx_p)
-            cnts = np.asarray(cnt_pg)[idx_l, idx_b, idx_p]
+            cnts = host_read(cnt_pg, "esc_cnt", self)[idx_l, idx_b, idx_p]
             if (cnts > lg.escape_cap).any():
                 raise ResidencyError(
                     f"leaf {lg.key!r}: page escape overflow "
@@ -489,9 +494,11 @@ class KVPool:
         every ``tokens_per_page`` steps) and scatters only the needy rows.
         Page-escape overflow raises :class:`ResidencyError` → demotion."""
         g = self.geom
-        lens = np.asarray(state.cache_len)
+        self.flushes += 1
+        lens = host_read(state.cache_len, "cache_len", self)
         full_page = lens // g.tokens_per_page - 1            # (B,)
-        table0 = np.asarray(state.leaves[g.leaves[0].key].page_table)
+        table0 = host_read(state.leaves[g.leaves[0].key].page_table,
+                           "page_table", self)
         rows = [b for b in range(g.batch)
                 if lens[b] > 0 and lens[b] % g.tokens_per_page == 0
                 and table0[0, b, full_page[b]] < 0]
@@ -520,7 +527,7 @@ class KVPool:
             idx_l = np.repeat(np.arange(g.n_layers), len(rows))
             idx_b = np.tile(rows_np, g.n_layers)
             idx_p = full_page[idx_b]
-            cnts = np.asarray(cnt_pg)[idx_l, idx_b]
+            cnts = host_read(cnt_pg, "esc_cnt", self)[idx_l, idx_b]
             if (cnts > lg.escape_cap).any():
                 raise ResidencyError(
                     f"leaf {lg.key!r}: tail recompress escape overflow "

@@ -256,6 +256,7 @@ def init_mlp(key, d_model, d_ff):
 
 
 def mlp(p, x):
-    g = jnp.einsum("bsd,df->bsf", x, p["w_gate"])
-    u = jnp.einsum("bsd,df->bsf", x, p["w_up"])
-    return jnp.einsum("bsf,fd->bsd", jax.nn.silu(g) * u, p["w_down"])
+    with jax.named_scope("mlp"):
+        g = jnp.einsum("bsd,df->bsf", x, p["w_gate"])
+        u = jnp.einsum("bsd,df->bsf", x, p["w_up"])
+        return jnp.einsum("bsf,fd->bsd", jax.nn.silu(g) * u, p["w_down"])

@@ -135,12 +135,13 @@ def embed_inputs(params, batch: Dict, cfg: ArchConfig) -> jax.Array:
 
 
 def lm_logits(params, x: jax.Array, cfg: ArchConfig) -> jax.Array:
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
-    return constrain(logits, "logits")
+    with jax.named_scope("head"):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+        else:
+            logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
+        return constrain(logits, "logits")
 
 
 # ---------------------------------------------------------------------------
@@ -155,18 +156,20 @@ def _dense_layer_fwd(cfg: ArchConfig, lp, x, positions, kv_block=1024):
         mix_out, state = SSM.mamba2_forward(lp["mixer"], h, cfg.ssm, cfg.d_model)
         x = constrain(x + mix_out, "btd")
         return x, state, aux
-    if cfg.mla is not None:
-        attn_out, kv = MLA.mla_prefill(lp["attn"], h, positions, cfg.mla,
-                                       cfg.rope_theta, kv_block=kv_block)
-    else:
-        q, k, v = L.attention_qkv(lp["attn"], h, positions, cfg.rope_theta)
-        q = constrain(q, "bthd")
-        k = constrain(k, "bthd")
-        v = constrain(v, "bthd")
-        o = L.chunked_attention(q, k, v, causal=not cfg.encoder_only,
-                                kv_block=kv_block)
-        attn_out = L.attention_out(lp["attn"], o)
-        kv = (k, v)
+    with jax.named_scope("attn"):
+        if cfg.mla is not None:
+            attn_out, kv = MLA.mla_prefill(lp["attn"], h, positions, cfg.mla,
+                                           cfg.rope_theta, kv_block=kv_block)
+        else:
+            q, k, v = L.attention_qkv(lp["attn"], h, positions,
+                                      cfg.rope_theta)
+            q = constrain(q, "bthd")
+            k = constrain(k, "bthd")
+            v = constrain(v, "bthd")
+            o = L.chunked_attention(q, k, v, causal=not cfg.encoder_only,
+                                    kv_block=kv_block)
+            attn_out = L.attention_out(lp["attn"], o)
+            kv = (k, v)
     x = x + attn_out
     h2 = L.rms_norm(x, lp["norm2"], cfg.norm_eps)
     if cfg.moe is not None:
@@ -449,8 +452,10 @@ def decode_step(params, tokens: jax.Array, state: DecodeState, cfg: ArchConfig
         def layer_step(carry, xs):
             lp, ckv, krope = xs
             h = L.rms_norm(carry, lp["norm1"], cfg.norm_eps)
-            out, (ckv, krope) = MLA.mla_decode(lp["attn"], h, ckv, krope,
-                                               cache_len, cfg.mla, cfg.rope_theta)
+            with jax.named_scope("attn"):
+                out, (ckv, krope) = MLA.mla_decode(
+                    lp["attn"], h, ckv, krope, cache_len, cfg.mla,
+                    cfg.rope_theta)
             h2 = L.rms_norm(carry + out, lp["norm2"], cfg.norm_eps)
             y = carry + out + (MOE.moe_ffn(lp["ffn"], h2, cfg.moe)[0]
                                if cfg.moe else L.mlp(lp["ffn"], h2))
@@ -463,8 +468,9 @@ def decode_step(params, tokens: jax.Array, state: DecodeState, cfg: ArchConfig
         def layer_step(carry, xs):
             lp, ck, cv = xs
             h = L.rms_norm(carry, lp["norm1"], cfg.norm_eps)
-            out, (ck, cv) = L.decode_attention_block(
-                lp["attn"], h, ck, cv, cache_len, cfg.rope_theta)
+            with jax.named_scope("attn"):
+                out, (ck, cv) = L.decode_attention_block(
+                    lp["attn"], h, ck, cv, cache_len, cfg.rope_theta)
             y = carry + out
             h2 = L.rms_norm(y, lp["norm2"], cfg.norm_eps)
             ffn = (MOE.moe_ffn(lp["ffn"], h2, cfg.moe)[0] if cfg.moe
@@ -508,10 +514,11 @@ def resident_decode_step(params, tokens: jax.Array, state, cfg: ArchConfig,
         def layer_step(carry, xs):
             lp, pt_c, pt_r, tc, tr = xs
             h = L.rms_norm(carry, lp["norm1"], cfg.norm_eps)
-            out, (tc, tr) = KVP.paged_mla_decode(
-                lp["attn"], h, c_streams, r_streams, pt_c, pt_r, tc, tr,
-                cache_len, cfg.mla, cfg.rope_theta, geom=g, fmt=fmt,
-                interpret=interpret)
+            with jax.named_scope("attn"):
+                out, (tc, tr) = KVP.paged_mla_decode(
+                    lp["attn"], h, c_streams, r_streams, pt_c, pt_r, tc, tr,
+                    cache_len, cfg.mla, cfg.rope_theta, geom=g, fmt=fmt,
+                    interpret=interpret)
             h2 = L.rms_norm(carry + out, lp["norm2"], cfg.norm_eps)
             y = carry + out + (MOE.moe_ffn(lp["ffn"], h2, cfg.moe)[0]
                                if cfg.moe else L.mlp(lp["ffn"], h2))
@@ -530,10 +537,11 @@ def resident_decode_step(params, tokens: jax.Array, state, cfg: ArchConfig,
         def layer_step(carry, xs):
             lp, pt_k, pt_v, tk, tv = xs
             h = L.rms_norm(carry, lp["norm1"], cfg.norm_eps)
-            out, (tk, tv) = KVP.paged_decode_attention_block(
-                lp["attn"], h, k_streams, v_streams, pt_k, pt_v, tk, tv,
-                cache_len, cfg.rope_theta, geom=g, fmt=fmt,
-                interpret=interpret)
+            with jax.named_scope("attn"):
+                out, (tk, tv) = KVP.paged_decode_attention_block(
+                    lp["attn"], h, k_streams, v_streams, pt_k, pt_v, tk, tv,
+                    cache_len, cfg.rope_theta, geom=g, fmt=fmt,
+                    interpret=interpret)
             y = carry + out
             h2 = L.rms_norm(y, lp["norm2"], cfg.norm_eps)
             ffn = (MOE.moe_ffn(lp["ffn"], h2, cfg.moe)[0] if cfg.moe

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.core.spans import span
 from repro.models import model as M
 from repro.models.kvcache import DecodeState
 
@@ -43,13 +44,16 @@ def decode_loop(params, first_token: jax.Array, state: DecodeState,
 def make_decode_fn(cfg: ArchConfig, num_steps: int):
     @jax.jit
     def fn(params, first_token, state):
-        return decode_loop(params, first_token, state, cfg, num_steps)
+        with jax.named_scope("decode"):
+            return decode_loop(params, first_token, state, cfg, num_steps)
     return fn
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4))
 def _resident_step(params, tok, state, cfg, interpret):
-    return M.resident_decode_step(params, tok, state, cfg, interpret=interpret)
+    with jax.named_scope("resident_step"):
+        return M.resident_decode_step(params, tok, state, cfg,
+                                      interpret=interpret)
 
 
 def resident_decode_loop(params, first_token: jax.Array, state, pool,
@@ -74,11 +78,14 @@ def resident_decode_loop(params, first_token: jax.Array, state, pool,
     toks = []
     st = state
     for i in range(num_steps):
-        logits, st = _resident_step(params, tok[:, None], st, cfg, interpret)
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with span("resident.step", step=i):
+            logits, st = _resident_step(params, tok[:, None], st, cfg,
+                                        interpret)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         toks.append(tok)
         try:
-            st = pool.flush_full_tails(st)
+            with span("resident.flush"):
+                st = pool.flush_full_tails(st)
         except ResidencyError:
             cache = pool.rehydrate(st)
             dst = DecodeState(cache=cache, cache_len=st.cache_len)

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.core.codebook import Codebook
 from repro.core.pipeline import CodecProfile
+from repro.core.spans import host_read, span
 from repro.models import model as M
 from repro.models.kvcache import DecodeState, cache_bytes
 from repro.serving import transfer as T
@@ -86,6 +87,14 @@ class EngineStats:
     # prefix-delta transfer: raw bytes the destination already held and the
     # wire therefore never carried (excluded from wire_bytes by construction)
     prefix_hit_bytes: float = 0.0
+    # host reads (``core.spans.host_read``): device-to-host reads made by the
+    # transfer (the session's per-unit ``ok`` and wire-byte reads, the
+    # cache-length read, the pool's admission reads) over ``transfer_calls``;
+    # and by the resident decode loop's tail flushes over ``resident_steps``
+    transfer_calls: int = 0
+    transfer_host_reads: int = 0
+    resident_steps: int = 0
+    resident_host_reads: int = 0
 
     @property
     def resident_ratio(self) -> float:
@@ -160,6 +169,7 @@ class DisaggregatedEngine:
         self.stats = EngineStats()
         self._session: Optional[TransferSession] = None
         self._pool = None   # KVPool of the last admitted batch
+        self._decode_calls = 0
         # jitted prefill/decode programs, one per (stage, static size): a
         # repeated call reuses its executable instead of re-tracing
         self._programs: Dict[Tuple[str, Optional[int]], object] = {}
@@ -241,7 +251,8 @@ class DisaggregatedEngine:
 
     # -- the three pipeline stages ------------------------------------------
     def prefill(self, batch: Dict, max_seq: Optional[int] = None):
-        out = self._program("prefill", max_seq)(self.params, batch)
+        with span("prefill", batch=self.stats.prefill_calls):
+            out = self._program("prefill", max_seq)(self.params, batch)
         self.stats.prefill_calls += 1
         return out
 
@@ -260,6 +271,11 @@ class DisaggregatedEngine:
         call through the prefix-delta path: segments the destination already
         holds for that session never cross the wire, and their raw size lands
         in ``EngineStats.prefix_hit_bytes``."""
+        with span("transfer", batch=self.stats.transfer_calls):
+            self.stats.transfer_calls += 1
+            return self._transfer(state, session_id)
+
+    def _transfer(self, state: DecodeState, session_id: Optional[int]):
         raw = T.raw_wire_bytes(state.cache)
         self.stats.raw_cache_bytes += raw
         if not self.tc.enabled or not state.cache:
@@ -310,12 +326,14 @@ class DisaggregatedEngine:
         if units:
             self.stats.encoded_units += units
             lens = jnp.asarray(state.cache_len)
-            length = int(jnp.max(lens)) if lens.size else 0
+            length = (host_read(jnp.max(lens), "cache_len", cstats, int)
+                      if lens.size else 0)
             obs = self.stats.overflow_obs.setdefault(length, [0, 0])
             obs[0] += units
             obs[1] += cstats.n_retries
         if self.tc.n_chunks > 1:
             self.stats.chunk_wire_bytes.extend(cstats.chunk_wire_bytes)
+        self.stats.transfer_host_reads += cstats.host_reads
 
     def resident_tokens_per_page(self, batch: int = 1) -> int:
         """Page granularity the pool will use for this arch (max_seq must be
@@ -341,19 +359,24 @@ class DisaggregatedEngine:
 
         comp, raw = sess.transfer_compressed(state.cache, check=False)
         self._absorb_transfer_stats(sess.last_stats, state)
+        pool = None
         try:
-            pool = KVP.KVPool.for_cache(
-                state.cache, self.tc.codebook,
-                resolve_backend(self.tc.backend, require_jittable=True),
-                chunk=self.tc.chunk,
-                page_bytes=self.page_bytes or KVP.DEFAULT_PAGE_BYTES)
-            rst = pool.admit_from_wire(comp, state.cache_len)
+            with span("resident.admit"):
+                pool = KVP.KVPool.for_cache(
+                    state.cache, self.tc.codebook,
+                    resolve_backend(self.tc.backend, require_jittable=True),
+                    chunk=self.tc.chunk,
+                    page_bytes=self.page_bytes or KVP.DEFAULT_PAGE_BYTES)
+                rst = pool.admit_from_wire(comp, state.cache_len)
         except KVP.ResidencyError:
+            if pool is not None:
+                self.stats.transfer_host_reads += pool.host_reads
             self.stats.resident_demotions += 1
             cache = decode_leaves(comp, raw, state.cache,
                                   backend=self.tc.backend)
             return DecodeState(cache=cache, cache_len=state.cache_len)
         self._pool = pool
+        self.stats.transfer_host_reads += pool.host_reads
         self.stats.resident_admits += 1
         self.stats.resident_hbm_bytes += pool.hbm_bytes()
         self.stats.resident_raw_bytes += pool.raw_bytes()
@@ -363,14 +386,20 @@ class DisaggregatedEngine:
                ) -> jax.Array:
         from repro.models.kvpool import ResidentState
         from repro.serving.decode import resident_decode_loop
-        if isinstance(state, ResidentState):
-            toks, _, demoted = resident_decode_loop(
-                self.params, first_token, state, self._pool, self.cfg,
-                num_steps)
-            self.stats.resident_demotions += int(demoted)
-        else:
-            toks, _ = self._program("decode", num_steps)(
-                self.params, first_token, state)
+        with span("decode", batch=self._decode_calls):
+            self._decode_calls += 1
+            if isinstance(state, ResidentState):
+                pool = self._pool
+                reads, flushes = pool.host_reads, pool.flushes
+                toks, _, demoted = resident_decode_loop(
+                    self.params, first_token, state, pool, self.cfg,
+                    num_steps)
+                self.stats.resident_demotions += int(demoted)
+                self.stats.resident_host_reads += pool.host_reads - reads
+                self.stats.resident_steps += pool.flushes - flushes
+            else:
+                toks, _ = self._program("decode", num_steps)(
+                    self.params, first_token, state)
         self.stats.decode_tokens += int(toks.size)
         return toks
 

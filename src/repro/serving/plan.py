@@ -198,6 +198,8 @@ class TransferStats:
     # property stays "bytes actually on the wire", and the saving is the gap
     # between raw_bytes and it
     prefix_hit_bytes: float = 0.0
+    # device-to-host reads the call made (``core.spans.host_read``)
+    host_reads: int = 0
 
     @property
     def wire_bytes(self) -> float:

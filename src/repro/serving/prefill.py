@@ -50,6 +50,7 @@ def make_prefill_fn(cfg: ArchConfig, max_seq: Optional[int] = None,
     """Jit-wrapped prefill step (static model config baked in)."""
     @jax.jit
     def fn(params, batch):
-        return prefill_step(params, batch, cfg, max_seq=max_seq,
-                            kv_block=kv_block)
+        with jax.named_scope("prefill"):
+            return prefill_step(params, batch, cfg, max_seq=max_seq,
+                                kv_block=kv_block)
     return fn

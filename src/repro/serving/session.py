@@ -38,6 +38,7 @@ Five execution paths, selected by the plan and the entry point:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -53,6 +54,7 @@ import numpy as np
 from jax import shard_map
 from repro.core.backend import CodecBackend, WireCompressed, get_backend
 from repro.core.pipeline import ChunkSchedule
+from repro.core.spans import host_read, span
 from repro.core.wire import WireIntegrityError, WireStats, fletcher32
 from repro.serving.faults import FaultChannel, resolve_faults
 from repro.serving.plan import TransferPlan, TransferStats, leaf_key
@@ -110,27 +112,32 @@ def _permute_leaf(x: jax.Array, axis_name: str, src: int, dst: int) -> jax.Array
 # ---------------------------------------------------------------------------
 
 def _encode_scheduled(plan: TransferPlan, x, codebook, n: int, cap: int,
-                      *, scheduled: bool):
+                      *, scheduled: bool, key: str,
+                      stats: Optional[TransferStats] = None):
     """Encode ``x`` down the plan's geometric capacity schedule.
 
     Returns ``(ct, ok, extra_attempts)``.  ``scheduled=False`` (one-shot
     shims, in-graph tracing) encodes once at plan capacity and leaves ``ok``
     traced — the schedule's concrete ``ok`` branch is host-side control
-    flow."""
+    flow.  A scheduled encode is eager host code: it runs inside a
+    ``sz.transfer.encode`` span, and its ``ok`` reads count on ``stats``."""
     tc = plan.tc
-    ct = plan.backend.encode(x, codebook, chunk=tc.chunk, cap=cap,
-                             layout=tc.layout)
     if not scheduled:
+        ct = plan.backend.encode(x, codebook, chunk=tc.chunk, cap=cap,
+                                 layout=tc.layout)
         return ct, plan.backend.ok(ct), 0
-    if bool(plan.backend.ok(ct)):
-        return ct, True, 0
-    extra = 0
-    for be, layout, c in plan.schedule_for(n, cap)[1:]:
-        extra += 1
-        ct = be.encode(x, codebook, chunk=tc.chunk, cap=c, layout=layout)
-        if bool(be.ok(ct)):
-            return ct, True, extra
-    return ct, False, extra
+    with span("transfer.encode", key=key):
+        ct = plan.backend.encode(x, codebook, chunk=tc.chunk, cap=cap,
+                                 layout=tc.layout)
+        if host_read(plan.backend.ok(ct), "ok", stats, bool):
+            return ct, True, 0
+        extra = 0
+        for be, layout, c in plan.schedule_for(n, cap)[1:]:
+            extra += 1
+            ct = be.encode(x, codebook, chunk=tc.chunk, cap=c, layout=layout)
+            if host_read(be.ok(ct), "ok", stats, bool):
+                return ct, True, extra
+        return ct, False, extra
 
 
 def _record_unit(stats: Optional[TransferStats], key: str, ok: bool,
@@ -161,7 +168,8 @@ def encode_leaves(plan: TransferPlan, cache, *, scheduled: bool = True,
         if r.route == "splitzip":
             ct, ok, extra = _encode_scheduled(plan, leaf, tc.codebook,
                                               r.n_elements, r.cap,
-                                              scheduled=scheduled)
+                                              scheduled=scheduled, key=key,
+                                              stats=stats)
             if scheduled and not bool(ok):
                 raw[key] = leaf
                 if stats is not None:
@@ -170,7 +178,8 @@ def encode_leaves(plan: TransferPlan, cache, *, scheduled: bool = True,
             else:
                 comp[key] = ct
                 if stats is not None:
-                    stats.leaf_wire_bytes[key] = float(be.wire_bytes(ct))
+                    stats.leaf_wire_bytes[key] = host_read(
+                        be.wire_bytes(ct), "wire_bytes", stats, float)
                 _record_unit(stats, key, True, extra)
         elif r.route == "fp32_hilo":
             u = jax.lax.bitcast_convert_type(leaf, jnp.uint32)
@@ -178,7 +187,8 @@ def encode_leaves(plan: TransferPlan, cache, *, scheduled: bool = True,
             lo = (u & 0xFFFF).astype(jnp.uint16)
             ct, ok, extra = _encode_scheduled(plan, hi, tc.codebook,
                                               r.n_elements, r.cap,
-                                              scheduled=scheduled)
+                                              scheduled=scheduled, key=key,
+                                              stats=stats)
             if scheduled and not bool(ok):
                 # an overflowed hi half means the WHOLE fp32 leaf ships raw
                 raw[key] = leaf
@@ -189,13 +199,15 @@ def encode_leaves(plan: TransferPlan, cache, *, scheduled: bool = True,
                 comp[key + "#hi"] = ct
                 raw[key + "#lo"] = lo
                 if stats is not None:
-                    stats.leaf_wire_bytes[key] = float(be.wire_bytes(ct))
+                    stats.leaf_wire_bytes[key] = host_read(
+                        be.wire_bytes(ct), "wire_bytes", stats, float)
                     stats.fp32_lo_wire_bytes += 2.0 * r.n_elements
                 _record_unit(stats, key, True, extra)
         elif r.route == "fp8":
             ct, ok, extra = _encode_scheduled(plan, leaf, plan.fp8_codebook,
                                               r.n_elements, r.cap,
-                                              scheduled=scheduled)
+                                              scheduled=scheduled, key=key,
+                                              stats=stats)
             if scheduled and not bool(ok):
                 raw[key] = leaf
                 if stats is not None:
@@ -204,7 +216,8 @@ def encode_leaves(plan: TransferPlan, cache, *, scheduled: bool = True,
             else:
                 comp[key] = ct
                 if stats is not None:
-                    stats.fp8_wire_bytes += float(be.wire_bytes(ct))
+                    stats.fp8_wire_bytes += host_read(
+                        be.wire_bytes(ct), "wire_bytes", stats, float)
                 _record_unit(stats, key, True, extra)
         else:
             raw[key] = leaf
@@ -219,34 +232,46 @@ def decode_leaves(comp: Dict, raw: Dict, structure, backend: str = "xla"):
     ``backend=`` argument that doesn't match what produced ``comp``."""
     be = get_backend(backend)
     flat, treedef = jax.tree_util.tree_flatten_with_path(structure)
+    # the mesh executor decodes inside shard_map: no spans while tracing
+    eager = not any(isinstance(x, jax.core.Tracer)
+                    for x in jax.tree.leaves((comp, raw)))
     leaves = []
     for path, leaf in flat:
         key = leaf_key(path)
         if key in comp:
             ct = comp[key]
-            leaves.append(jnp.asarray(
-                _backend_for(ct, be).decode(ct)).reshape(leaf.shape))
+            with _leaf_span(eager, "transfer.decode", key):
+                leaves.append(jnp.asarray(
+                    _backend_for(ct, be).decode(ct)).reshape(leaf.shape))
         elif key + "#hi" in comp:  # fp32 hi/lo split
             ct = comp[key + "#hi"]
-            hi = jnp.asarray(
-                _backend_for(ct, be).decode(ct)).reshape(leaf.shape)
-            lo = raw[key + "#lo"]
-            u = (hi.astype(jnp.uint32) << 16) | lo.astype(jnp.uint32)
-            leaves.append(jax.lax.bitcast_convert_type(u, jnp.float32))
+            with _leaf_span(eager, "transfer.decode", key):
+                hi = jnp.asarray(
+                    _backend_for(ct, be).decode(ct)).reshape(leaf.shape)
+                lo = raw[key + "#lo"]
+                u = (hi.astype(jnp.uint32) << 16) | lo.astype(jnp.uint32)
+                leaves.append(jax.lax.bitcast_convert_type(u, jnp.float32))
         else:
             leaves.append(raw[key])
     return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def _leaf_span(eager: bool, name: str, key: str):
+    """A per-leaf span in eager code; none while tracing, where it would
+    time the trace and not the work."""
+    return span(name, key=key) if eager else contextlib.nullcontext()
 
 
 # ---------------------------------------------------------------------------
 # prefix-delta index (transfer_delta)
 # ---------------------------------------------------------------------------
 
-def _host_bits(x) -> np.ndarray:
+def _host_bits(x, stats: TransferStats) -> np.ndarray:
     """Flat byte view of any array-like, on host.  Sender-shadow comparison
     runs in the BIT domain, not the numeric one — NaN payloads, negative
     zeros, and denormals all compare exactly."""
-    return np.ascontiguousarray(np.asarray(x)).view(np.uint8).reshape(-1)
+    return np.ascontiguousarray(host_read(x, "shadow", stats)).view(
+        np.uint8).reshape(-1)
 
 
 @dataclasses.dataclass
@@ -583,7 +608,7 @@ class TransferSession:
         plan = self.plan
         stats = self._new_chunked_stats()
         stream, lo, fp8, raw = plan.fold_stream(cache)
-        host_stream = np.asarray(stream)
+        host_stream = host_read(stream, "stream", stats)
         entry = self._prefix_index.get(session_id)
 
         # pipelined stream: per-segment sender-shadow comparison
@@ -613,7 +638,7 @@ class TransferSession:
                 return False
             shadow = entry.side_shadow.get(f"{fam}:{key}")
             return (shadow is not None
-                    and np.array_equal(_host_bits(sender_obj), shadow))
+                    and np.array_equal(_host_bits(sender_obj, stats), shadow))
 
         for r in plan.routes:
             k = r.key
@@ -631,10 +656,11 @@ class TransferSession:
                 else:
                     ct, ok, extra = _encode_scheduled(
                         plan, fp8[k], plan.fp8_codebook, r.n_elements, r.cap,
-                        scheduled=True)
+                        scheduled=True, key=k, stats=stats)
                     _record_unit(stats, k, bool(ok), extra)
                     stats.fp8_wire_bytes += (
-                        float(plan.backend.wire_bytes(ct)) if ok
+                        host_read(plan.backend.wire_bytes(ct), "wire_bytes",
+                                  stats, float) if ok
                         else r.raw_bytes)
                     miss_fp8[k] = ct if ok else fp8[k]
             elif r.route == "raw":
@@ -668,15 +694,15 @@ class TransferSession:
         for r in plan.routes:
             k = r.key
             if r.route == "fp32_hilo":
-                shadow[f"lo:{k}"] = _host_bits(lo[k]).copy()
+                shadow[f"lo:{k}"] = _host_bits(lo[k], stats).copy()
                 side_obj[f"lo:{k}"] = lo_out[k]
                 nbytes += 2.0 * r.n_elements
             elif r.route == "fp8":
-                shadow[f"fp8:{k}"] = _host_bits(fp8[k]).copy()
+                shadow[f"fp8:{k}"] = _host_bits(fp8[k], stats).copy()
                 side_obj[f"fp8:{k}"] = fp8_dec[k]
                 nbytes += r.raw_bytes
             elif r.route == "raw":
-                shadow[f"raw:{k}"] = _host_bits(raw[k]).copy()
+                shadow[f"raw:{k}"] = _host_bits(raw[k], stats).copy()
                 side_obj[f"raw:{k}"] = raw_out[k]
                 nbytes += r.raw_bytes
         self._prefix_index.put(session_id, _PrefixEntry(
@@ -1192,18 +1218,21 @@ class TransferSession:
                 obj, is_raw = raw_payload, True
             stats.refetches += 1
             stats.raw_refetches += int(is_raw)
-            stats.refetch_wire_bytes += self._object_wire_bytes(obj, is_raw)
+            stats.refetch_wire_bytes += self._object_wire_bytes(obj, is_raw,
+                                                             stats)
             frame = self._channel.ship(obj, self._uid, ci, attempt)
             payload, intact = self._channel.deliver(frame)
             stats.fault_delay_s += frame.delay_s
             attempt += 1
         return payload, is_raw
 
-    def _object_wire_bytes(self, obj, is_raw: bool) -> float:
+    def _object_wire_bytes(self, obj, is_raw: bool,
+                           stats: TransferStats) -> float:
         if is_raw or isinstance(obj, (jax.Array, np.ndarray)):
-            a = np.asarray(obj)
+            a = host_read(obj, "refetch", stats)
             return float(a.size * a.dtype.itemsize)
-        return float(_backend_for(obj, self.plan.backend).wire_bytes(obj))
+        return host_read(_backend_for(obj, self.plan.backend).wire_bytes(obj),
+                         "wire_bytes", stats, float)
 
     # -- local / chunked -----------------------------------------------------
     def _encode_chunk(self, stream, i: int):
@@ -1221,7 +1250,7 @@ class TransferSession:
         plan, tc = self.plan, self.plan.tc
         seg = plan.segments[i]
         be = plan.backend
-        ok = bool(be.ok(ct))
+        ok = host_read(be.ok(ct), "ok", stats, bool)
         extra = 0
         if not ok:
             for rbe, layout, cap in plan.schedule_for(seg.n_elements,
@@ -1229,14 +1258,15 @@ class TransferSession:
                 extra += 1
                 ct2 = rbe.encode(stream[seg.start:seg.stop], tc.codebook,
                                  chunk=tc.chunk, cap=cap, layout=layout)
-                if bool(rbe.ok(ct2)):
+                if host_read(rbe.ok(ct2), "ok", stats, bool):
                     ct, ok = ct2, True
                     break
         stats.chunk_retried[i] = extra > 0
         stats.chunk_retry_steps[i] = extra
         stats.chunk_ok[i] = ok
-        stats.chunk_wire_bytes[i] = (float(be.wire_bytes(ct)) if ok
-                                     else seg.raw_bytes)
+        stats.chunk_wire_bytes[i] = (
+            host_read(be.wire_bytes(ct), "wire_bytes", stats, float) if ok
+            else seg.raw_bytes)
         return ct if ok else None
 
     def _decode_chunk(self, stream, i: int, payload):
@@ -1295,8 +1325,9 @@ class TransferSession:
                 be, layout, cap = sched[attempt]
                 ct = be.encode(stream[seg.start:seg.stop], tc.codebook,
                                chunk=tc.chunk, cap=cap, layout=layout)
-                if bool(be.ok(ct)):
-                    obj, nbytes, is_raw = ct, float(be.wire_bytes(ct)), False
+                if host_read(be.ok(ct), "ok", stats, bool):
+                    obj, nbytes, is_raw = ct, host_read(
+                        be.wire_bytes(ct), "wire_bytes", stats, float), False
                 else:
                     obj, nbytes, is_raw = (stream[seg.start:seg.stop],
                                            seg.raw_bytes, True)
@@ -1324,10 +1355,11 @@ class TransferSession:
             elif r.route == "fp8":
                 ct, ok, extra = _encode_scheduled(
                     plan, fp8[r.key], plan.fp8_codebook, r.n_elements, r.cap,
-                    scheduled=True)
+                    scheduled=True, key=r.key, stats=stats)
                 _record_unit(stats, r.key, bool(ok), extra)
-                stats.fp8_wire_bytes += (float(plan.backend.wire_bytes(ct))
-                                         if ok else r.raw_bytes)
+                stats.fp8_wire_bytes += (
+                    host_read(plan.backend.wire_bytes(ct), "wire_bytes",
+                              stats, float) if ok else r.raw_bytes)
                 fp8_payload[r.key] = ct if ok else fp8[r.key]
             elif r.route == "raw":
                 stats.raw_passthrough_bytes += r.raw_bytes
