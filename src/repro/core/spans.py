@@ -22,7 +22,7 @@ no read: each call site is a read the program already made.
     sz.resident.admit    pool build and admission of the received streams
     sz.decode            DisaggregatedEngine.decode (batch)
     sz.resident.step     one resident decode step's dispatch (step)
-    sz.resident.flush    the host flush of full tail pages after a step
+    sz.resident.flush    the flush of full tail pages after a step
     sz.host_read         a device-to-host read (what)
 """
 

@@ -127,7 +127,7 @@ def main(argv=None):
     print(f"host reads           : transfer {eng.stats.transfer_host_reads}"
           f" in {eng.stats.transfer_calls} call(s), resident decode "
           f"{eng.stats.resident_host_reads} in {eng.stats.resident_steps} "
-          "step(s)")
+          f"step(s), {eng.stats.resident_page_flushes} page flush(es)")
     resolved = eng.tc.get_backend().name
     print(f"codec backend        : {args.codec_backend}"
           + (f" (resolved: {resolved})" if args.codec_backend == "auto" else ""))

@@ -20,8 +20,11 @@ them compressed **at rest in HBM** on the decode worker.  Storage is paged:
   page id (−1 = unmapped); physical pages come from a host-side free-list.
 * decode-time growth appends raw tokens to a per-row **tail page** in the
   container dtype; when a row's tail fills (``cache_len % tokens_per_page ==
-  0``) the host flushes it through the registered codec backend
-  (``flush_full_tails``) into fresh pages.  The attention kernel
+  0``) ``flush_full_tails`` recompresses it through the registered codec
+  backend into fresh pages.  The host decides which rows flush from the
+  lengths and page map it keeps itself, so an ordinary step reads nothing
+  from the device; a page-boundary flush is one jitted program whose only
+  read is its overflow flag.  The attention kernel
   (``kernels/splitzip_attention.py``) therefore only ever sees FULL
   compressed pages + a raw tail, and the decode *step* never touches the
   codec's decompress path (CI grep-guards this).
@@ -35,6 +38,7 @@ HBM-derived decode-slot capacity and ``benchmarks/fig6_resident_capacity``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -44,7 +48,7 @@ import numpy as np
 
 from repro.core.codebook import FORMATS, Codebook
 from repro.core import codec as C
-from repro.core.backend import CodecBackend
+from repro.core.backend import CodecBackend, get_backend
 from repro.core.spans import host_read
 
 # Default raw-payload bytes per page per leaf.  32 KiB ≅ 128 tokens for the
@@ -159,7 +163,7 @@ class ResidentState:
     """What a jitted resident decode step consumes/returns.
 
     The page pools are read-only inside a step; only ``tail`` rows and
-    ``cache_len`` change (flushes happen host-side between steps)."""
+    ``cache_len`` change (flushes run between steps)."""
 
     leaves: Dict[str, PagedLeaf]
     cache_len: jax.Array       # (B,) i32
@@ -260,6 +264,52 @@ def _decode_pool_pages(leaf: PagedLeaf, lg: LeafGeometry,
     return bits.at[rows, pos].set(jnp.where(occupied, new, 0), mode="drop")
 
 
+@functools.partial(jax.jit, static_argnames=("geom", "backend"))
+def _flush_pages(leaves, pids, page, *, geom: PoolGeometry, backend: str):
+    """Recompress every leaf's tail into the pages ``pids`` names.
+
+    ``pids[key]`` is (L, B) physical page ids and ``page`` (B,) the logical
+    page each row's tail becomes; rows that do not flush carry out-of-range
+    ids in both, so their writes drop and one executable serves any set of
+    flushing rows.  Returns the new leaves and ``ok``: every flushing row's
+    page escape count fits its leaf's ``escape_cap``.  The inputs are not
+    donated, so a caller that finds ``ok`` false still holds its state.
+
+    Module-level with hashable static arguments (the geometry carries the
+    codebook's exponents), so pools of one geometry share one compile."""
+    be = get_backend(backend)
+    L_, B, chunk = geom.n_layers, geom.batch, geom.chunk
+    flushing = page < geom.max_pages                         # (B,)
+    ok = jnp.asarray(True)
+    out = {}
+    for lg in geom.leaves:
+        leaf = leaves[lg.key]
+        pc = lg.page_chunks
+        ct = be.encode(leaf.tail.reshape(-1),
+                       Codebook(fmt=lg.fmt, exponents=geom.exponents),
+                       chunk=chunk, cap=lg.escape_cap, layout="chunked")
+        pos_pg, val_pg, cnt_pg = _page_escapes(
+            ct.esc_pos.reshape(L_, B, pc, -1),
+            ct.esc_val.reshape(L_, B, pc, -1),
+            ct.esc_count.reshape(L_, B, pc), chunk=chunk, page_chunks=pc,
+            cap_page=lg.escape_cap)
+        ok &= jnp.all((cnt_pg <= lg.escape_cap) | ~flushing[None, :])
+        pid = pids[lg.key]                                   # (L, B)
+        out[lg.key] = dataclasses.replace(
+            leaf,
+            sign_mantissa=leaf.sign_mantissa.at[pid].set(
+                ct.sign_mantissa.reshape(L_, B, pc, chunk), mode="drop"),
+            packed=leaf.packed.at[pid].set(
+                ct.packed.reshape(L_, B, pc, chunk // 2), mode="drop"),
+            esc_pos=leaf.esc_pos.at[pid, 0].set(pos_pg, mode="drop"),
+            esc_val=leaf.esc_val.at[pid, 0].set(val_pg, mode="drop"),
+            esc_cnt=leaf.esc_cnt.at[pid, 0].set(cnt_pg, mode="drop"),
+            page_table=leaf.page_table.at[
+                jnp.arange(L_)[:, None], jnp.arange(B)[None, :],
+                page[None, :]].set(pid, mode="drop"))
+    return out, ok
+
+
 # ---------------------------------------------------------------------------
 # the pool
 # ---------------------------------------------------------------------------
@@ -280,10 +330,16 @@ class KVPool:
             raise ValueError("codebook/geometry exponent mismatch")
         self._free: Dict[str, list] = {
             lg.key: list(range(lg.n_pages - 1, -1, -1)) for lg in geom.leaves}
-        # device-to-host reads made by admission and flushes, and flush
-        # calls (one per resident decode step)
+        # device-to-host reads made by admission and flushes, flush calls
+        # (one per resident decode step), and flushes that mapped pages
         self.host_reads = 0
         self.flushes = 0
+        self.page_flushes = 0
+        # the host's copy of ``self.state.cache_len`` and of how many
+        # logical pages each row has mapped in ``self.state`` (the same for
+        # every layer and leaf); ``sync`` points both at another state
+        self.lens = np.zeros((geom.batch,), np.int64)
+        self.mapped = np.zeros((geom.batch,), np.int64)
         self.state = ResidentState(
             leaves={lg.key: self._empty_leaf(lg) for lg in geom.leaves},
             cache_len=jnp.zeros((geom.batch,), jnp.int32),
@@ -409,6 +465,7 @@ class KVPool:
             leaves[lg.key] = self._admit_leaf(ct, lg, lens, n_full)
         self.state = ResidentState(leaves=leaves, cache_len=cache_len,
                                    geom=g)
+        self.lens, self.mapped = lens, n_full
         return self.state
 
     def _admit_leaf(self, ct, lg: LeafGeometry, lens: np.ndarray,
@@ -485,81 +542,73 @@ class KVPool:
 
     # -- decode-time growth ------------------------------------------------
 
-    def flush_full_tails(self, state: ResidentState) -> ResidentState:
+    def sync(self, state: ResidentState,
+             lens: Optional[np.ndarray] = None) -> np.ndarray:
+        """Point the host's lengths and page map at ``state``; return the
+        lengths.
+
+        Both describe ``self.state``, the state the pool made last.  A
+        state that descends from it through decode steps (which grow only
+        tails and lengths) shares its page tables, so the map holds; any
+        other (an earlier snapshot, a state built by hand) has its map read
+        back from leaf 0's page table.  The lengths are ``lens`` when the
+        caller knows them, the pool's own when ``state`` shares
+        ``self.state``'s ``cache_len``, and read from the device otherwise."""
+        key = self.geom.leaves[0].key
+        table = state.leaves[key].page_table
+        if table is not self.state.leaves[key].page_table:
+            self.mapped = (host_read(table, "page_table", self)[0] >= 0
+                           ).sum(-1)
+        if lens is None:
+            lens = (self.lens if state.cache_len is self.state.cache_len
+                    else host_read(state.cache_len, "cache_len", self))
+        self.state, self.lens = state, np.asarray(lens)
+        return self.lens
+
+    def flush_full_tails(self, state: ResidentState,
+                         lens: Optional[np.ndarray] = None) -> ResidentState:
         """Recompress rows whose tail page just filled into fresh pages.
 
-        Host-side, between steps.  A row needs flushing when its logical
-        page ``cache_len // Tp - 1`` is still unmapped but fully covered.
-        Encodes the whole tail leaf once per call (amortized: a row flushes
-        every ``tokens_per_page`` steps) and scatters only the needy rows.
-        Page-escape overflow raises :class:`ResidencyError` → demotion."""
+        Between steps.  ``lens`` is the caller's knowledge of
+        ``state.cache_len`` (the resident decode loop passes it); ``sync``
+        supplies what the host does not already know about ``state``.  A
+        row flushes when its length is a page multiple and the page it just
+        filled is not yet mapped.  Nothing else is read: the page ids are
+        popped from the free-list first, then one jitted program
+        (``_flush_pages``) encodes every leaf's tail and scatters the
+        flushing rows' pages, and the host reads its ``ok`` flag.  Pool
+        exhaustion or page-escape overflow raises :class:`ResidencyError`
+        (→ demotion) with the free-list as it was and ``state`` untouched."""
         g = self.geom
+        tp = g.tokens_per_page
         self.flushes += 1
-        lens = host_read(state.cache_len, "cache_len", self)
-        full_page = lens // g.tokens_per_page - 1            # (B,)
-        table0 = host_read(state.leaves[g.leaves[0].key].page_table,
-                           "page_table", self)
-        rows = [b for b in range(g.batch)
-                if lens[b] > 0 and lens[b] % g.tokens_per_page == 0
-                and table0[0, b, full_page[b]] < 0]
-        if not rows:
-            self.state = state
+        lens = self.sync(state, lens)
+        rows = np.flatnonzero((lens % tp == 0) & (self.mapped < lens // tp))
+        if not rows.size:
             return state
-        rows_np = np.array(rows)
-        # Phase 1: encode + overflow-check EVERY leaf before touching the
-        # free-list, so a failed flush leaves the pool exactly as it was
-        # (no leaked pages when a later leaf overflows).
-        staged = []
-        for lg in g.leaves:
-            leaf = state.leaves[lg.key]
-            ct = self.backend.encode(
-                leaf.tail.reshape(-1), self.codebook, chunk=g.chunk,
-                cap=lg.escape_cap, layout="chunked")
-            pc = lg.page_chunks
-            sm = ct.sign_mantissa.reshape(g.n_layers, g.batch, pc, g.chunk)
-            packed = ct.packed.reshape(g.n_layers, g.batch, pc, g.chunk // 2)
-            pos_c = ct.esc_pos.reshape(g.n_layers, g.batch, pc, -1)
-            val_c = ct.esc_val.reshape(g.n_layers, g.batch, pc, -1)
-            cnt_c = ct.esc_count.reshape(g.n_layers, g.batch, pc)
-            pos_pg, val_pg, cnt_pg = _page_escapes(
-                pos_c, val_c, cnt_c, chunk=g.chunk, page_chunks=pc,
-                cap_page=lg.escape_cap)
-            idx_l = np.repeat(np.arange(g.n_layers), len(rows))
-            idx_b = np.tile(rows_np, g.n_layers)
-            idx_p = full_page[idx_b]
-            cnts = host_read(cnt_pg, "esc_cnt", self)[idx_l, idx_b]
-            if (cnts > lg.escape_cap).any():
-                raise ResidencyError(
-                    f"leaf {lg.key!r}: tail recompress escape overflow "
-                    f"(max {int(cnts.max())} > cap {lg.escape_cap})")
-            staged.append((lg, sm, packed, pos_pg, val_pg, cnt_pg,
-                           idx_l, idx_b, idx_p))
-        # Phase 2: allocate + scatter; if a later leaf's allocation exhausts
-        # the pool, return the pages already popped for earlier leaves.
-        new_leaves = dict(state.leaves)
-        alloced = []
+        page = np.full((g.batch,), g.max_pages, np.int32)
+        page[rows] = lens[rows] // tp - 1
+        popped, pids = {}, {}
         try:
-            for (lg, sm, packed, pos_pg, val_pg, cnt_pg,
-                 idx_l, idx_b, idx_p) in staged:
-                leaf = state.leaves[lg.key]
-                pids = self._alloc(lg.key, len(idx_l))
-                alloced.append((lg.key, pids))
-                new_leaves[lg.key] = dataclasses.replace(
-                    leaf,
-                    sign_mantissa=leaf.sign_mantissa.at[pids].set(
-                        sm[idx_l, idx_b]),
-                    packed=leaf.packed.at[pids].set(packed[idx_l, idx_b]),
-                    esc_pos=leaf.esc_pos.at[pids, 0].set(pos_pg[idx_l, idx_b]),
-                    esc_val=leaf.esc_val.at[pids, 0].set(val_pg[idx_l, idx_b]),
-                    esc_cnt=leaf.esc_cnt.at[pids, 0].set(
-                        cnt_pg[idx_l, idx_b]),
-                    page_table=leaf.page_table.at[idx_l, idx_b, idx_p].set(
-                        pids))
+            for lg in g.leaves:
+                ids = popped[lg.key] = self._alloc(lg.key,
+                                                   g.n_layers * rows.size)
+                pid = np.full((g.n_layers, g.batch), lg.n_pages, np.int32)
+                pid[:, rows] = ids.reshape(g.n_layers, rows.size)
+                pids[lg.key] = pid
+            leaves, ok = _flush_pages(state.leaves, pids, page, geom=g,
+                                      backend=self.backend.name)
+            if not host_read(ok, "ok", self, bool):
+                raise ResidencyError(
+                    "tail recompress escape overflow (a flushed page holds "
+                    "more escapes than its leaf's escape_cap)")
         except ResidencyError:
-            for key, pids in alloced:
-                self._release(key, pids)
+            for key, ids in popped.items():
+                self._release(key, ids[::-1])   # the free-list's old order
             raise
-        self.state = dataclasses.replace(state, leaves=new_leaves)
+        self.page_flushes += 1
+        self.mapped[rows] = lens[rows] // tp
+        self.state = dataclasses.replace(state, leaves=leaves)
         return self.state
 
     # -- fallback / teardown ----------------------------------------------
@@ -629,6 +678,7 @@ class KVPool:
                 pt = pt.at[:, b, :].set(-1)
             new_leaves[lg.key] = dataclasses.replace(leaf, page_table=pt)
         self.state = dataclasses.replace(self.state, leaves=new_leaves)
+        self.mapped[list(rows)] = 0
 
     # -- accounting --------------------------------------------------------
 

@@ -492,8 +492,8 @@ def resident_decode_step(params, tokens: jax.Array, state, cfg: ArchConfig,
     ``state`` is a ``kvpool.ResidentState``: the prefix lives as splitzip
     pages consumed directly by the fused Pallas attention kernel (one
     ``pallas_call`` per layer), and the step only grows the raw tail pages —
-    the compressed pool is read-only here and tail flushes/recompression are
-    host-side between steps (``KVPool.flush_full_tails``).  Dense-GQA and MLA
+    the compressed pool is read-only here and tail flushes/recompression run
+    between steps (``KVPool.flush_full_tails``).  Dense-GQA and MLA
     families only; others decode raw-resident."""
     import dataclasses
 

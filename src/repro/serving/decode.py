@@ -7,6 +7,7 @@ lowers for the decode_* shape cells.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Dict, Tuple
 
@@ -51,9 +52,16 @@ def make_decode_fn(cfg: ArchConfig, num_steps: int):
 
 @functools.partial(jax.jit, static_argnums=(3, 4))
 def _resident_step(params, tok, state, cfg, interpret):
+    """One resident step; returns the logits, the new tails and lengths.
+
+    The page pools pass through a step unchanged, and an output of a jit is
+    a new buffer, so returning the whole state would copy every pool each
+    step; the caller puts the tails and lengths back into its state."""
     with jax.named_scope("resident_step"):
-        return M.resident_decode_step(params, tok, state, cfg,
-                                      interpret=interpret)
+        logits, new = M.resident_decode_step(params, tok, state, cfg,
+                                             interpret=interpret)
+    return logits, {k: leaf.tail for k, leaf in new.leaves.items()}, \
+        new.cache_len
 
 
 def resident_decode_loop(params, first_token: jax.Array, state, pool,
@@ -62,11 +70,15 @@ def resident_decode_loop(params, first_token: jax.Array, state, pool,
     """Greedy generation over a compressed-resident cache.
 
     A Python loop of one reused jitted step (page tables and tails are
-    fixed-shape, so every step hits the same executable) with a host-side
-    tail recompression between steps: rows whose raw tail page filled are
+    fixed-shape, so every step hits the same executable) with a tail
+    recompression between steps: rows whose raw tail page filled are
     flushed into fresh compressed pages through the registered backend
-    (``KVPool.flush_full_tails``).  The jitted step itself never touches the
-    codec — the fused kernel decodes pages in-register.
+    (``KVPool.flush_full_tails``).  Every step adds one to every row's
+    length, so the loop hands the flush the lengths after step ``i``
+    (``state``'s, from ``KVPool.sync``, + ``i`` + 1) and an ordinary step
+    reads nothing back.  The
+    jitted step itself never touches the codec — the fused kernel decodes
+    pages in-register.
 
     Escape overflow or pool exhaustion during a flush demotes the WHOLE
     batch: the pool rehydrates (bit-exact) to a raw ``DecodeState`` and the
@@ -77,15 +89,19 @@ def resident_decode_loop(params, first_token: jax.Array, state, pool,
     tok = first_token
     toks = []
     st = state
+    admitted = pool.sync(state)
     for i in range(num_steps):
         with span("resident.step", step=i):
-            logits, st = _resident_step(params, tok[:, None], st, cfg,
-                                        interpret)
+            logits, tails, cache_len = _resident_step(
+                params, tok[:, None], st, cfg, interpret)
+            st = dataclasses.replace(st, cache_len=cache_len, leaves={
+                k: dataclasses.replace(leaf, tail=tails[k])
+                for k, leaf in st.leaves.items()})
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         toks.append(tok)
         try:
             with span("resident.flush"):
-                st = pool.flush_full_tails(st)
+                st = pool.flush_full_tails(st, admitted + i + 1)
         except ResidencyError:
             cache = pool.rehydrate(st)
             dst = DecodeState(cache=cache, cache_len=st.cache_len)

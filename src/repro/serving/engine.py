@@ -90,11 +90,14 @@ class EngineStats:
     # host reads (``core.spans.host_read``): device-to-host reads made by the
     # transfer (the session's per-unit ``ok`` and wire-byte reads, the
     # cache-length read, the pool's admission reads) over ``transfer_calls``;
-    # and by the resident decode loop's tail flushes over ``resident_steps``
+    # and by the resident decode loop's tail flushes over ``resident_steps``,
+    # of which ``resident_page_flushes`` mapped pages (one jitted program and
+    # one ``ok`` read each)
     transfer_calls: int = 0
     transfer_host_reads: int = 0
     resident_steps: int = 0
     resident_host_reads: int = 0
+    resident_page_flushes: int = 0
 
     @property
     def resident_ratio(self) -> float:
@@ -391,12 +394,14 @@ class DisaggregatedEngine:
             if isinstance(state, ResidentState):
                 pool = self._pool
                 reads, flushes = pool.host_reads, pool.flushes
+                paged = pool.page_flushes
                 toks, _, demoted = resident_decode_loop(
                     self.params, first_token, state, pool, self.cfg,
                     num_steps)
                 self.stats.resident_demotions += int(demoted)
                 self.stats.resident_host_reads += pool.host_reads - reads
                 self.stats.resident_steps += pool.flushes - flushes
+                self.stats.resident_page_flushes += pool.page_flushes - paged
             else:
                 toks, _ = self._program("decode", num_steps)(
                     self.params, first_token, state)

@@ -385,6 +385,226 @@ class TestPool:
 
 
 # ---------------------------------------------------------------------------
+# the jitted page-boundary flush against the eager flush it replaced
+# ---------------------------------------------------------------------------
+
+def _eager_flush(pool, state):
+    """The reference: the flush as eager ops, deciding from device reads of
+    ``cache_len`` and leaf 0's page table, one overflow check per leaf."""
+    g = pool.geom
+    lens = np.asarray(state.cache_len)
+    full_page = lens // g.tokens_per_page - 1
+    table0 = np.asarray(state.leaves[g.leaves[0].key].page_table)
+    rows = [b for b in range(g.batch)
+            if lens[b] > 0 and lens[b] % g.tokens_per_page == 0
+            and table0[0, b, full_page[b]] < 0]
+    if not rows:
+        return state
+    rows_np = np.array(rows)
+    staged = []
+    for lg in g.leaves:
+        leaf = state.leaves[lg.key]
+        ct = pool.backend.encode(
+            leaf.tail.reshape(-1), pool.codebook, chunk=g.chunk,
+            cap=lg.escape_cap, layout="chunked")
+        pc = lg.page_chunks
+        sm = ct.sign_mantissa.reshape(g.n_layers, g.batch, pc, g.chunk)
+        packed = ct.packed.reshape(g.n_layers, g.batch, pc, g.chunk // 2)
+        pos_pg, val_pg, cnt_pg = KVP._page_escapes(
+            ct.esc_pos.reshape(g.n_layers, g.batch, pc, -1),
+            ct.esc_val.reshape(g.n_layers, g.batch, pc, -1),
+            ct.esc_count.reshape(g.n_layers, g.batch, pc), chunk=g.chunk,
+            page_chunks=pc, cap_page=lg.escape_cap)
+        idx_l = np.repeat(np.arange(g.n_layers), len(rows))
+        idx_b = np.tile(rows_np, g.n_layers)
+        idx_p = full_page[idx_b]
+        cnts = np.asarray(cnt_pg)[idx_l, idx_b]
+        if (cnts > lg.escape_cap).any():
+            raise KVP.ResidencyError(f"leaf {lg.key!r}: escape overflow")
+        staged.append((lg, sm, packed, pos_pg, val_pg, cnt_pg,
+                       idx_l, idx_b, idx_p))
+    new_leaves = dict(state.leaves)
+    for (lg, sm, packed, pos_pg, val_pg, cnt_pg,
+         idx_l, idx_b, idx_p) in staged:
+        leaf = state.leaves[lg.key]
+        pids = pool._alloc(lg.key, len(idx_l))
+        new_leaves[lg.key] = dataclasses.replace(
+            leaf,
+            sign_mantissa=leaf.sign_mantissa.at[pids].set(sm[idx_l, idx_b]),
+            packed=leaf.packed.at[pids].set(packed[idx_l, idx_b]),
+            esc_pos=leaf.esc_pos.at[pids, 0].set(pos_pg[idx_l, idx_b]),
+            esc_val=leaf.esc_val.at[pids, 0].set(val_pg[idx_l, idx_b]),
+            esc_cnt=leaf.esc_cnt.at[pids, 0].set(cnt_pg[idx_l, idx_b]),
+            page_table=leaf.page_table.at[idx_l, idx_b, idx_p].set(pids))
+    return dataclasses.replace(state, leaves=new_leaves)
+
+
+def _bits(x):
+    """Integer view of an array (bit-exact compare): floats bitcast to the
+    unsigned integer of their width, integers as they are."""
+    if jnp.issubdtype(x.dtype, jnp.integer):
+        return np.asarray(x)
+    u = jnp.uint16 if jnp.dtype(x.dtype).itemsize == 2 else jnp.uint8
+    return np.asarray(jax.lax.bitcast_convert_type(x, u))
+
+
+def _leaf_cache(kind, L=2, B=4, S=64, seed=0):
+    """(cache, codebook, page_bytes) for one leaf geometry, 16 tokens a
+    page in each: GQA bf16 (k, v), MLA bf16 (a latent and a narrower rope
+    leaf), and GQA with float8 e5m2 leaves."""
+    rng = np.random.default_rng(seed)
+    if kind == "mla-bf16":
+        cache = {"ckv": jnp.asarray(rng.standard_normal((L, B, S, 128)),
+                                    jnp.bfloat16),
+                 "krope": jnp.asarray(rng.standard_normal((L, B, S, 64)),
+                                      jnp.bfloat16)}
+        return cache, _calibrate(cache), 4096
+    cache = _dense_cache(L=L, B=B, S=S, seed=seed)
+    if kind == "gqa-bf16":
+        return cache, _calibrate(cache), 2048
+    cache = {k: v.astype(jnp.float8_e5m2) for k, v in cache.items()}
+    cb = cbm.calibrate([_bits(v).ravel() for v in cache.values()], k=16,
+                       fmt="fp8_e5m2")
+    return cache, cb, 1024
+
+
+def _admit_pair(cache, cb, page_bytes, lens):
+    """Two pools of one geometry admitting the same wire streams."""
+    be = resolve_backend("xla", require_jittable=True)
+    comp = {k: be.encode(v, cb, chunk=CHUNK, layout="chunked")
+            for k, v in cache.items()}
+    out = []
+    for _ in range(2):
+        pool = KVP.KVPool.for_cache(cache, cb, be, chunk=CHUNK,
+                                    page_bytes=page_bytes)
+        out.append((pool, pool.admit_from_wire(comp, lens)))
+    return out
+
+
+class TestJittedFlush:
+    @pytest.mark.parametrize("kind", ["gqa-bf16", "mla-bf16", "fp8"])
+    def test_matches_eager_flush_bit_exact(self, kind):
+        """Appends and flushes across two page boundaries, some rows
+        flushing and some not at each, one of them with escapes: after
+        every boundary the jitted flush's streams, escapes, page tables and
+        free-list equal the eager flush's bit for bit, and both rehydrate
+        to the grown cache."""
+        cache, cb, page_bytes = _leaf_cache(kind)
+        tp = 16
+        # rows 0 and 1 fill a page together, rows 2 and 3 on their own
+        start = np.array([tp - 1, 2 * tp - 1, tp // 2, 3])
+        (pj, sj), (pe, se) = _admit_pair(cache, cb, page_bytes,
+                                         jnp.asarray(start, jnp.int32))
+        g = pj.geom
+        assert g.tokens_per_page == tp and g.leaves[0].fmt == cb.fmt
+        np.testing.assert_array_equal(pj.lens, start)
+        grown = {k: _bits(v).copy() for k, v in cache.items()}
+        rng = np.random.default_rng(5)
+        lens = start.copy()
+        boundaries = 0
+        for step in range(tp + 2):
+            new = {}
+            for lg in g.leaves:
+                x = rng.standard_normal((g.n_layers, g.batch, lg.m))
+                if step % 3 == 0:
+                    x[:, :, 0] = 3e4 if kind == "fp8" else 1e30  # escapes
+                new[lg.key] = jnp.asarray(x, jnp.bfloat16).astype(
+                    jnp.dtype(lg.dtype))
+                for row in range(g.batch):
+                    grown[lg.key][:, row, lens[row]] = _bits(
+                        new[lg.key][:, row]).reshape(
+                            g.n_layers, *grown[lg.key].shape[3:])
+            sj = _append_rows(pj, sj, rng, values=new)
+            se = _append_rows(pe, se, rng, values=new)
+            lens += 1
+            sj = pj.flush_full_tails(sj, lens)
+            se = _eager_flush(pe, se)
+            if not (lens % tp == 0).any():
+                continue
+            boundaries += 1
+            assert 0 < (lens % tp == 0).sum() < g.batch
+            for lg in g.leaves:
+                for x, y in zip(jax.tree.leaves(sj.leaves[lg.key]),
+                                jax.tree.leaves(se.leaves[lg.key])):
+                    np.testing.assert_array_equal(_bits(x), _bits(y))
+            assert pj._free == pe._free
+            for pool, st in ((pj, sj), (pe, se)):
+                reh = pool.rehydrate(st)
+                for key in grown:
+                    got = _bits(reh[key])
+                    for row in range(g.batch):
+                        np.testing.assert_array_equal(
+                            got[:, row, :lens[row]],
+                            grown[key][:, row, :lens[row]],
+                            err_msg=f"{key} row {row}")
+        assert boundaries >= 2
+        assert pj.page_flushes == boundaries
+        np.testing.assert_array_equal(pj.mapped, lens // tp)
+        np.testing.assert_array_equal(pj.lens, np.asarray(sj.cache_len))
+
+    @pytest.mark.parametrize("pass_lens", [False, True])
+    def test_flush_of_earlier_snapshot_matches_eager(self, pass_lens):
+        """A state the pool did not make last (here an earlier snapshot,
+        flushed a second time after the pool moved on) is flushed by its
+        own page table and lengths, as the eager flush does: pages, page
+        tables and free-list equal the eager flush's bit for bit."""
+        cache, cb, page_bytes = _leaf_cache("gqa-bf16")
+        tp = 16
+        start = jnp.asarray([tp - 1, 3, 2 * tp - 1, 7], jnp.int32)
+        (pj, sj), (pe, se) = _admit_pair(cache, cb, page_bytes, start)
+        rng = np.random.default_rng(8)
+        new = {lg.key: jnp.asarray(
+            rng.standard_normal((pj.geom.n_layers, pj.geom.batch, lg.m)),
+            jnp.bfloat16) for lg in pj.geom.leaves}
+        snap_j = _append_rows(pj, sj, rng, values=new)
+        snap_e = _append_rows(pe, se, rng, values=new)
+        lens = np.asarray(start) + 1
+        first_j = pj.flush_full_tails(snap_j, lens if pass_lens else None)
+        first_e = _eager_flush(pe, snap_e)
+        assert pj.page_flushes == 1
+        np.testing.assert_array_equal(pj.mapped, lens // tp)
+        again_j = pj.flush_full_tails(snap_j, lens if pass_lens else None)
+        again_e = _eager_flush(pe, snap_e)
+        assert pj.page_flushes == 2                 # the snapshot flushed
+        for (xj, xe) in ((first_j, first_e), (again_j, again_e)):
+            for lg in pj.geom.leaves:
+                for x, y in zip(jax.tree.leaves(xj.leaves[lg.key]),
+                                jax.tree.leaves(xe.leaves[lg.key])):
+                    np.testing.assert_array_equal(_bits(x), _bits(y))
+        assert pj._free == pe._free
+        np.testing.assert_array_equal(pj.lens, lens)
+        # the snapshot's one-page-short rows are mapped as its table says
+        np.testing.assert_array_equal(pj.mapped, lens // tp)
+
+    def test_compiles_once_per_geometry(self, monkeypatch):
+        """Two pools of one geometry, one boundary flush each: the flush
+        program is traced once (a geometry no other test uses)."""
+        cache = _dense_cache(L=1, B=2, S=48, seed=4)
+        cb = _calibrate(cache)
+        traced = []
+        real = KVP._page_escapes
+
+        def counting(*args, **kwargs):
+            traced.append(1)
+            return real(*args, **kwargs)
+
+        monkeypatch.setattr(KVP, "_page_escapes", counting)
+        before = KVP._flush_pages._cache_size()
+        per_pool = []
+        for pool, rs in _admit_pair(cache, cb, 2048,
+                                    jnp.asarray([15, 3], jnp.int32)):
+            rs = _append_rows(pool, rs, np.random.default_rng(1))
+            del traced[:]                        # admission's own calls
+            pool.flush_full_tails(rs, pool.lens + 1)
+            assert pool.page_flushes == 1
+            per_pool.append(len(traced))
+        # the one trace calls _page_escapes once per leaf; the second pool's
+        # flush runs the cached executable
+        assert per_pool == [len(cache), 0]
+        assert KVP._flush_pages._cache_size() - before == 1
+
+
+# ---------------------------------------------------------------------------
 # fused attention over pages
 # ---------------------------------------------------------------------------
 
@@ -788,7 +1008,7 @@ class TestEngineResident:
         orig = pool.flush_full_tails
         state = {"failed": False}
 
-        def failing(st):
+        def failing(st, *args, **kwargs):
             lens_ = np.asarray(st.cache_len)
             table0 = np.asarray(
                 st.leaves[pool.geom.leaves[0].key].page_table)
@@ -799,7 +1019,7 @@ class TestEngineResident:
             if needs and not state["failed"]:
                 state["failed"] = True
                 raise KVP.ResidencyError("injected flush failure")
-            return orig(st)
+            return orig(st, *args, **kwargs)
 
         pool.flush_full_tails = failing
         first = jnp.asarray(rng.integers(0, cfg.vocab_size, (2,)),
@@ -811,6 +1031,32 @@ class TestEngineResident:
         toks_raw, _ = D.decode_loop(params, first, st0, cfg, n)
         np.testing.assert_array_equal(np.asarray(toks_res),
                                       np.asarray(toks_raw))
+
+    def test_decode_same_state_twice(self):
+        """Decoding one admitted ``ResidentState`` twice through the engine
+        (the second time after the pool's pages and lengths moved on) gives
+        the same tokens, page flushes included, and the pool's final states
+        rehydrate to the same cache bit for bit."""
+        cfg, params, batch, cb = self._setup()
+        eng = DisaggregatedEngine(cfg, params, cb, resident="compressed",
+                                  page_bytes=2048)
+        pre = eng.prefill(batch, max_seq=64)
+        rst = eng.transfer(pre.state)
+        assert isinstance(rst, KVP.ResidentState)
+        tp = eng._pool.geom.tokens_per_page
+        n = tp + 2                                 # crosses >=1 boundary
+        runs = []
+        for _ in range(2):
+            toks = np.asarray(eng.decode(pre.first_token, rst, n))
+            pool = eng._pool
+            runs.append((toks, pool.rehydrate(pool.state),
+                         np.asarray(pool.state.cache_len)))
+            np.testing.assert_array_equal(pool.lens, runs[-1][2])
+        assert eng.stats.resident_demotions == 0
+        assert eng.stats.resident_page_flushes >= 2
+        np.testing.assert_array_equal(runs[0][0], runs[1][0])
+        np.testing.assert_array_equal(runs[0][2], runs[1][2])
+        _assert_cache_equal(runs[0][1], runs[1][1], lens=runs[0][2])
 
     def test_hbm_derived_decode_slots(self):
         """SchedulerConfig.derived_decode_slots: the compressed-resident

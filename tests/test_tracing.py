@@ -95,21 +95,24 @@ def _serve(model, resident):
     pre = eng.prefill(model["batch"], max_seq=model["max_seq"])
     got = eng.transfer(pre.state)
     toks = jax.block_until_ready(eng.decode(pre.first_token, got, STEPS))
-    return np.asarray(toks), dataclasses.asdict(eng.stats)
+    pool = eng._pool
+    lens = None if pool is None else (pool.lens,
+                                      np.asarray(pool.state.cache_len))
+    return np.asarray(toks), dataclasses.asdict(eng.stats), lens
 
 
 @pytest.fixture(scope="module", params=["raw", "compressed"])
 def served(request, model):
     """One batch served with the profiler off, then the same batch on a
     fresh engine with it on (the programs compiled by then)."""
-    plain = _serve(model, request.param)
+    plain = _serve(model, request.param)[:2]
     with tempfile.TemporaryDirectory() as d:
         with jax.profiler.trace(d):
             with jax.profiler.TraceAnnotation(CALLER):
-                traced = _serve(model, request.param)
+                *traced, lens = _serve(model, request.param)
         events = _host_events(d)
-    return dict(model, resident=request.param, traced=traced, plain=plain,
-                events=events)
+    return dict(model, resident=request.param, traced=tuple(traced),
+                plain=plain, lens=lens, events=events)
 
 
 def _sz(events):
@@ -204,22 +207,30 @@ def test_transfer_host_reads_hand_count(served):
 
 
 def test_resident_host_reads_hand_count(served):
-    """Every flush reads the cache lengths and the page table; a flush that
-    maps a filled tail page also reads each leaf's page escape counts."""
+    """The flush decides from the lengths and page map the host keeps, so a
+    step that fills no tail page reads nothing; a flush that maps a filled
+    tail page reads one ``ok`` flag for all leaves. The host's lengths end
+    equal to the device's."""
     _, stats = served["traced"]
     if served["resident"] == "raw":
         assert stats["resident_steps"] == stats["resident_host_reads"] == 0
+        assert stats["resident_page_flushes"] == 0
         return
     flushing = sum((served["prompt"] + 1 + i) % served["tp"] == 0
                    for i in range(STEPS))
     assert flushing == 1
-    want = 2 * STEPS + served["leaves"] * flushing
+    want = flushing
     assert stats["resident_steps"] == STEPS
+    assert stats["resident_page_flushes"] == flushing
     assert stats["resident_host_reads"] == want
+    host_lens, device_lens = served["lens"]
+    np.testing.assert_array_equal(host_lens, device_lens)
+    np.testing.assert_array_equal(host_lens, served["prompt"] + STEPS)
     ev = served["events"]
     decode = _one(ev, "sz.decode")
     reads = [e for e in ev if e.name == "sz.host_read" and e.inside(decode)]
     assert len(reads) == want
+    assert {e.stats["what"] for e in reads} == {"ok"}
     flushes = [e for e in ev if e.name == "sz.resident.flush"]
     assert all(any(r.inside(f) for f in flushes) for r in reads)
 
