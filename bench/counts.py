@@ -66,11 +66,14 @@ def paged_attention_step(*, rows: int, heads: int, head_dim: int,
                          dv: int, full_pages: Iterable[int],
                          tokens_per_page: int, page_bytes_kv: int
                          ) -> Tuple[float, float]:
-    """(FLOPs, bytes) of one call of the paged GQA kernel, one layer.
+    """(FLOPs, bytes) of one call of a paged attention kernel, one layer.
 
-    ``full_pages`` holds each row's compressed pages in use; the kernel reads
-    each such page of K and V once (``page_bytes_kv`` for the pair), the
-    bf16 queries, and writes f32 partials (acc, max, sum)."""
+    Each query head scores ``head_dim``-wide queries against every token of
+    the row's pages and sums ``dv``-wide values. ``full_pages`` holds each
+    row's compressed pages in use; the kernel reads each such page of every
+    paged leaf once (``page_bytes_kv`` for the set: K and V, or the latent
+    and its rope key), the bf16 queries, and writes f32 partials (acc, max,
+    sum)."""
     pages = sum(full_pages)
     tokens = pages * tokens_per_page
     flops = 2.0 * heads * (head_dim + dv) * tokens

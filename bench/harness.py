@@ -145,7 +145,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     dev = jax.devices()[0]
     peak = peak or counts.peaks(dev.device_kind)
 
-    served = program.Served(conf, mix, seed)
+    served = program.Served(conf, mix, seed, cell.root)
     gen = traffic.ClosedLoop(mix, conf["vocab_size"], seed)
     log(f"cell {cell.name}: backend {served.backend}, max_seq "
         f"{served.max_seq}, {gen.batch} x {gen.prompt_tokens} prompt tokens, "

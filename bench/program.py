@@ -1,11 +1,11 @@
 """The system under test, built as its own entry points build it.
 
-This is the only file of the benchmark that imports the program (``repro``,
-from ``src/``). It maps a configuration file onto the program's
-``ArchConfig``, draws the weights on the device in one jitted call of the
-program's ``init_params``, calibrates the codebook as ``launch/serve.py``
-does, and builds one ``DisaggregatedEngine`` whose three stages the harness
-drives.
+This file and the family files (``bench/families/<family>.py``, which map a
+configuration file onto the program's ``ArchConfig``) are the only files of
+the benchmark that import the program (``repro``, from ``src/``). It draws
+the weights on the device in one jitted call of the program's
+``init_params``, calibrates the codebook as ``launch/serve.py`` does, and
+builds one ``DisaggregatedEngine`` whose three stages the harness drives.
 """
 
 from __future__ import annotations
@@ -16,9 +16,10 @@ from pathlib import Path
 
 import jax
 
-from bench import seeds
+from bench import seeds, spec
 
-SRC = Path(__file__).resolve().parents[1] / "src"
+ROOT = Path(__file__).resolve().parents[1]
+SRC = ROOT / "src"
 
 
 def _importable() -> None:
@@ -27,41 +28,26 @@ def _importable() -> None:
         sys.path.insert(0, str(SRC))
 
 
-def arch_config(conf: dict):
-    """The program's ``ArchConfig`` for a configuration file."""
+def arch_config(conf: dict, root: Path = ROOT):
+    """The program's ``ArchConfig`` for a configuration file: the mapping of
+    its family, ``<root>/bench/families/<family>.py``."""
     _importable()
-    from repro.configs.base import ArchConfig, MLAConfig
-    d, h = conf["hidden_size"], conf["num_attention_heads"]
-    kw = dict(name=conf.get("model_type", "model"), family="dense",
-              num_layers=conf["num_hidden_layers"], d_model=d, num_heads=h,
-              num_kv_heads=conf["num_key_value_heads"],
-              d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-              rope_theta=conf["rope_theta"], norm_eps=conf["rms_norm_eps"],
-              tie_embeddings=bool(conf.get("tie_word_embeddings", False)))
-    if conf["family"] == "gqa":
-        kw["head_dim"] = conf["head_dim"]
-    elif conf["family"] == "mla":
-        kw["head_dim"] = d // h
-        kw["mla"] = MLAConfig(
-            q_lora_rank=conf["q_lora_rank"],
-            kv_lora_rank=conf["kv_lora_rank"],
-            qk_nope_head_dim=conf["qk_nope_head_dim"],
-            qk_rope_head_dim=conf["qk_rope_head_dim"], v_head_dim=d // h)
-    else:
-        raise ValueError(f"family {conf['family']!r}: known gqa, mla")
-    return ArchConfig(**kw)
+    path = root / "bench" / "families" / f"{conf['family']}.py"
+    if not path.is_file():
+        raise ValueError(f"family {conf['family']!r}: no file {path}")
+    return spec.load_module(path).arch_config(conf)
 
 
 class Served:
     """One engine serving one cell's traffic, and what the check needs."""
 
-    def __init__(self, conf: dict, mix: dict, seed: int):
+    def __init__(self, conf: dict, mix: dict, seed: int, root: Path = ROOT):
         _importable()
         from repro.launch.serve import calibrate_on_model
         from repro.models import model as M
         from repro.serving.engine import DisaggregatedEngine
 
-        self.cfg = arch_config(conf)
+        self.cfg = arch_config(conf, root)
         cfg = self.cfg
         self.params = jax.block_until_ready(jax.jit(
             lambda k: M.init_params(cfg, k))(seeds.key(seed, "weights")))

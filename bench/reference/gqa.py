@@ -119,3 +119,14 @@ def decode_step(c, batch: int, ctx: int):
 def attention_flops(c, rows: int, tokens: int) -> float:
     """QK^T and PV of one layer's decode attention over ``tokens`` keys."""
     return rows * 2 * 2 * c["num_attention_heads"] * c["head_dim"] * tokens
+
+
+# -- the paged kernel of the compressed-resident decode ---------------------
+
+paged_kernel = "paged_gqa_attention"
+
+
+def paged_dims(c):
+    """(heads, query width, value width) of the paged kernel: each query
+    head scores its KV head's keys and sums its values."""
+    return c["num_attention_heads"], c["head_dim"], c["head_dim"]

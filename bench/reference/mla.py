@@ -134,3 +134,16 @@ def attention_flops(c, rows: int, tokens: int) -> float:
     h, r, rp = (c["num_attention_heads"], c["kv_lora_rank"],
                 c["qk_rope_head_dim"])
     return rows * 2 * h * (r + rp + r) * tokens
+
+
+# -- the paged kernel of the compressed-resident decode ---------------------
+
+paged_kernel = "paged_mla_attention"
+
+
+def paged_dims(c):
+    """(heads, query width, value width) of the paged kernel in its absorbed
+    form: scores are q_lat . c + q_rope . k_r over the latent and rope pages,
+    and the context is summed over the latent c."""
+    r = c["kv_lora_rank"]
+    return c["num_attention_heads"], r + c["qk_rope_head_dim"], r

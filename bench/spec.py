@@ -4,14 +4,18 @@ Everything that belongs to one configuration, one traffic mix, one cell or
 one per-layer metric sits in a file of its own, found by its name:
 
     bench/configs/<config>.json      sizes of the model as it is run
-    bench/reference/<family>.py      the plain float32 reference of a family
+    bench/families/<family>.py       the program's configuration of a family
+                                     (with ``program.py``, the only files
+                                     that import the program)
+    bench/reference/<family>.py      the plain float32 reference of a family,
+                                     its counts and its paged kernel
     bench/traffic/<traffic>.json     parameters of a traffic mix
     bench/limits/<cell>.json         the limits of the comparison that
                                      decides ``correct`` in a cell
     bench/metrics/<metric>.py        the reader of a per-layer metric
 
-so a new configuration, mix, cell or metric is new files plus new entries in
-``BENCHMARK.json``, and no existing file changes.
+so a new family, configuration, mix, cell or metric is new files plus new
+entries in ``BENCHMARK.json``, and no existing file changes.
 """
 
 from __future__ import annotations
