@@ -10,15 +10,67 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
+import math
 from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """A mixture-of-experts FFN as one chip holds it.
+
+    The router spans all ``num_experts`` (the published count) and picks
+    ``top_k`` per token; the chip holds experts ``expert_offset ..
+    expert_offset + held - 1`` (all of them when ``num_held`` is None) and
+    computes their part of the result, plus ``n_shared`` always-on experts.
+    ``scoring`` "softmax" is Qwen3-MoE's router; "sigmoid" with ``n_group``
+    groups, of which the ``topk_group`` best (each scored by its two best
+    experts) are kept, is DeepSeek-V3's. The chosen weights are renormalized
+    to sum to one and multiplied by ``routed_scale``. The first
+    ``first_k_dense`` layers of the model have a dense MLP instead.
+    ``bias_std`` sets the random draw that stands in for the sigmoid router's
+    trained correction bias."""
     num_experts: int = 128
     top_k: int = 8
     d_ff_expert: int = 1536          # per-expert FFN hidden
-    capacity_factor: float = 1.25    # dispatch slot headroom
+    num_held: Optional[int] = None   # experts held here (None: all)
+    expert_offset: int = 0           # the first held expert
+    n_shared: int = 0                # shared experts, d_ff_expert wide each
+    scoring: str = "softmax"         # softmax | sigmoid
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scale: float = 1.0
+    first_k_dense: int = 0
+    bias_std: float = 0.0            # drawn correction bias (sigmoid): N(0, std^2)
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.num_held is None else self.num_held
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN RoPE scaling (``rope_scaling`` of type "yarn"): rotary
+    frequencies blended between the extrapolated and the interpolated
+    (divided by ``factor``) ones over the dimensions between ``beta_fast``
+    and ``beta_slow`` rotations at ``original_max_position``."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+
+    def _m(self, m: float) -> float:
+        return 0.1 * m * math.log(self.factor) + 1.0 if self.factor > 1 else 1.0
+
+    @property
+    def cos_sin_scale(self) -> float:
+        return self._m(self.mscale) / self._m(self.mscale_all_dim)
+
+    @property
+    def softmax_mult(self) -> float:
+        """Factor on the attention's softmax scale: mscale(all_dim)^2."""
+        return self._m(self.mscale_all_dim) ** 2 if self.mscale_all_dim else 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -28,6 +80,12 @@ class MLAConfig:
     qk_nope_head_dim: int = 64
     qk_rope_head_dim: int = 32
     v_head_dim: int = 64
+    yarn: Optional[YaRN] = None      # None: plain RoPE
+
+    @property
+    def softmax_scale(self) -> float:
+        s = 1.0 / math.sqrt(self.qk_nope_head_dim + self.qk_rope_head_dim)
+        return s * self.yarn.softmax_mult if self.yarn else s
 
 
 @dataclasses.dataclass(frozen=True)
@@ -108,11 +166,15 @@ class ArchConfig:
         else:
             per_attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd \
                 + self.num_heads * hd * d
+        per_ffn = 3 * d * self.d_ff
         if self.moe is not None:
-            per_ffn = (d * self.moe.num_experts
-                       + self.moe.num_experts * 3 * d * self.moe.d_ff_expert)
-        else:
-            per_ffn = 3 * d * self.d_ff
+            m = self.moe
+            bias = m.num_experts if m.scoring == "sigmoid" else 0
+            per_moe = (d * m.num_experts + bias
+                       + (m.held + m.n_shared) * 3 * d * m.d_ff_expert)
+            n += m.first_k_dense * (per_attn + per_ffn)
+            l -= m.first_k_dense
+            per_ffn = per_moe
         if self.hybrid is not None:
             h = self.hybrid
             lru = h.lru_width or d
@@ -126,12 +188,13 @@ class ArchConfig:
         return n
 
     def active_param_count(self) -> int:
-        """MoE: only top_k of num_experts fire per token."""
+        """MoE: only top_k of the held experts fire per token."""
         if self.moe is None:
             return self.param_count()
-        d, l = self.d_model, self.num_layers
-        dense = self.param_count() - l * self.moe.num_experts * 3 * d * self.moe.d_ff_expert
-        return dense + l * self.moe.top_k * 3 * d * self.moe.d_ff_expert
+        m, d = self.moe, self.d_model
+        l = self.num_layers - m.first_k_dense
+        routed = 3 * d * m.d_ff_expert
+        return self.param_count() - l * (m.held - min(m.top_k, m.held)) * routed
 
     def with_layers(self, num_layers: int) -> "ArchConfig":
         """Same config at a different depth (dry-run cost extrapolation).
@@ -156,10 +219,7 @@ class ArchConfig:
             frontend_len=8,
         )
         if self.moe is not None:
-            # capacity_factor high enough to be dropless at smoke-test sizes,
-            # so decode-vs-forward consistency is exact
-            kw["moe"] = MoEConfig(num_experts=8, top_k=2, d_ff_expert=64,
-                                  capacity_factor=8.0)
+            kw["moe"] = MoEConfig(num_experts=8, top_k=2, d_ff_expert=64)
         if self.mla is not None:
             kw["mla"] = MLAConfig(q_lora_rank=32, kv_lora_rank=16,
                                   qk_nope_head_dim=16, qk_rope_head_dim=8,

@@ -134,16 +134,6 @@ class ShardingPolicy:
                 return None
             t = self._maybe(shape[0], dp)
             return P(t, None)
-        if kind == "moe_ecd":        # (E, C, D) expert compute buffers
-            if not self.moe_dispatch_sharding:
-                return None
-            e = self._maybe(shape[0], tp)
-            return P(e, None, None)
-        if kind == "moe_ecf":        # (E, C, F) expert hidden activations
-            if not self.moe_dispatch_sharding:
-                return None
-            e = self._maybe(shape[0], tp)
-            return P(e, None, None)
         raise KeyError(f"unknown activation kind {kind!r}")
 
     def spec_for_cache(self, name: str, shape: Tuple[int, ...]) -> P:

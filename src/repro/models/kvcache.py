@@ -37,9 +37,15 @@ class DecodeState:
     ``models.model.prefill`` builds it from ``batch['lengths']`` (scoring
     each row's logits at its own last real token).  The compressed-resident
     analogue is :class:`repro.models.kvpool.ResidentState`, which carries
-    the same (B,) vector next to page tables instead of a raw cache."""
+    the same (B,) vector next to page tables instead of a raw cache.
+
+    ``moe`` carries a mixture-of-experts model's routing counts on the
+    device (``models.model.moe_counts``): prefill sets them to its own, each
+    decode step adds its own, and a transfer passes them on. None where no
+    count is kept: a model without MoE, or a state built by hand."""
     cache: dict
     cache_len: jax.Array  # (B,) int32 — valid prefix length per row
+    moe: Optional[dict] = None
 
     def valid_mask(self, max_seq: Optional[int] = None) -> jax.Array:
         """(B, S) bool — True where the cache holds a real token.  Only

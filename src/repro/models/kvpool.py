@@ -797,7 +797,7 @@ def paged_mla_decode(p, x, ckv_streams, kr_streams, pt_c, pt_r,
     w_knope = p["wkv_b"][..., : cfg.qk_nope_head_dim]            # (r, H, n)
     w_v = p["wkv_b"][..., cfg.qk_nope_head_dim:]                 # (r, H, v)
     q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, w_knope)
-    scale = 1.0 / np.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    scale = cfg.softmax_scale
 
     acc, m, l = SA.paged_mla_attention(
         q_lat, q_rope, ckv_streams, kr_streams, pt_c, pt_r, cache_len,

@@ -40,17 +40,36 @@ def init_rms_norm(d: int) -> jax.Array:
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_frequencies(head_dim: int, theta: float) -> np.ndarray:
-    return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+def rope_frequencies(head_dim: int, theta: float, yarn=None) -> np.ndarray:
+    """Rotary frequencies; with a ``configs.base.YaRN``, the YaRN blend:
+    dimensions that turn more than ``beta_fast`` times over the original
+    context keep their frequency, those that turn fewer than ``beta_slow``
+    times are divided by ``factor``, and a linear ramp joins the two."""
+    freqs = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+    if yarn is None:
+        return freqs
+
+    def dim_of(rotations):      # the dimension that turns ``rotations`` times
+        return (head_dim * np.log(yarn.original_max_position
+                                  / (rotations * 2 * np.pi))
+                / (2 * np.log(theta)))
+
+    lo = max(np.floor(dim_of(yarn.beta_fast)), 0)
+    hi = min(np.ceil(dim_of(yarn.beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(head_dim // 2) - lo) / max(hi - lo, 1e-3), 0, 1)
+    return freqs / yarn.factor * ramp + freqs * (1 - ramp)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn=None) -> jax.Array:
     """x: (..., S, H, D) rotated by position; positions: broadcastable to (..., S)."""
     d = x.shape[-1]
-    freqs = jnp.asarray(rope_frequencies(d, theta), jnp.float32)        # (D/2,)
+    freqs = jnp.asarray(rope_frequencies(d, theta, yarn), jnp.float32)  # (D/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs           # (..., S, D/2)
     cos = jnp.cos(angles)[..., None, :]                                 # (..., S, 1, D/2)
     sin = jnp.sin(angles)[..., None, :]
+    if yarn is not None and yarn.cos_sin_scale != 1.0:
+        cos, sin = cos * yarn.cos_sin_scale, sin * yarn.cos_sin_scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

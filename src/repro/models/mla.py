@@ -4,6 +4,10 @@ The KV cache is the *compressed latent*: per token only
 (kv_lora_rank + qk_rope_head_dim) values — this is what crosses the PD
 boundary and what SplitZip compresses (DESIGN.md §4).
 
+RoPE on the rope parts is YaRN-scaled when ``MLAConfig.yarn`` is set
+(DeepSeek-V3), which also multiplies the softmax scale by mscale²
+(``MLAConfig.softmax_scale``).
+
 Prefill uses the naive expanded form (latent -> per-head K/V, chunked
 attention).  Decode uses the **absorbed form**: the k_nope projection is
 folded into the query and the v projection into the output, so per-step cost
@@ -16,7 +20,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import MLAConfig
 from repro.distributed.sharding import constrain
@@ -47,7 +50,8 @@ def _queries(p, x, positions, cfg: MLAConfig, theta: float):
     q_lat = rms_norm(jnp.einsum("bsd,dr->bsr", x, p["wq_a"]), p["q_norm"])
     q = jnp.einsum("bsr,rhk->bshk", q_lat, p["wq_b"])
     q_nope = q[..., : cfg.qk_nope_head_dim]
-    q_rope = apply_rope(q[..., cfg.qk_nope_head_dim:], positions, theta)
+    q_rope = apply_rope(q[..., cfg.qk_nope_head_dim:], positions, theta,
+                        cfg.yarn)
     return q_nope, q_rope
 
 
@@ -55,7 +59,8 @@ def _latent_kv(p, x, positions, cfg: MLAConfig, theta: float):
     kv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])
     c_kv = rms_norm(kv[..., : cfg.kv_lora_rank], p["kv_norm"])
     k_rope = kv[..., cfg.kv_lora_rank:]                         # (B, S, rope)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, theta)[:, :, 0, :]
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, theta,
+                        cfg.yarn)[:, :, 0, :]
     return c_kv, k_rope
 
 
@@ -78,8 +83,8 @@ def mla_prefill(p, x, positions, cfg: MLAConfig, theta: float,
     # on every model shard (16x waste; EXPERIMENTS.md §Perf Cell A).
     k = constrain(k, "bthd")
     q = constrain(jnp.concatenate([q_nope, q_rope], axis=-1), "bthd")
-    o = constrain(chunked_attention(q, k, v, causal=True, kv_block=kv_block),
-                  "bthd")
+    o = constrain(chunked_attention(q, k, v, causal=True, kv_block=kv_block,
+                                    scale=cfg.softmax_scale), "bthd")
     out = jnp.einsum("bshv,hvd->bsd", o, p["wo"])
     return out, (c_kv, k_rope)
 
@@ -104,7 +109,7 @@ def mla_decode(p, x, cache_ckv, cache_krope, cache_len, cfg: MLAConfig,
 
     # absorb: q_lat[h] = q_nope[h] @ w_knope[:, h, :].T  -> latent-space query
     q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, w_knope)
-    scale = 1.0 / np.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    scale = cfg.softmax_scale
     sc = (jnp.einsum("bqhr,bsr->bqhs", q_lat, cache_ckv) +
           jnp.einsum("bqhp,bsp->bqhs", q_rope, cache_krope)).astype(jnp.float32)
     sc = sc * scale

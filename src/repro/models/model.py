@@ -16,6 +16,7 @@ activation sharding is requested via repro.distributed.sharding.constrain
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Dict, Optional, Tuple
 
@@ -38,7 +39,8 @@ from repro.models.kvcache import DecodeState, init_cache, n_triples_extra
 # parameter init
 # ---------------------------------------------------------------------------
 
-def _init_dense_layer(key, cfg: ArchConfig):
+def _init_dense_layer(key, cfg: ArchConfig, moe: bool = True):
+    """One layer; ``moe=False`` gives an MoE model's dense-prefix layer."""
     k1, k2 = jax.random.split(key)
     p = {
         "norm1": L.init_rms_norm(cfg.d_model),
@@ -52,7 +54,7 @@ def _init_dense_layer(key, cfg: ArchConfig):
     if cfg.ssm is not None:
         p["mixer"] = SSM.init_mamba2(k1, cfg.d_model, cfg.ssm)
         del p["norm2"]
-    elif cfg.moe is not None:
+    elif cfg.moe is not None and moe:
         p["ffn"] = MOE.init_moe(k2, cfg.d_model, cfg.moe)
     else:
         p["ffn"] = L.init_mlp(k2, cfg.d_model, cfg.d_ff)
@@ -108,13 +110,79 @@ def init_params(cfg: ArchConfig, key: jax.Array) -> Dict:
             })(ekeys)
     else:
         lkeys = jax.random.split(ks[3], cfg.num_layers)
-        p["layers"] = jax.vmap(lambda k: _init_dense_layer(k, cfg))(lkeys)
+        k = cfg.moe.first_k_dense if cfg.moe is not None else 0
+        if k:
+            p["dense_layers"] = jax.vmap(
+                lambda key: _init_dense_layer(key, cfg, moe=False))(lkeys[:k])
+        p["layers"] = jax.vmap(lambda key: _init_dense_layer(key, cfg))(lkeys[k:])
     return p
 
 
 def abstract_params(cfg: ArchConfig) -> Dict:
     """ShapeDtypeStruct pytree — no allocation (dry-run path)."""
     return jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+
+
+# ---------------------------------------------------------------------------
+# layer stacks
+# ---------------------------------------------------------------------------
+
+def _stacks(cfg: ArchConfig, params):
+    """The layer stacks in depth order, each with whether its FFN is the MoE
+    layer: an MoE model's dense prefix (``MoEConfig.first_k_dense``), then
+    ``layers``. One stack for every other model."""
+    moe = cfg.moe is not None
+    if "dense_layers" in params:
+        return [(params["dense_layers"], False), (params["layers"], moe)]
+    return [(params["layers"], moe)]
+
+
+def _scan_stacks(cfg: ArchConfig, params, make_step, carry, per_layer=None):
+    """Scan ``make_step(moe)`` over each layer stack in depth order.
+
+    A step maps ``(carry, (layer params, per-layer inputs))`` to ``(carry,
+    (per-layer outputs, stats))``. ``per_layer`` (a pytree of (L, ...)
+    arrays: caches, page tables) is cut between the stacks and the outputs
+    are joined back along the layer axis, so a cache stays one leaf set over
+    the whole depth. An MoE stack's held experts are not sliced: each layer
+    reads them from the stack by its index (``moe.per_layer``). Returns
+    ``(carry, outputs, stats summed over layers)``."""
+    stacks = _stacks(cfg, params)
+    outs, stats, lo = [], {}, 0
+    for stack, moe in stacks:
+        n = jax.tree.leaves(stack)[0].shape[0]
+        xs = per_layer if len(stacks) == 1 else jax.tree.map(
+            lambda a: a[lo:lo + n], per_layer)
+        step = make_step(moe)
+        if moe:
+            ffn, experts = MOE.per_layer(stack["ffn"])
+            stack = (dict(stack, ffn=ffn), jnp.arange(n))
+            step = _with_experts(step, experts)
+        carry, (out, st) = scanctl.scan(step, carry, (stack, xs))
+        outs.append(out)
+        for name, v in st.items():
+            stats[name] = stats.get(name, 0) + jnp.sum(v)
+        lo += n
+    if len(outs) > 1:
+        outs = [jax.tree.map(lambda *a: jnp.concatenate(a), *outs)]
+    return carry, outs[0], stats
+
+
+def _with_experts(step, experts):
+    """``step`` over an MoE stack scanned without its held experts: each
+    layer gets them whole, with its index in the stack."""
+    def with_experts(carry, xs):
+        (lp, i), rest = xs
+        ffn = dict(lp["ffn"], layer=i, **experts)
+        return step(carry, (dict(lp, ffn=ffn), rest))
+    return with_experts
+
+
+def _ffn(cfg: ArchConfig, lp, h, moe: bool):
+    """The layer's FFN on ``h``: (out, stats); stats only from an MoE FFN."""
+    if moe:
+        return MOE.moe_ffn(lp["ffn"], h, cfg.moe)
+    return L.mlp(lp["ffn"], h), {}
 
 
 # ---------------------------------------------------------------------------
@@ -148,14 +216,14 @@ def lm_logits(params, x: jax.Array, cfg: ArchConfig) -> jax.Array:
 # full-sequence forward (train / prefill)
 # ---------------------------------------------------------------------------
 
-def _dense_layer_fwd(cfg: ArchConfig, lp, x, positions, kv_block=1024):
-    """One transformer layer; returns (x, cache_entries, aux)."""
+def _dense_layer_fwd(cfg: ArchConfig, lp, x, positions, kv_block=1024,
+                     moe: bool = False):
+    """One transformer layer; returns (x, cache_entries, stats)."""
     h = L.rms_norm(x, lp["norm1"], cfg.norm_eps)
-    aux = jnp.zeros((), jnp.float32)
     if cfg.ssm is not None:
         mix_out, state = SSM.mamba2_forward(lp["mixer"], h, cfg.ssm, cfg.d_model)
         x = constrain(x + mix_out, "btd")
-        return x, state, aux
+        return x, state, {}
     with jax.named_scope("attn"):
         if cfg.mla is not None:
             attn_out, kv = MLA.mla_prefill(lp["attn"], h, positions, cfg.mla,
@@ -172,12 +240,9 @@ def _dense_layer_fwd(cfg: ArchConfig, lp, x, positions, kv_block=1024):
             kv = (k, v)
     x = x + attn_out
     h2 = L.rms_norm(x, lp["norm2"], cfg.norm_eps)
-    if cfg.moe is not None:
-        ffn_out, aux = MOE.moe_ffn(lp["ffn"], h2, cfg.moe)
-    else:
-        ffn_out = L.mlp(lp["ffn"], h2)
+    ffn_out, stats = _ffn(cfg, lp, h2, moe)
     x = constrain(x + ffn_out, "btd")
-    return x, kv, aux
+    return x, kv, stats
 
 
 def _triple_fwd(cfg: ArchConfig, tp, x, positions, window, kv_block=1024):
@@ -218,7 +283,18 @@ def forward(params, batch: Dict, cfg: ArchConfig, *, kv_block: int = 1024,
     ``logits_positions='last'`` projects only the final position through the
     LM head — prefill needs just the first sampled token, and the full
     (B, S, V) logits chain is the single largest non-attention tensor in
-    long-context prefill (EXPERIMENTS.md §Perf Cell A)."""
+    long-context prefill (EXPERIMENTS.md §Perf Cell A).  ``aux_loss`` is the
+    MoE layers' load-balancing loss (0 without MoE)."""
+    logits, cache, stats = _forward(
+        params, batch, cfg, kv_block=kv_block, remat=remat,
+        collect_cache=collect_cache, logits_positions=logits_positions)
+    return logits, cache, stats.get("balance", jnp.zeros((), jnp.float32))
+
+
+def _forward(params, batch: Dict, cfg: ArchConfig, *, kv_block: int,
+             remat: bool, collect_cache: bool, logits_positions: str):
+    """:func:`forward`, returning the MoE layers' summed stats
+    (``moe.moe_ffn``) in place of the loss: ``{}`` without MoE."""
     x = embed_inputs(params, batch, cfg)
     b, s, _ = x.shape
     positions = jnp.arange(s)[None, :]
@@ -260,15 +336,16 @@ def forward(params, batch: Dict, cfg: ArchConfig, *, kv_block: int = 1024,
                     (0, b, cfg.hybrid.conv_width - 1, x.shape[-1]), x.dtype)
         if logits_positions == "last":
             x = x[:, -1:]
-        return lm_logits(params, x, cfg), cache, jnp.zeros((), jnp.float32)
+        return lm_logits(params, x, cfg), cache, {}
 
-    def layer_step(carry, lp):
-        h, cache, aux = _dense_layer_fwd(cfg, lp, carry, positions, kv_block)
-        return h, (cache if collect_cache else None, aux)
+    def make_step(moe):
+        def layer_step(carry, xs):
+            h, cache, stats = _dense_layer_fwd(cfg, xs[0], carry, positions,
+                                               kv_block, moe)
+            return h, (cache if collect_cache else None, stats)
+        return jax.checkpoint(layer_step) if remat else layer_step
 
-    step = jax.checkpoint(layer_step) if remat else layer_step
-    x, (caches, auxs) = scanctl.scan(step, x, params["layers"])
-    aux = jnp.sum(auxs)
+    x, caches, stats = _scan_stacks(cfg, params, make_step, x)
     if logits_positions == "last":
         x = x[:, -1:]
     cache = None
@@ -279,7 +356,7 @@ def forward(params, batch: Dict, cfg: ArchConfig, *, kv_block: int = 1024,
             cache = {"ckv": caches[0], "krope": caches[1]}
         else:
             cache = {"k": caches[0], "v": caches[1]}
-    return lm_logits(params, x, cfg), cache, aux
+    return lm_logits(params, x, cfg), cache, stats
 
 
 def loss_fn(params, batch: Dict, cfg: ArchConfig, *, kv_block: int = 1024,
@@ -301,7 +378,8 @@ def loss_fn(params, batch: Dict, cfg: ArchConfig, *, kv_block: int = 1024,
 
 def prefill(params, batch: Dict, cfg: ArchConfig, *, max_seq: Optional[int] = None,
             kv_block: int = 1024) -> Tuple[jax.Array, DecodeState]:
-    """Run the full prompt; return (last-position logits, decode state).
+    """Run the full prompt; return (last-position logits, decode state). The
+    state carries the MoE layers' routing counts (:func:`moe_counts`).
 
     For cache-positional families (dense/mla) the cache is padded to
     ``max_seq`` slots so decode can continue in place.
@@ -322,10 +400,28 @@ def prefill(params, batch: Dict, cfg: ArchConfig, *, max_seq: Optional[int] = No
                 "absorbs right-padding")
         if cfg.frontend is not None or cfg.encoder_only:
             raise ValueError("ragged prefill is token-decoder only")
-    logits, cache, _ = forward(
-        params, batch, cfg, kv_block=kv_block, collect_cache=True,
+    logits, cache, stats = _forward(
+        params, batch, cfg, kv_block=kv_block, remat=False, collect_cache=True,
         logits_positions="all" if (cfg.encoder_only or lengths is not None)
         else "last")
+    last, state = _prefill_state(logits, cache, batch, cfg, max_seq, lengths)
+    return last, dataclasses.replace(state, moe=moe_counts(stats))
+
+
+def moe_counts(stats: Dict, before: Optional[Dict] = None) -> Optional[Dict]:
+    """The routing counts of a forward or a step, added to ``before``:
+    ``moe_pairs`` (routed (token, held expert) pairs computed, summed over
+    MoE layers) and ``moe_experts_touched`` (held experts with a pair, summed
+    over MoE layers and token blocks); None for a model without MoE."""
+    if "pairs" not in stats:
+        return before
+    counts = {"moe_pairs": stats["pairs"],
+              "moe_experts_touched": stats["touched"]}
+    return counts if before is None else jax.tree.map(jnp.add, before, counts)
+
+
+def _prefill_state(logits, cache, batch, cfg, max_seq, lengths):
+    """The last-position logits and the decode state of a prompt's cache."""
     if cfg.frontend == "vision_patches":
         s = batch["tokens"].shape[1] + cfg.frontend_len
         b = batch["tokens"].shape[0]
@@ -386,13 +482,16 @@ def _windowed_decode(ap, x, k_cache, v_cache, cache_len, cfg):
 
 def decode_step(params, tokens: jax.Array, state: DecodeState, cfg: ArchConfig
                 ) -> Tuple[jax.Array, DecodeState]:
-    """One autoregressive step.  tokens: (B, 1) int32 -> logits (B, V)."""
+    """One autoregressive step.  tokens: (B, 1) int32 -> logits (B, V).
+    The step's MoE routing counts are added to the state's (none are kept
+    where the state carries none)."""
     if cfg.encoder_only:
         raise ValueError(f"{cfg.name} is encoder-only: no decode step")
     x = params["embed"][tokens]
     x = constrain(x, "btd")
     cache_len = state.cache_len
     cache = state.cache
+    stats = {}
 
     if cfg.hybrid is not None:
         window = cache["attn_k"].shape[2]
@@ -449,40 +548,44 @@ def decode_step(params, tokens: jax.Array, state: DecodeState, cfg: ArchConfig
             layer_step, x, (params["layers"], cache["ssm"], cache["conv"]))
         new_cache = {"ssm": ssms, "conv": convs}
     elif cfg.mla is not None:
-        def layer_step(carry, xs):
-            lp, ckv, krope = xs
-            h = L.rms_norm(carry, lp["norm1"], cfg.norm_eps)
-            with jax.named_scope("attn"):
-                out, (ckv, krope) = MLA.mla_decode(
-                    lp["attn"], h, ckv, krope, cache_len, cfg.mla,
-                    cfg.rope_theta)
-            h2 = L.rms_norm(carry + out, lp["norm2"], cfg.norm_eps)
-            y = carry + out + (MOE.moe_ffn(lp["ffn"], h2, cfg.moe)[0]
-                               if cfg.moe else L.mlp(lp["ffn"], h2))
-            return constrain(y, "btd"), (ckv, krope)
+        def make_step(moe):
+            def layer_step(carry, xs):
+                lp, (ckv, krope) = xs
+                h = L.rms_norm(carry, lp["norm1"], cfg.norm_eps)
+                with jax.named_scope("attn"):
+                    out, (ckv, krope) = MLA.mla_decode(
+                        lp["attn"], h, ckv, krope, cache_len, cfg.mla,
+                        cfg.rope_theta)
+                h2 = L.rms_norm(carry + out, lp["norm2"], cfg.norm_eps)
+                ffn, st = _ffn(cfg, lp, h2, moe)
+                return constrain(carry + out + ffn, "btd"), ((ckv, krope), st)
+            return layer_step
 
-        x, (ckvs, kropes) = scanctl.scan(
-            layer_step, x, (params["layers"], cache["ckv"], cache["krope"]))
+        x, (ckvs, kropes), stats = _scan_stacks(
+            cfg, params, make_step, x, (cache["ckv"], cache["krope"]))
         new_cache = {"ckv": ckvs, "krope": kropes}
     else:
-        def layer_step(carry, xs):
-            lp, ck, cv = xs
-            h = L.rms_norm(carry, lp["norm1"], cfg.norm_eps)
-            with jax.named_scope("attn"):
-                out, (ck, cv) = L.decode_attention_block(
-                    lp["attn"], h, ck, cv, cache_len, cfg.rope_theta)
-            y = carry + out
-            h2 = L.rms_norm(y, lp["norm2"], cfg.norm_eps)
-            ffn = (MOE.moe_ffn(lp["ffn"], h2, cfg.moe)[0] if cfg.moe
-                   else L.mlp(lp["ffn"], h2))
-            return constrain(y + ffn, "btd"), (ck, cv)
+        def make_step(moe):
+            def layer_step(carry, xs):
+                lp, (ck, cv) = xs
+                h = L.rms_norm(carry, lp["norm1"], cfg.norm_eps)
+                with jax.named_scope("attn"):
+                    out, (ck, cv) = L.decode_attention_block(
+                        lp["attn"], h, ck, cv, cache_len, cfg.rope_theta)
+                y = carry + out
+                h2 = L.rms_norm(y, lp["norm2"], cfg.norm_eps)
+                ffn, st = _ffn(cfg, lp, h2, moe)
+                return constrain(y + ffn, "btd"), ((ck, cv), st)
+            return layer_step
 
-        x, (cks, cvs) = scanctl.scan(
-            layer_step, x, (params["layers"], cache["k"], cache["v"]))
+        x, (cks, cvs), stats = _scan_stacks(
+            cfg, params, make_step, x, (cache["k"], cache["v"]))
         new_cache = {"k": cks, "v": cvs}
 
     logits = lm_logits(params, x, cfg)[:, -1]
-    return logits, DecodeState(cache=new_cache, cache_len=cache_len + 1)
+    moe = None if state.moe is None else moe_counts(stats, state.moe)
+    return logits, DecodeState(cache=new_cache, cache_len=cache_len + 1,
+                               moe=moe)
 
 
 def resident_decode_step(params, tokens: jax.Array, state, cfg: ArchConfig,
@@ -495,7 +598,6 @@ def resident_decode_step(params, tokens: jax.Array, state, cfg: ArchConfig,
     the compressed pool is read-only here and tail flushes/recompression run
     between steps (``KVPool.flush_full_tails``).  Dense-GQA and MLA
     families only; others decode raw-resident."""
-    import dataclasses
 
     from repro.models import kvpool as KVP
 
@@ -511,22 +613,23 @@ def resident_decode_step(params, tokens: jax.Array, state, cfg: ArchConfig,
         c_streams, r_streams = cl.streams(), rl.streams()
         fmt = g.leaf("ckv").fmt
 
-        def layer_step(carry, xs):
-            lp, pt_c, pt_r, tc, tr = xs
-            h = L.rms_norm(carry, lp["norm1"], cfg.norm_eps)
-            with jax.named_scope("attn"):
-                out, (tc, tr) = KVP.paged_mla_decode(
-                    lp["attn"], h, c_streams, r_streams, pt_c, pt_r, tc, tr,
-                    cache_len, cfg.mla, cfg.rope_theta, geom=g, fmt=fmt,
-                    interpret=interpret)
-            h2 = L.rms_norm(carry + out, lp["norm2"], cfg.norm_eps)
-            y = carry + out + (MOE.moe_ffn(lp["ffn"], h2, cfg.moe)[0]
-                               if cfg.moe else L.mlp(lp["ffn"], h2))
-            return constrain(y, "btd"), (tc, tr)
+        def make_step(moe):
+            def layer_step(carry, xs):
+                lp, (pt_c, pt_r, tc, tr) = xs
+                h = L.rms_norm(carry, lp["norm1"], cfg.norm_eps)
+                with jax.named_scope("attn"):
+                    out, (tc, tr) = KVP.paged_mla_decode(
+                        lp["attn"], h, c_streams, r_streams, pt_c, pt_r, tc,
+                        tr, cache_len, cfg.mla, cfg.rope_theta, geom=g,
+                        fmt=fmt, interpret=interpret)
+                h2 = L.rms_norm(carry + out, lp["norm2"], cfg.norm_eps)
+                ffn, st = _ffn(cfg, lp, h2, moe)
+                return constrain(carry + out + ffn, "btd"), ((tc, tr), st)
+            return layer_step
 
-        x, (tcs, trs) = scanctl.scan(
-            layer_step, x, (params["layers"], cl.page_table, rl.page_table,
-                            cl.tail, rl.tail))
+        x, (tcs, trs), _ = _scan_stacks(
+            cfg, params, make_step, x,
+            (cl.page_table, rl.page_table, cl.tail, rl.tail))
         new_leaves = {"ckv": dataclasses.replace(cl, tail=tcs),
                       "krope": dataclasses.replace(rl, tail=trs)}
     elif cfg.ssm is None and cfg.hybrid is None:
@@ -534,23 +637,24 @@ def resident_decode_step(params, tokens: jax.Array, state, cfg: ArchConfig,
         k_streams, v_streams = kl.streams(), vl.streams()
         fmt = g.leaf("k").fmt
 
-        def layer_step(carry, xs):
-            lp, pt_k, pt_v, tk, tv = xs
-            h = L.rms_norm(carry, lp["norm1"], cfg.norm_eps)
-            with jax.named_scope("attn"):
-                out, (tk, tv) = KVP.paged_decode_attention_block(
-                    lp["attn"], h, k_streams, v_streams, pt_k, pt_v, tk, tv,
-                    cache_len, cfg.rope_theta, geom=g, fmt=fmt,
-                    interpret=interpret)
-            y = carry + out
-            h2 = L.rms_norm(y, lp["norm2"], cfg.norm_eps)
-            ffn = (MOE.moe_ffn(lp["ffn"], h2, cfg.moe)[0] if cfg.moe
-                   else L.mlp(lp["ffn"], h2))
-            return constrain(y + ffn, "btd"), (tk, tv)
+        def make_step(moe):
+            def layer_step(carry, xs):
+                lp, (pt_k, pt_v, tk, tv) = xs
+                h = L.rms_norm(carry, lp["norm1"], cfg.norm_eps)
+                with jax.named_scope("attn"):
+                    out, (tk, tv) = KVP.paged_decode_attention_block(
+                        lp["attn"], h, k_streams, v_streams, pt_k, pt_v, tk,
+                        tv, cache_len, cfg.rope_theta, geom=g, fmt=fmt,
+                        interpret=interpret)
+                y = carry + out
+                h2 = L.rms_norm(y, lp["norm2"], cfg.norm_eps)
+                ffn, st = _ffn(cfg, lp, h2, moe)
+                return constrain(y + ffn, "btd"), ((tk, tv), st)
+            return layer_step
 
-        x, (tks, tvs) = scanctl.scan(
-            layer_step, x, (params["layers"], kl.page_table, vl.page_table,
-                            kl.tail, vl.tail))
+        x, (tks, tvs), _ = _scan_stacks(
+            cfg, params, make_step, x,
+            (kl.page_table, vl.page_table, kl.tail, vl.tail))
         new_leaves = {"k": dataclasses.replace(kl, tail=tks),
                       "v": dataclasses.replace(vl, tail=tvs)}
     else:

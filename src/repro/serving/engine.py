@@ -98,6 +98,15 @@ class EngineStats:
     resident_steps: int = 0
     resident_host_reads: int = 0
     resident_page_flushes: int = 0
+    # mixture-of-experts routing (``model.moe_counts``), carried on the device
+    # in the prefill's and the raw decode's states and read when ``stats`` is
+    # read: (token, held expert) pairs computed and held experts with a pair,
+    # summed over MoE layers, token blocks and steps; the decode part of each
+    # again on its own. The compressed-resident loop does not count.
+    moe_pairs: int = 0
+    moe_experts_touched: int = 0
+    moe_decode_pairs: int = 0
+    moe_decode_experts_touched: int = 0
 
     @property
     def resident_ratio(self) -> float:
@@ -169,13 +178,27 @@ class DisaggregatedEngine:
         self.page_bytes = page_bytes
         self.retain_for_failover = retain_for_failover
         self.prefix_cache_bytes = prefix_cache_bytes
-        self.stats = EngineStats()
+        self._stats = EngineStats()
+        self._moe_pending: List[Tuple[str, dict]] = []   # (stage, counts)
         self._session: Optional[TransferSession] = None
         self._pool = None   # KVPool of the last admitted batch
         self._decode_calls = 0
         # jitted prefill/decode programs, one per (stage, static size): a
         # repeated call reuses its executable instead of re-tracing
         self._programs: Dict[Tuple[str, Optional[int]], object] = {}
+
+    @property
+    def stats(self) -> EngineStats:
+        """The engine's counters. Routing counts still on the device are
+        read here, in one transfer for every call since the last read, so a
+        stage never waits on them."""
+        for stage, counts in jax.device_get(self._moe_pending):
+            for name, v in counts.items():
+                names = (name, name.replace("moe_", "moe_decode_"))
+                for n in names[:2 if stage == "decode" else 1]:
+                    setattr(self._stats, n, getattr(self._stats, n) + int(v))
+        self._moe_pending = []
+        return self._stats
 
     def _program(self, stage: str, size: Optional[int]):
         key = (stage, size)
@@ -219,7 +242,7 @@ class DisaggregatedEngine:
         scheduler falls back to its scalar ``overflow_p`` for them."""
         b = max(1, bucket_tokens)
         agg: Dict[int, List[int]] = {}
-        for length, (units, retried) in self.stats.overflow_obs.items():
+        for length, (units, retried) in self._stats.overflow_obs.items():
             bucket = max(b, -(-length // b) * b)
             acc = agg.setdefault(bucket, [0, 0])
             acc[0] += units
@@ -245,18 +268,20 @@ class DisaggregatedEngine:
                   plan=self.plan, transfer_config=self.tc,
                   compress=self.tc.enabled,
                   n_chunks=max(1, self.tc.n_chunks),
-                  overflow_p=self.stats.observed_overflow_p)
+                  overflow_p=self._stats.observed_overflow_p)
         kw.update(overrides)
-        if "overflow_priors" not in overrides and self.stats.overflow_obs:
+        if "overflow_priors" not in overrides and self._stats.overflow_obs:
             kw["overflow_priors"] = self.overflow_priors(
                 kw.get("bucket_tokens", SchedulerConfig.bucket_tokens))
         return SchedulerConfig(**kw)
 
     # -- the three pipeline stages ------------------------------------------
     def prefill(self, batch: Dict, max_seq: Optional[int] = None):
-        with span("prefill", batch=self.stats.prefill_calls):
+        with span("prefill", batch=self._stats.prefill_calls):
             out = self._program("prefill", max_seq)(self.params, batch)
-        self.stats.prefill_calls += 1
+        self._stats.prefill_calls += 1
+        if out.state.moe is not None:
+            self._moe_pending.append(("prefill", out.state.moe))
         return out
 
     def transfer(self, state: DecodeState,
@@ -274,15 +299,15 @@ class DisaggregatedEngine:
         call through the prefix-delta path: segments the destination already
         holds for that session never cross the wire, and their raw size lands
         in ``EngineStats.prefix_hit_bytes``."""
-        with span("transfer", batch=self.stats.transfer_calls):
-            self.stats.transfer_calls += 1
+        with span("transfer", batch=self._stats.transfer_calls):
+            self._stats.transfer_calls += 1
             return self._transfer(state, session_id)
 
     def _transfer(self, state: DecodeState, session_id: Optional[int]):
         raw = T.raw_wire_bytes(state.cache)
-        self.stats.raw_cache_bytes += raw
+        self._stats.raw_cache_bytes += raw
         if not self.tc.enabled or not state.cache:
-            self.stats.wire_bytes += raw
+            self._stats.wire_bytes += raw
             return state
         sess = self._session_for(state.cache)
         if self.resident == "compressed":
@@ -292,7 +317,8 @@ class DisaggregatedEngine:
         else:
             cache = sess.transfer(state.cache, check=False)
         self._absorb_transfer_stats(sess.last_stats, state)
-        return DecodeState(cache=cache, cache_len=state.cache_len)
+        return DecodeState(cache=cache, cache_len=state.cache_len,
+                           moe=state.moe)
 
     def resend_cache(self, state: DecodeState) -> DecodeState:
         """Failover re-send: re-ship the last transfer's retained payload to
@@ -306,37 +332,38 @@ class DisaggregatedEngine:
             return state
         sess = self._session_for(state.cache)
         cache = sess.resend_last()
-        self.stats.failover_resends += 1
-        self.stats.raw_cache_bytes += T.raw_wire_bytes(state.cache)
+        self._stats.failover_resends += 1
+        self._stats.raw_cache_bytes += T.raw_wire_bytes(state.cache)
         self._absorb_transfer_stats(sess.last_stats, state)
-        return DecodeState(cache=cache, cache_len=state.cache_len)
+        return DecodeState(cache=cache, cache_len=state.cache_len,
+                           moe=state.moe)
 
     def _absorb_transfer_stats(self, cstats, state: DecodeState) -> None:
-        self.stats.wire_bytes += cstats.wire_bytes
-        self.stats.codec_ok &= cstats.all_ok
-        self.stats.chunk_retries += cstats.n_retries
-        self.stats.chunk_retry_steps += cstats.n_retry_steps
-        self.stats.fp32_lo_wire_bytes += cstats.fp32_lo_wire_bytes
-        self.stats.prefix_hit_bytes += cstats.prefix_hit_bytes
-        self.stats.verify_failures += cstats.verify_failures
-        self.stats.refetches += cstats.refetches
-        self.stats.raw_refetches += cstats.raw_refetches
-        self.stats.faults_injected += cstats.faults_injected
+        self._stats.wire_bytes += cstats.wire_bytes
+        self._stats.codec_ok &= cstats.all_ok
+        self._stats.chunk_retries += cstats.n_retries
+        self._stats.chunk_retry_steps += cstats.n_retry_steps
+        self._stats.fp32_lo_wire_bytes += cstats.fp32_lo_wire_bytes
+        self._stats.prefix_hit_bytes += cstats.prefix_hit_bytes
+        self._stats.verify_failures += cstats.verify_failures
+        self._stats.refetches += cstats.refetches
+        self._stats.raw_refetches += cstats.raw_refetches
+        self._stats.faults_injected += cstats.faults_injected
         # overflow observations: units that walked the capacity schedule on
         # this call, keyed by the transferred prompt length — the raw
         # material for the scheduler's per-bucket overflow priors
         units = len(cstats.chunk_retried)
         if units:
-            self.stats.encoded_units += units
+            self._stats.encoded_units += units
             lens = jnp.asarray(state.cache_len)
             length = (host_read(jnp.max(lens), "cache_len", cstats, int)
                       if lens.size else 0)
-            obs = self.stats.overflow_obs.setdefault(length, [0, 0])
+            obs = self._stats.overflow_obs.setdefault(length, [0, 0])
             obs[0] += units
             obs[1] += cstats.n_retries
         if self.tc.n_chunks > 1:
-            self.stats.chunk_wire_bytes.extend(cstats.chunk_wire_bytes)
-        self.stats.transfer_host_reads += cstats.host_reads
+            self._stats.chunk_wire_bytes.extend(cstats.chunk_wire_bytes)
+        self._stats.transfer_host_reads += cstats.host_reads
 
     def resident_tokens_per_page(self, batch: int = 1) -> int:
         """Page granularity the pool will use for this arch (max_seq must be
@@ -373,16 +400,17 @@ class DisaggregatedEngine:
                 rst = pool.admit_from_wire(comp, state.cache_len)
         except KVP.ResidencyError:
             if pool is not None:
-                self.stats.transfer_host_reads += pool.host_reads
-            self.stats.resident_demotions += 1
+                self._stats.transfer_host_reads += pool.host_reads
+            self._stats.resident_demotions += 1
             cache = decode_leaves(comp, raw, state.cache,
                                   backend=self.tc.backend)
-            return DecodeState(cache=cache, cache_len=state.cache_len)
+            return DecodeState(cache=cache, cache_len=state.cache_len,
+                               moe=state.moe)
         self._pool = pool
-        self.stats.transfer_host_reads += pool.host_reads
-        self.stats.resident_admits += 1
-        self.stats.resident_hbm_bytes += pool.hbm_bytes()
-        self.stats.resident_raw_bytes += pool.raw_bytes()
+        self._stats.transfer_host_reads += pool.host_reads
+        self._stats.resident_admits += 1
+        self._stats.resident_hbm_bytes += pool.hbm_bytes()
+        self._stats.resident_raw_bytes += pool.raw_bytes()
         return rst
 
     def decode(self, first_token: jax.Array, state, num_steps: int
@@ -398,14 +426,17 @@ class DisaggregatedEngine:
                 toks, _, demoted = resident_decode_loop(
                     self.params, first_token, state, pool, self.cfg,
                     num_steps)
-                self.stats.resident_demotions += int(demoted)
-                self.stats.resident_host_reads += pool.host_reads - reads
-                self.stats.resident_steps += pool.flushes - flushes
-                self.stats.resident_page_flushes += pool.page_flushes - paged
+                self._stats.resident_demotions += int(demoted)
+                self._stats.resident_host_reads += pool.host_reads - reads
+                self._stats.resident_steps += pool.flushes - flushes
+                self._stats.resident_page_flushes += pool.page_flushes - paged
             else:
-                toks, _ = self._program("decode", num_steps)(
+                toks, final = self._program("decode", num_steps)(
                     self.params, first_token, state)
-        self.stats.decode_tokens += int(toks.size)
+                if final.moe is not None:     # counted since the prefill
+                    self._moe_pending.append(("decode", jax.tree.map(
+                        jnp.subtract, final.moe, state.moe)))
+        self._stats.decode_tokens += int(toks.size)
         return toks
 
     # -- end-to-end ----------------------------------------------------------
@@ -429,6 +460,6 @@ class DisaggregatedEngine:
     def transfer_report(self) -> Optional[T.TransferReport]:
         if self.profile is None:
             return None
-        return T.transfer_report(self.stats.raw_cache_bytes,
-                                 self.stats.wire_bytes, self.profile,
+        return T.transfer_report(self._stats.raw_cache_bytes,
+                                 self._stats.wire_bytes, self.profile,
                                  n_chunks=self.tc.n_chunks, plan=self.plan)

@@ -70,19 +70,22 @@ class TestFSDP:
 class TestMoEDispatchKinds:
     def test_disabled_by_default(self, mesh2):
         pol = ShardingPolicy(mesh2)
-        assert pol.spec_for_activation("moe_ecd", (8, 64, 128)) is None
+        assert pol.spec_for_activation("moe_td", (4096, 128)) is None
+        assert pol.spec_for_activation("moe_te", (4096, 8)) is None
 
     def test_enabled(self, mesh2):
         pol = ShardingPolicy(mesh2, moe_dispatch_sharding=True)
-        assert pol.spec_for_activation("moe_ecd", (8, 64, 128)) == \
-            P("model", None, None)
         assert pol.spec_for_activation("moe_td", (4096, 128)) == P("data", None)
         assert pol.spec_for_activation("moe_te", (4096, 8)) == P("data", None)
 
-    def test_indivisible_experts_replicate(self, mesh2):
+    def test_indivisible_tokens_replicate(self, mesh2):
         pol = ShardingPolicy(mesh2, moe_dispatch_sharding=True)
-        assert pol.spec_for_activation("moe_ecd", (7, 64, 128)) == \
-            P(None, None, None)
+        assert pol.spec_for_activation("moe_td", (4095, 128)) == P(None, None)
+
+    def test_unknown_kind_raises(self, mesh2):
+        pol = ShardingPolicy(mesh2, moe_dispatch_sharding=True)
+        with pytest.raises(KeyError):
+            pol.spec_for_activation("moe_ecd", (8, 64, 128))
 
 
 class TestPDDisaggregation:

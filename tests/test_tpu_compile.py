@@ -8,7 +8,10 @@ lane shuffles Mosaic cannot express, VMEM overflow) without a chip.
 
 Sizes are those of the one-chip smoke (``chip_smoke.py``): the qwen3-32b KV
 layer of 4 x 2048 tokens x 8 heads x 128 (8192 codec rows of 1024), 32 KiB
-pages of 16 tokens, and minicpm3-4b's MLA latent pages (kv_lora 256, rope 32).
+pages of 16 tokens, and minicpm3-4b's MLA latent pages (kv_lora 256, rope 32);
+and DeepSeek-V3's expert products (d_model 7168, experts 2048 wide, 8 held,
+seven MoE layers stacked) at a decode step's 64 rows and a prefill block's
+16384.
 """
 
 import os
@@ -19,6 +22,7 @@ import pytest
 
 from repro.kernels import splitzip_attention as SA
 from repro.kernels import splitzip_decode, splitzip_encode
+from repro.kernels.grouped_matmul import moe_grouped_matmul
 
 EXPONENTS = tuple(range(112, 128))
 CHUNK, CAP = 1024, 64
@@ -126,3 +130,14 @@ def test_paged_mla_attention_minicpm3_4b(one_chip):
         _shape(one_chip, (b, 1, 40, 32), jnp.bfloat16),
         _streams(one_chip, n_pages, 16, 64), _streams(one_chip, n_pages, 2, 8),
         table, table, _shape(one_chip, (b,), jnp.int32))
+
+
+@pytest.mark.parametrize("rows", (64, 16384))
+@pytest.mark.parametrize("glu", (True, False), ids=("gate-up", "down"))
+def test_moe_grouped_matmul_deepseek_v3(one_chip, rows, glu):
+    k, n = (7168, 2 * 2048) if glu else (2048, 7168)
+    _compile(lambda x, w, s, i: moe_grouped_matmul(
+        x, w, s, i, glu=glu, interpret=False),
+        _shape(one_chip, (rows, k), jnp.bfloat16),
+        _shape(one_chip, (7, 8, k, n), jnp.bfloat16),
+        _shape(one_chip, (8,), jnp.int32), _shape(one_chip, (), jnp.int32))
