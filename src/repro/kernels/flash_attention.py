@@ -1,31 +1,36 @@
-"""Flash attention as a Pallas TPU kernel (EXPERIMENTS.md §Perf Cell A).
+"""Flash attention as a Pallas TPU kernel: full-sequence (prefill) attention
+with the score block, the online-softmax state and P·V kept in VMEM.
 
-Why this kernel exists: the XLA prefill attention materializes the per-block
-score chain through HBM (on CPU-XLA even the reductions are unfused), which
-is the dominant byte term of every long-context prefill cell in §Roofline.
-A fused kernel keeps Q·Kᵀ, the online-softmax state and P·V in VMEM; its HBM
-traffic is exactly q+k+v+o.
+The XLA path (``models/layers.chunked_attention``) writes every
+(query, KV-block) score block to HBM in f32 and runs the softmax over it as
+separate fusions, and under a causal mask it computes the blocks above the
+diagonal too.  This kernel's HBM traffic is q + k + v + o (``hbm_bytes``),
+and it neither fetches nor computes a KV block above the diagonal.
 
 TPU mapping:
-  * grid = (B·H, Sq/blk_q, Skv/blk_k), last axis fastest => sequential
-    accumulation over KV blocks per (head, q-block) with carried VMEM
-    scratch (m, l, acc) — the canonical TPU flash schedule.
-  * BlockSpecs tile Q (blk_q, d), K/V (blk_k, d) into VMEM; GQA is handled
-    in the K/V index maps (query head h reads kv head h // group).
-  * MXU-aligned tiles: blk_q, blk_k multiples of 128 by default; working set
-    at (256, 512, d=128): q 64 KB + k/v 256 KB + scores 512 KB + acc 128 KB
-    ≈ 1 MB — comfortably inside the 16 MB VMEM budget.
-  * Causal masking via position iota; blocks strictly above the diagonal
-    short-circuit through @pl.when (visited but skipped).
-
-Validated on CPU in interpret mode against the jnp oracle (ref.py) across
-shapes/dtypes/causality — see tests/test_flash_attention.py.
+  * Queries are grouped by KV head: ``(B·Hkv, g, S, d)``, a q tile is
+    ``(1, g, blk_q, d)``, so the g query heads of a group share each K/V
+    block read (GQA reads K/V once per group, not g times).
+  * The grid is ``(B·Hkv, pairs)``: ``pairs`` lists, q block by q block,
+    the KV blocks at or below the diagonal (every block when not causal),
+    read from a scalar-prefetched schedule by the index maps.  A KV block
+    above the diagonal is never a grid step, so it costs neither a DMA nor
+    a matmul.  The KV axis of a q block runs in order; m, l and acc are
+    carried in VMEM scratch and the output is written at its last block.
+    m and l are kept replicated over the 128 lanes, so a step broadcasts
+    them across a score row without a lane shuffle.
+  * The arithmetic is the XLA path's: bf16 operands into both dots with f32
+    accumulation, the scale applied to the f32 scores, f32 max/exp/sum, p
+    cast to v's dtype before P·V.  Only blocks that cross the diagonal or
+    hold KV padding are masked.
+  * Block sizes are chosen from the shapes under a VMEM budget
+    (``block_sizes``).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,57 +40,125 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import auto_interpret
 
-DEFAULT_BLK_Q = 256
-DEFAULT_BLK_K = 512
 NEG_INF = -1e30
+LANES = 128
+MAX_BLOCK = 1024           # positions of a q or KV block
+MAX_TILE = 1 << 21         # score elements (g · blk_q rows × blk_k keys) of a step
+VMEM_BUDGET = 40 * 1024 * 1024
+VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            scale: float, causal: bool, blk_q: int, blk_k: int,
-            nk: int, seq_kv: int):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def vmem_bytes(blk_q: int, blk_k: int, g: int, d: int, dv: int) -> int:
+    """VMEM a grid step holds: double-buffered bf16 q, k, v and o tiles,
+    f32 m, l and acc scratch, and about three f32 score-sized temporaries
+    (s, p and the mask or exp operand)."""
+    rows = g * blk_q
+    tiles = 2 * 2 * (rows * d + blk_k * (d + dv) + rows * dv)
+    scratch = 4 * rows * (dv + 2 * LANES)
+    return tiles + scratch + 3 * 4 * rows * blk_k
+
+
+def block_sizes(sq: int, skv: int, g: int, d: int, dv: int
+                ) -> Tuple[int, int]:
+    """(blk_q, blk_k): square blocks of the largest power of two up to 1024
+    positions whose score tile (g · blk_q rows × blk_k keys) holds at most
+    2^21 elements.  Large steps keep the MXU fed between the softmax's
+    reductions; square blocks compute the least above the diagonal.  A
+    sequence shorter than a block is one block, rounded up to the bf16
+    sublane tile (16) for q and the lane width (128) for k.  Halved until a
+    step fits the VMEM budget."""
+    blk = MAX_BLOCK
+    while g * blk * blk > MAX_TILE and blk > LANES:
+        blk //= 2
+    blk_q = min(blk, _round_up(sq, 16))
+    blk_k = min(blk, _round_up(skv, LANES))
+    while vmem_bytes(blk_q, blk_k, g, d, dv) > VMEM_BUDGET and blk_q > 16:
+        blk_q //= 2
+    while vmem_bytes(blk_q, blk_k, g, d, dv) > VMEM_BUDGET and blk_k > LANES:
+        blk_k //= 2
+    return blk_q, blk_k
+
+
+def _schedule(nq: int, nk: int, blk_q: int, blk_k: int, sq: int, skv: int,
+              causal: bool) -> np.ndarray:
+    """(3, pairs) int32: per grid step its q block, its KV block, and 1 where
+    that KV block is the q block's last.  Causal: a q block visits the KV
+    blocks up to the one holding the key of its last valid query."""
+    qi, ki, fin = [], [], []
+    for j in range(nq):
+        last = nk - 1
+        if causal:
+            last = min(min((j + 1) * blk_q, sq) - 1, skv - 1) // blk_k
+        for t in range(last + 1):
+            qi.append(j)
+            ki.append(t)
+            fin.append(int(t == last))
+    return np.asarray([qi, ki, fin], np.int32)
+
+
+def _lanes(x: jax.Array, n: int) -> jax.Array:
+    """A (rows, 128) lane-replicated statistic at width n."""
+    if n <= LANES:
+        return x[:, :n]
+    return jnp.tile(x, (1, -(-n // LANES)))[:, :n]
+
+
+def _kernel(sched, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
+            scale: float, causal: bool, g: int, blk_q: int, blk_k: int,
+            seq_kv: int):
+    p = pl.program_id(1)
+    qi, ki = sched[0, p], sched[1, p]
+    rows = g * blk_q
+    q_start, k_start = qi * blk_q, ki * blk_k
 
     @pl.when(ki == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    q_start = qi * blk_q
-    k_start = ki * blk_k
-
-    # skip blocks strictly above the causal diagonal (no query attends there)
-    @pl.when((k_start <= q_start + blk_q - 1) if causal else (ki >= 0))
-    def _step():
-        q = q_ref[0].astype(jnp.float32)                    # (blk_q, d)
-        k = k_ref[0].astype(jnp.float32)                    # (blk_k, d)
-        v = v_ref[0].astype(jnp.float32)                    # (blk_k, dv)
+    def step(masked: bool):
+        q = q_ref[0].reshape(rows, q_ref.shape[-1])           # (rows, d)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # (blk_q, blk_k)
-
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = k_pos < seq_kv                               # kv padding
-        if causal:
-            q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            mask &= q_pos >= k_pos
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[...]                                  # (blk_q, 1)
+            q, k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale       # (rows, blk_k)
+        if masked:
+            shape = (blk_q, blk_k)
+            k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            mask = k_pos < seq_kv
+            if causal:
+                q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                mask &= q_pos >= k_pos
+            s = jnp.where(mask[None], s.reshape(g, blk_q, blk_k),
+                          NEG_INF).reshape(rows, blk_k)
+        m_prev = m_sc[...]                                     # (rows, 128)
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                               # (blk_q, blk_k)
-        corr = jnp.exp(m_prev - m_new)                       # (blk_q, 1)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+        pr = jnp.exp(s - _lanes(m_new, blk_k))
+        corr = jnp.exp(m_prev - m_new)
+        l_sc[...] = l_sc[...] * corr + pr.sum(axis=-1, keepdims=True)
+        v = v_ref[0]
+        acc_sc[...] = acc_sc[...] * _lanes(corr, v.shape[-1]) + jax.lax.dot_general(
+            pr.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        m_sc[...] = m_new
 
-    @pl.when(ki == nk - 1)
+    # a block needs the mask where one of its keys lies past a query of the
+    # tile (it crosses the diagonal) or past the sequence (KV padding)
+    needs_mask = k_start + blk_k > seq_kv
+    if causal:
+        needs_mask |= k_start + blk_k - 1 > q_start
+    pl.when(needs_mask)(lambda: step(True))
+    pl.when(jnp.logical_not(needs_mask))(lambda: step(False))
+
+    @pl.when(sched[2, p] == 1)
     def _finalize():
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        l = _lanes(l_sc[...], acc_sc.shape[-1])
+        out = acc_sc[...] / jnp.maximum(l, 1e-30)
+        o_ref[0] = out.reshape(g, blk_q, -1).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -97,58 +170,63 @@ def flash_attention(
     *,
     causal: bool = True,
     scale: Optional[float] = None,
-    blk_q: int = DEFAULT_BLK_Q,
-    blk_k: int = DEFAULT_BLK_K,
+    blk_q: Optional[int] = None,   # None: ``block_sizes`` from the shapes
+    blk_k: Optional[int] = None,
     interpret: bool | None = None,  # None: Mosaic on TPU, interpreted elsewhere
 ) -> jax.Array:
-    """Fused multi-head attention; value head dim may differ (MLA)."""
+    """Fused multi-head attention; value head dim may differ (MLA).  Causal
+    positions are aligned at 0 for queries and keys."""
     b, sq, h, d = q.shape
     _, skv, hkv, _ = k.shape
     dv = v.shape[-1]
     g = h // hkv
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
+    auto_q, auto_k = block_sizes(sq, skv, g, d, dv)
+    blk_q, blk_k = blk_q or auto_q, blk_k or auto_k
 
-    blk_q = min(blk_q, sq)
-    blk_k = min(blk_k, skv)
     pad_q = (-sq) % blk_q
     pad_k = (-skv) % blk_k
-    qp = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0))) if pad_q else q
-    kp = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0))) if pad_k else k
-    vp = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0))) if pad_k else v
+    nq, nk = (sq + pad_q) // blk_q, (skv + pad_k) // blk_k
+    # head-major: queries (B·Hkv, g, Sq, d), keys and values (B·Hkv, Skv, ·)
+    qh = jnp.pad(q.reshape(b, sq, hkv, g, d),
+                 ((0, 0), (0, pad_q), (0, 0), (0, 0), (0, 0)))
+    qh = qh.transpose(0, 2, 3, 1, 4).reshape(b * hkv, g, sq + pad_q, d)
+    kh = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0))).transpose(
+        0, 2, 1, 3).reshape(b * hkv, skv + pad_k, d)
+    vh = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0))).transpose(
+        0, 2, 1, 3).reshape(b * hkv, skv + pad_k, dv)
 
-    # head-major layout: (B*H, S, d) queries, (B*Hkv, S, d) keys/values
-    qh = qp.transpose(0, 2, 1, 3).reshape(b * h, sq + pad_q, d)
-    kh = kp.transpose(0, 2, 1, 3).reshape(b * hkv, skv + pad_k, d)
-    vh = vp.transpose(0, 2, 1, 3).reshape(b * hkv, skv + pad_k, dv)
-
-    nq = (sq + pad_q) // blk_q
-    nk = (skv + pad_k) // blk_k
-
-    def kv_head(i):   # query-head program index -> kv-head row
-        bb, hh = i // h, (i % h) // g
-        return bb * hkv + hh
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, causal=causal, blk_q=blk_q,
-                          blk_k=blk_k, nk=nk, seq_kv=skv),
-        grid=(b * h, nq, nk),
+    sched = _schedule(nq, nk, blk_q, blk_k, sq, skv, causal)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b * hkv, sched.shape[1]),
         in_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda i, j, t: (i, j, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda i, j, t: (kv_head(i), t, 0)),
-            pl.BlockSpec((1, blk_k, dv), lambda i, j, t: (kv_head(i), t, 0)),
+            pl.BlockSpec((1, g, blk_q, d), lambda i, p, s: (i, 0, s[0, p], 0)),
+            pl.BlockSpec((1, blk_k, d), lambda i, p, s: (i, s[1, p], 0)),
+            pl.BlockSpec((1, blk_k, dv), lambda i, p, s: (i, s[1, p], 0)),
         ],
-        out_specs=pl.BlockSpec((1, blk_q, dv), lambda i, j, t: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq + pad_q, dv), q.dtype),
+        out_specs=pl.BlockSpec((1, g, blk_q, dv),
+                               lambda i, p, s: (i, 0, s[0, p], 0)),
         scratch_shapes=[
-            pltpu.VMEM((blk_q, 1), jnp.float32),   # m: running row max
-            pltpu.VMEM((blk_q, 1), jnp.float32),   # l: running denominator
-            pltpu.VMEM((blk_q, dv), jnp.float32),  # acc: running numerator
+            pltpu.VMEM((g * blk_q, LANES), jnp.float32),  # m: running row max
+            pltpu.VMEM((g * blk_q, LANES), jnp.float32),  # l: running denominator
+            pltpu.VMEM((g * blk_q, dv), jnp.float32),   # acc: running numerator
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=float(scale), causal=causal, g=g,
+                          blk_q=blk_q, blk_k=blk_k, seq_kv=skv),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * hkv, g, sq + pad_q, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=auto_interpret(interpret),
-    )(qh, kh, vh)
+        name="flash_attention",
+    )(jnp.asarray(sched), qh, kh, vh)
 
-    out = out[:, :sq].reshape(b, h, sq, dv).transpose(0, 2, 1, 3)
-    return out
+    out = out[:, :, :sq].reshape(b, hkv, g, sq, dv)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, dv)
 
 
 def hbm_bytes(b, sq, skv, h, hkv, d, dv, bytes_per_el=2) -> int:

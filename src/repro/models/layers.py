@@ -2,10 +2,11 @@
 GQA/MQA attention blocks, sliding-window attention, SwiGLU MLP.
 
 All attention paths are memory-efficient by construction: scores are never
-materialized at (S, S) — prefill/train attention scans over KV blocks with an
-online softmax (the standard flash recurrence), so the 32k-prefill cells lower
-within HBM.  Decode attends over the full cache in one pass (scores are
-(B, H, 1, S), which is small).
+materialized at (S, S) — prefill/train attention is an online softmax over KV
+blocks (the standard flash recurrence): the fused Pallas kernel on a TPU, an
+XLA scan elsewhere, so the 32k-prefill cells lower within HBM.  Decode
+attends over the full cache in one pass (scores are (B, H, 1, S), which is
+small).
 
 Compute dtype is bf16 with fp32 softmax statistics and accumulators.
 """
@@ -22,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.distributed.sharding import current_policy
+from repro.kernels.flash_attention import flash_attention
 from repro.models import scanctl
 
 
@@ -81,10 +84,10 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
 
 NEG_INF = -1e30
 
-# performance overrides installed by the dry-run/launchers (EXPERIMENTS.md
-# §Perf): score_dtype bf16 halves the dominant score-chain HBM traffic at a
-# ~2-decimal attention-weight precision cost; kv_block trades scan trip count
-# against carried-accumulator rewrite traffic.
+# performance overrides of the XLA path, installed by ``launch/dryrun.py``:
+# score_dtype bf16 halves the score chain's HBM traffic at a ~2-decimal
+# attention-weight precision cost; kv_block trades scan trip count against
+# carried-accumulator rewrite traffic.
 _ATTN_OVERRIDES = threading.local()
 
 
@@ -104,20 +107,60 @@ def _attn_override(key, default):
     return getattr(_ATTN_OVERRIDES, "cfg", {}).get(key, default)
 
 
+def use_fused_attention(window: Optional[int]) -> bool:
+    """Whether full-sequence attention runs as the fused Pallas kernel: on a
+    TPU, with no sharding policy active (the kernel is one device's) and no
+    sliding window.  Everything else takes the XLA scan."""
+    return (window is None and jax.default_backend() == "tpu"
+            and current_policy() is None)
+
+
 def chunked_attention(
     q: jax.Array,                 # (B, Sq, H, D)
     k: jax.Array,                 # (B, Skv, Hkv, D)
-    v: jax.Array,                 # (B, Skv, Hkv, D)
+    v: jax.Array,                 # (B, Skv, Hkv, Dv)
     *,
     causal: bool,
-    q_offset: int | jax.Array = 0,   # absolute position of q[0]
     window: Optional[int] = None,    # sliding-window width (None = full)
     kv_block: int = 1024,
     scale: Optional[float] = None,
 ) -> jax.Array:
-    """Memory-efficient attention: scan over KV blocks, never materialize SxS.
+    """Memory-efficient attention: scores are never materialized at SxS.
 
-    Value head dim may differ from the q/k head dim (MLA)."""
+    Value head dim may differ from the q/k head dim (MLA).  Causal positions
+    are aligned at 0 for queries and keys.  Where ``use_fused_attention``
+    holds this is the fused kernel (``kernels/flash_attention.py``), whose
+    gradient is the XLA scan's; elsewhere the XLA scan over KV blocks."""
+    if use_fused_attention(window):
+        return _fused_attention(q, k, v, causal, scale, kv_block)
+    return _scan_attention(q, k, v, causal=causal, window=window,
+                           kv_block=kv_block, scale=scale)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _fused_attention(q, k, v, causal, scale, kv_block):
+    return flash_attention(q, k, v, causal=causal, scale=scale)
+
+
+def _fused_fwd(q, k, v, causal, scale, kv_block):
+    return _fused_attention(q, k, v, causal, scale, kv_block), (q, k, v)
+
+
+def _fused_bwd(causal, scale, kv_block, res, ct):
+    """The XLA scan's VJP, recomputed from q, k and v."""
+    _, vjp = jax.vjp(functools.partial(
+        _scan_attention, causal=causal, kv_block=kv_block, scale=scale), *res)
+    return vjp(ct)
+
+
+_fused_attention.defvjp(_fused_fwd, _fused_bwd)
+
+
+def _scan_attention(q, k, v, *, causal, window=None, kv_block=1024,
+                    scale=None):
+    """Online softmax over KV blocks in XLA (one ``scanctl.scan`` step per
+    block): bf16 q·k with f32 accumulation, f32 softmax statistics, p cast
+    to v's dtype for P·V."""
     b, sq, h, d = q.shape
     _, skv, hkv, _ = k.shape
     dv = v.shape[-1]
@@ -135,7 +178,7 @@ def chunked_attention(
     nblk = skv // kv_block
 
     qg = q.reshape(b, sq, hkv, g, d)
-    q_pos = q_offset + jnp.arange(sq)
+    q_pos = jnp.arange(sq)
 
     kb = k.reshape(b, nblk, kv_block, hkv, d).transpose(1, 0, 2, 3, 4)
     vb = v.reshape(b, nblk, kv_block, hkv, dv).transpose(1, 0, 2, 3, 4)

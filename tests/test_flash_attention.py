@@ -3,14 +3,20 @@
 Sweeps shapes (incl. GQA groupings, MLA-style dv != d, non-divisible sequence
 lengths that exercise padding) and dtypes, causal and bidirectional, plus a
 seeded random-shape sweep (hypothesis-free so collection never depends on an
-optional package).
+optional package).  Then the kernel against the XLA scan it replaces on a TPU
+(``layers._scan_attention``), its causal schedule, the custom VJP of the
+fused path and the rule that picks kernel or scan.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.distributed.sharding import ShardingPolicy, use_policy
+from repro.kernels import flash_attention as FA
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ref import attention_ref
 from repro.models import layers as L
@@ -153,3 +159,167 @@ def test_random_shapes(seed):
     hkv = max(1, h // g)
     h = hkv * g
     check(b, sq, sq, h, hkv, d, d, jnp.float32, causal, blk_q=32, blk_k=32)
+
+
+# ---------------------------------------------------------------------------
+# the kernel against the XLA path it replaces on a TPU, the schedule, the
+# custom VJP and the dispatch rule
+# ---------------------------------------------------------------------------
+
+# (b, s, h, hkv, d, dv, blocks): GQA g = 8 at d 128, MiniCPM3's and
+# DeepSeek-V3's MLA widths; S a multiple of neither block, blocks chosen
+# from the shapes (None) or small enough for several of each
+XLA_SHAPES = {
+    "gqa8_d128": (1, 200, 16, 2, 128, 128, None),
+    "gqa8_d128_blocks": (1, 300, 16, 2, 128, 128, (32, 128)),
+    "mla_d96_dv64": (1, 300, 4, 4, 96, 64, (64, 128)),
+    "mla_d192_dv128": (1, 200, 2, 2, 192, 128, None),
+}
+
+
+def _qkv(b, s, h, hkv, d, dv, dtype=jnp.bfloat16, seed=20):
+    return (make((b, s, h, d), dtype, seed), make((b, s, hkv, d), dtype, seed + 1),
+            make((b, s, hkv, dv), dtype, seed + 2))
+
+
+def _blocks(blocks):
+    return dict(zip(("blk_q", "blk_k"), blocks)) if blocks else {}
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("shape", list(XLA_SHAPES))
+def test_matches_xla_path(shape, causal):
+    """The kernel's arithmetic is the XLA scan's (bf16 dots with f32
+    accumulation, f32 softmax statistics, bf16 p into P·V): the two differ
+    only by where the online softmax's blocks fall."""
+    b, s, h, hkv, d, dv, blocks = XLA_SHAPES[shape]
+    q, k, v = _qkv(b, s, h, hkv, d, dv)
+    out = flash_attention(q, k, v, causal=causal, scale=0.3, **_blocks(blocks))
+    ref = L._scan_attention(q, k, v, causal=causal, scale=0.3)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=3e-2, rtol=3e-2)
+
+
+def test_right_padded_ragged_batch():
+    """Rows right-padded to a common length: a causal row's valid positions
+    read nothing past them, so poisoning the padding changes none of them,
+    and they match the XLA path."""
+    lens = (300, 173)
+    q, k, v = _qkv(2, 300, 16, 2, 128, 128)
+    pad = np.arange(300)[None, :, None, None] >= np.asarray(lens)[:, None, None, None]
+    poison = lambda x: jnp.where(pad, jnp.asarray(1e4, x.dtype), x)
+    clean = flash_attention(q, k, v, causal=True, blk_q=32, blk_k=128)
+    dirty = flash_attention(poison(q), poison(k), poison(v), causal=True,
+                            blk_q=32, blk_k=128)
+    ref = L._scan_attention(q, k, v, causal=True)
+    for row, n in enumerate(lens):
+        np.testing.assert_array_equal(
+            np.asarray(clean[row, :n]).view(np.uint16),
+            np.asarray(dirty[row, :n]).view(np.uint16))
+        np.testing.assert_allclose(np.asarray(clean[row, :n], np.float32),
+                                   np.asarray(ref[row, :n], np.float32),
+                                   atol=3e-2, rtol=3e-2)
+
+
+_SCHEDULE = FA._schedule
+
+
+def _full_schedule(nq, nk, blk_q, blk_k, sq, skv, causal):
+    """Every KV block for every q block, masked or not."""
+    return _SCHEDULE(nq, nk, blk_q, blk_k, sq, skv, False)
+
+
+@pytest.mark.parametrize("shape", ["gqa8_d128_blocks", "mla_d96_dv64"])
+def test_causal_schedule_equals_visiting_every_block(shape, monkeypatch):
+    """Skipping the KV blocks above the diagonal changes no bit: a fully
+    masked block adds exp(-inf) = 0 to l and acc and leaves m alone."""
+    b, s, h, hkv, d, dv, blocks = XLA_SHAPES[shape]
+    q, k, v = _qkv(b, s, h, hkv, d, dv)
+    run = lambda: jax.jit(functools.partial(
+        flash_attention.__wrapped__, causal=True, **_blocks(blocks)))(q, k, v)
+    skipped = run()
+    monkeypatch.setattr(FA, "_schedule", _full_schedule)
+    every = run()
+    np.testing.assert_array_equal(np.asarray(skipped).view(np.uint16),
+                                  np.asarray(every).view(np.uint16))
+
+
+@pytest.mark.parametrize("blk_q,blk_k,pairs", [
+    (128, 128, 1 + 2 + 3 + 4),      # square blocks: the lower triangle
+    (64, 128, 1 + 1 + 2 + 2 + 3 + 3 + 4 + 4),
+    (128, 256, 1 + 1 + 2 + 2),
+])
+def test_causal_schedule_counts(blk_q, blk_k, pairs):
+    nq, nk = 512 // blk_q, 512 // blk_k
+    sched = FA._schedule(nq, nk, blk_q, blk_k, 512, 512, True)
+    assert sched.shape == (3, pairs)
+    # each q block ends on the KV block holding its last query's key
+    last = sched[1][sched[2] == 1]
+    assert list(last) == [((j + 1) * blk_q - 1) // blk_k for j in range(nq)]
+    assert FA._schedule(nq, nk, blk_q, blk_k, 512, 512, False).shape == (
+        3, nq * nk)
+
+
+@pytest.mark.parametrize("sq,g,d,dv,blocks", [
+    (2048, 8, 128, 128, (512, 512)),     # qwen3-32b: 8 heads a group
+    (4096, 1, 96, 64, (1024, 1024)),     # minicpm3-4b: MLA
+    (512, 1, 192, 128, (512, 512)),      # DeepSeek-V3: one block
+    (200, 8, 128, 128, (208, 256)),
+])
+def test_block_sizes_fit_the_budget(sq, g, d, dv, blocks):
+    blk_q, blk_k = FA.block_sizes(sq, sq, g, d, dv)
+    assert (blk_q, blk_k) == blocks
+    assert blk_q % 16 == 0 and blk_k % 128 == 0
+    assert g * blk_q * blk_k <= FA.MAX_TILE
+    assert FA.vmem_bytes(blk_q, blk_k, g, d, dv) <= FA.VMEM_BUDGET
+
+
+def _loss(attn, q, k, v, w):
+    return jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_grad_through_the_fused_path(causal, monkeypatch):
+    """On a TPU ``chunked_attention`` takes the kernel, whose VJP is the XLA
+    scan's: gradients equal the scan's."""
+    q, k, v = _qkv(1, 130, 8, 2, 64, 64, jnp.float32)
+    w = make((1, 130, 8, 64), jnp.float32, 30)
+    scan = functools.partial(L._scan_attention, causal=causal, kv_block=64)
+    ref = jax.grad(_loss, argnums=(1, 2, 3))(scan, q, k, v, w)
+    monkeypatch.setattr(L, "use_fused_attention", lambda window: True)
+    fused = functools.partial(L.chunked_attention, causal=causal, kv_block=64)
+    out = fused(q, k, v)
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(flash_attention(q, k, v, causal=causal)))
+    got = jax.grad(_loss, argnums=(1, 2, 3))(fused, q, k, v, w)
+    for a, b in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5, rtol=1e-5)
+
+
+def _one_device_policy():
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
+                             ("data", "model"))
+    return ShardingPolicy(mesh)
+
+
+@pytest.mark.parametrize("backend,policy,window,fused", [
+    ("tpu", False, None, True),
+    ("tpu", True, None, False),
+    ("tpu", False, 64, False),
+    ("cpu", False, None, False),
+])
+def test_dispatch_rule(backend, policy, window, fused, monkeypatch):
+    """The kernel runs on a TPU with no sharding policy and no window;
+    everything else keeps the XLA scan."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    calls = []
+    monkeypatch.setattr(L, "_fused_attention",
+                        lambda *a: calls.append(a) or L._scan_attention(
+                            *a[:3], causal=a[3], scale=a[4]))
+    q, k, v = _qkv(1, 64, 4, 2, 32, 32)
+    with use_policy(_one_device_policy() if policy else None):
+        assert L.use_fused_attention(window) is fused
+        L.chunked_attention(q, k, v, causal=True, window=window)
+    assert len(calls) == int(fused)

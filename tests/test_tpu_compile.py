@@ -11,7 +11,7 @@ layer of 4 x 2048 tokens x 8 heads x 128 (8192 codec rows of 1024), 32 KiB
 pages of 16 tokens, and minicpm3-4b's MLA latent pages (kv_lora 256, rope 32);
 and DeepSeek-V3's expert products (d_model 7168, experts 2048 wide, 8 held,
 seven MoE layers stacked) at a decode step's 64 rows and a prefill block's
-16384.
+16384; and the fused prefill attention at the three cells' prompt shapes.
 """
 
 import os
@@ -22,6 +22,7 @@ import pytest
 
 from repro.kernels import splitzip_attention as SA
 from repro.kernels import splitzip_decode, splitzip_encode
+from repro.kernels.flash_attention import flash_attention
 from repro.kernels.grouped_matmul import moe_grouped_matmul
 
 EXPONENTS = tuple(range(112, 128))
@@ -141,3 +142,18 @@ def test_moe_grouped_matmul_deepseek_v3(one_chip, rows, glu):
         _shape(one_chip, (rows, k), jnp.bfloat16),
         _shape(one_chip, (7, 8, k, n), jnp.bfloat16),
         _shape(one_chip, (8,), jnp.int32), _shape(one_chip, (), jnp.int32))
+
+
+@pytest.mark.parametrize("b,s,h,hkv,d,dv", [
+    (4, 2048, 64, 8, 128, 128),      # qwen3-32b: GQA, 8 heads a group
+    (2, 4096, 40, 40, 96, 64),       # minicpm3-4b: MLA, d 96 / dv 64
+    (8, 512, 128, 128, 192, 128),    # DeepSeek-V3: MLA, d 192 / dv 128
+], ids=("qwen3-32b", "minicpm3-4b", "deepseek-v3"))
+def test_flash_attention_prefill(one_chip, b, s, h, hkv, d, dv):
+    """The fused causal prefill kernel at the cells' widths, with the block
+    sizes it chooses from the shapes."""
+    _compile(lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                             interpret=False),
+             _shape(one_chip, (b, s, h, d), jnp.bfloat16),
+             _shape(one_chip, (b, s, hkv, d), jnp.bfloat16),
+             _shape(one_chip, (b, s, hkv, dv), jnp.bfloat16))
