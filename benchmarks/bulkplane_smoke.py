@@ -1,15 +1,15 @@
 """Bulk-data plane smoke: checkpoint save/restore + 2-pod ring_reduce.
 
-Exercises the two executors the refactor added to ``TransferSession``:
+Exercises the two bulk-plane executors outside the KV hop:
 
 * **persistent** — a train-state pytree (bf16 params, fp32 optimizer
-  moments, int step) round-trips through ``session.save``/``session.load``
-  bit-exactly via ``distributed/checkpoint.Checkpointer``, and a corrupted
-  frame falls back to the previous step (the fallback is driven by
-  Fletcher-32 + ``WireIntegrityError``, not ad-hoc hashing).
-* **collective** — ``compressed_cross_pod_mean`` rides
-  ``session.ring_reduce`` on a 2-pod CPU mesh and matches the ``jnp.mean``
-  all-reduce bitwise, with plan-derived wire accounting.
+  moments, int step) round-trips through ``checkpoint.save_tree`` /
+  ``load_tree`` bit-exactly via ``distributed/checkpoint.Checkpointer``, and
+  a corrupted frame falls back to the previous step (the fallback is driven
+  by Fletcher-32 + ``WireIntegrityError``, not ad-hoc hashing).
+* **collective** — ``compressed_cross_pod_mean`` runs the compressed ring
+  of ``training/grad_compress.py`` on a 2-pod CPU mesh and matches the
+  ``jnp.mean`` all-reduce bitwise, with plan-derived wire accounting.
 
 CI runs this with ``SPLITZIP_BENCH_SMOKE=1`` as
 ``python -m benchmarks.run --only bulkplane`` — its own process, so the

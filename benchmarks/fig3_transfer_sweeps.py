@@ -17,7 +17,7 @@ from repro.configs.base import get_config
 from repro.core import codebook as cbm
 from repro.core import wire
 from repro.core.pipeline import CodecProfile
-from repro.serving.transfer import transfer_report
+from repro.serving.plan import transfer_report
 
 LINK_BW = 25e9        # 200 Gb/s RDMA-class per-transfer effective bandwidth
 FIXED_OVERHEAD = 2e-4  # launch/registration overhead (short-payload regime)

@@ -21,7 +21,7 @@ from benchmarks.common import bench_config, generate_kv_bits, pooled_bits
 from repro.configs.base import get_config
 from repro.core import codebook as cbm
 from repro.core.pipeline import CodecProfile, pipelined_transfer_time
-from repro.serving.transfer import TransferConfig, transfer_report
+from repro.serving.plan import TransferConfig, transfer_report
 
 N_CHUNKS = 8  # pipelined-engine granularity (TransferPlan n_chunks)
 

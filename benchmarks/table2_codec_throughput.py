@@ -57,8 +57,7 @@ from repro.core import backend as B
 from repro.core import codebook as cbm
 from repro.core import codec as C
 from repro.core.profile import CalibratedProfile, save_profiles
-from repro.serving.plan import TransferPlan
-from repro.serving.transfer import TransferConfig, transfer_cache_chunked
+from repro.serving.plan import TransferConfig, TransferPlan
 
 SPLITZIP_BACKENDS = ("wire", "xla", "pallas")
 
@@ -153,12 +152,12 @@ def _measure_backend(name: str, be, x, cb, bits, nbytes, repeats) -> CodecResult
 
 
 def _planned_vs_legacy_transfer(x, cb, nbytes, repeats) -> dict:
-    """Plan/execute API vs the one-shot shim on the chunked local engine.
+    """Plan/execute API vs a one-shot plan on the chunked local engine.
 
-    The shim rebuilds the TransferPlan (route resolution, segmentation,
-    capacity schedule) on EVERY call; the session builds it once and reuses
-    it — the compile-once/run-many win of the plan API, measured on the same
-    bit-exact pipeline.  Also reports the per-call wire bytes so the row
+    The one-shot row rebuilds the TransferPlan (route resolution,
+    segmentation, capacity schedule) and its session on EVERY call; the
+    planned row builds them once and reuses them — the compile-once/run-many
+    win of the plan API, measured on the same bit-exact pipeline.  Also reports the per-call wire bytes so the row
     doubles as a ratio regression gate."""
     cache = {"kv": x}
     tc = TransferConfig(codebook=cb, backend="xla", n_chunks=8)
@@ -169,7 +168,8 @@ def _planned_vs_legacy_transfer(x, cb, nbytes, repeats) -> dict:
         jax.block_until_ready(jax.tree.leaves(out))
 
     def _legacy():
-        out, _ = transfer_cache_chunked(cache, tc)
+        out = TransferPlan.build(cache, tc, granularity="chunked").session(
+        ).transfer(cache)
         jax.block_until_ready(jax.tree.leaves(out))
 
     _planned(); _legacy()   # warmup (jit caches shared: same shapes)

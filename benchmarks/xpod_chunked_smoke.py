@@ -2,10 +2,10 @@
 
 Exercises the acceptance property of the plan/execute API: a
 ``TransferPlan`` executed on a multi-pod mesh with ``n_chunks > 1`` (per-
-chunk ``lax.ppermute``, double-buffered inside ``shard_map``) reproduces
-``transfer_cache_cross_pod`` semantics bit-identically to the whole-tensor
-path, and the per-chunk collectives move the same compressed payload (HLO
-collective-permute bytes are compared).
+chunk ``lax.ppermute``, double-buffered inside ``shard_map``) is
+bit-identical to the whole-tensor collective, and the per-chunk
+collectives move the same compressed payload (HLO collective-permute bytes
+are compared).
 
 CI runs this with ``SPLITZIP_BENCH_SMOKE=1`` (tiny cache) as
 ``python -m benchmarks.run --only xpod_chunked`` — its own process, so the

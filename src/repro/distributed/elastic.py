@@ -97,12 +97,14 @@ def reshard(state, old_mesh_plan: Optional[MeshPlan],
             compress_fp32: bool = True, faults=None, verify: bool = False
             ) -> Tuple[Any, TransferStats]:
     """Ship ``state`` from ``old_mesh_plan``'s configuration onto
-    ``new_mesh_plan``'s mesh through the bulk-data plane: one
+    ``new_mesh_plan``'s mesh through the bulk-data plane: one local
     :class:`TransferPlan` over the state pytree, host-staged splitzip
     streams via the session's tensor executor (bit-exact; fp32 rides the
-    hi/lo split), then ``device_put`` onto the new mesh.  The old mesh may
-    already be gone (that's the point — after a node loss the state is only
-    host-addressable), so the hop never touches old-mesh collectives.
+    hi/lo split, integrity framing and re-fetches ride the session's
+    ``verify=`` / ``faults=``), then ``device_put`` onto the new mesh.  The
+    old mesh may already be gone (that's the point — after a node loss the
+    state is only host-addressable), so the plan is built with
+    ``mesh=None`` and the hop never touches old-mesh collectives.
 
     ``shardings``: optional pytree of :class:`NamedSharding` matching
     ``state``; defaults to replicated on the new mesh (the training step's
@@ -121,7 +123,7 @@ def reshard(state, old_mesh_plan: Optional[MeshPlan],
         mesh = make_mesh(new_mesh_plan.shape, new_mesh_plan.axes)
         repl = NamedSharding(mesh, P())
         shardings = jax.tree.map(lambda _: repl, state)
-    out = sess.reshard(state, shardings)
+    out = jax.device_put(sess.transfer(state), shardings)
     return out, sess.last_stats
 
 

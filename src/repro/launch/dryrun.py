@@ -109,7 +109,7 @@ def _variant_ctx(variant: str):
 
 def _transfer_config(variant: str):
     from repro.core.codebook import DEFAULT_BF16_CODEBOOK as cb
-    from repro.serving import transfer as T
+    from repro.serving import plan as T
     if variant.endswith("_raw"):
         return T.TransferConfig(codebook=cb, enabled=False)
     if variant.endswith("_chunked"):

@@ -36,7 +36,7 @@ from repro.core.pipeline import CodecProfile
 from repro.core.spans import host_read, span
 from repro.models import model as M
 from repro.models.kvcache import DecodeState, cache_bytes
-from repro.serving import transfer as T
+from repro.serving import plan as T
 from repro.serving.plan import TransferPlan
 from repro.serving.session import TransferSession
 from repro.serving.decode import make_decode_fn

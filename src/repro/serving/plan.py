@@ -10,10 +10,10 @@ hardware-aware dispatch both make this argument: policy is a property of the
 model + deployment, not of the individual transfer.
 
 The structure is ANY pytree, not just a KV cache: train states, optimizer
-states, and pod-partial gradient trees build plans the same way, which is
-what lets checkpointing (persistent executor), elastic resharding, and the
-compressed gradient ring (collective executor) all ride the one planned,
-verified, accounted byte-moving core.
+states, and pod-partial gradient trees build plans the same way, so
+checkpointing (``distributed/checkpoint.py``), elastic resharding and the
+compressed gradient ring (``training/grad_compress.py``) share the plan's
+route table and accounting.
 
 Per-leaf routing table (resolved at build time):
 
@@ -222,10 +222,6 @@ class TransferStats:
         return sum(self.chunk_retry_steps)
 
 
-# back-compat alias: the chunked engine's stats type predates the plan API
-ChunkedTransferStats = TransferStats
-
-
 # ---------------------------------------------------------------------------
 # the plan
 # ---------------------------------------------------------------------------
@@ -383,19 +379,6 @@ class TransferPlan:
                 out += r.raw_bytes
         return stream * scale, fp8 * scale, out * scale
 
-    def collective_wire_bytes(self, ratio: float, n_hops: int,
-                              scale: float = 1.0) -> float:
-        """Analytic wire bytes for a ring collective over this plan: each of
-        the ``n_hops`` hops ships the routed stream at the codec ``ratio``
-        (a calibrated/paper :class:`~repro.core.pipeline.CodecProfile`
-        ratio — NOT a hard-coded guess) plus the incompressible bytes at
-        full cost.  ``scale`` evaluates a per-participant slice of the plan
-        (e.g. ``1/n_pod`` when the plan was built over pod-stacked leaves).
-        The lowered HLO's ppermute operand sizes are the ground truth this
-        estimates (analysis/roofline.py reads those)."""
-        stream, fp8, out = self.byte_split(scale)
-        return ((stream + fp8) / max(ratio, 1e-9) + out) * n_hops
-
     def expected_attempts(self, overflow_p: float) -> Tuple[float, float]:
         """``(expected encode attempts per unit, raw-fallback fraction)``
         under THIS plan's geometric capacity schedule when each attempt
@@ -532,3 +515,67 @@ class TransferPlan:
         from repro.serving.session import TransferSession
         return TransferSession(self, faults=faults, verify=verify,
                                retain_last=retain_last)
+
+
+def raw_wire_bytes(cache) -> float:
+    return float(sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache)))
+
+
+# ---------------------------------------------------------------------------
+# analytic transfer report (paper Fig. 3 / Fig. 4 accounting)
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class TransferReport:
+    raw_bytes: float
+    wire_bytes: float
+    t_native: float
+    t_splitzip: float
+    t_encode: float
+    t_transfer: float
+    t_decode: float
+
+    @property
+    def ratio(self) -> float:
+        return self.raw_bytes / max(self.wire_bytes, 1.0)
+
+    @property
+    def speedup(self) -> float:
+        return self.t_native / max(self.t_splitzip, 1e-12)
+
+
+def transfer_report(raw_bytes: float, wire_bytes: float,
+                    profile: CodecProfile, n_chunks: int = 1,
+                    plan: Optional[TransferPlan] = None) -> TransferReport:
+    """Analytic accounting from MEASURED wire bytes: additive
+    encode + compressed transfer + decode (Fig. 4) when ``n_chunks == 1``,
+    chunked steady-state pipeline (Appendix A) when ``n_chunks > 1``.
+
+    With ``plan=`` the pipeline term splits the MEASURED totals across
+    chunks in the plan's ACTUAL segment proportions (short tail chunk
+    included) and runs the flowshop recurrence — still a function of the
+    measured raw/wire bytes, so it stays consistent when the totals
+    accumulate over many engine calls and when raw fallbacks inflate the
+    wire bytes (``plan.estimate_time`` is the single-transfer a-priori
+    estimate instead)."""
+    t_enc = raw_bytes / profile.g_enc
+    t_dec = raw_bytes / profile.g_dec
+    t_xfer = wire_bytes / profile.link_bw
+    if plan is not None and plan.granularity == "chunked":
+        seg = plan.chunk_raw_bytes()
+        fracs = [s / sum(seg) for s in seg]
+        t_total = flowshop_makespan(
+            [(f * t_enc, f * t_xfer, f * t_dec) for f in fracs]
+        ) + profile.fixed_overhead_s
+    elif n_chunks > 1:
+        per = [t / n_chunks for t in (t_enc, t_xfer, t_dec)]
+        t_total = sum(per) + (n_chunks - 1) * max(per) + profile.fixed_overhead_s
+    else:
+        t_total = t_enc + t_xfer + t_dec + profile.fixed_overhead_s
+    return TransferReport(
+        raw_bytes=raw_bytes,
+        wire_bytes=wire_bytes,
+        t_native=raw_bytes / profile.link_bw + profile.fixed_overhead_s,
+        t_splitzip=t_total,
+        t_encode=t_enc, t_transfer=t_xfer, t_decode=t_dec,
+    )

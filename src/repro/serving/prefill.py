@@ -2,7 +2,7 @@
 
 In the disaggregated deployment this code runs on the prefill pod; the jitted
 ``prefill_step`` is the unit of work per prompt batch, and its output cache is
-handed to the transfer engine (serving/transfer.py) — compressed with
+handed to the transfer session (serving/session.py) — compressed with
 SplitZip — before any decode work can start (the paper's critical path).
 """
 

@@ -4,10 +4,9 @@ One session == one (plan, execution-target) pair.  ``send(cache)`` runs the
 prefill-side work (encode + the wire hop), ``recv()`` the decode-side work,
 ``transfer(cache)`` fuses both; ``last_stats`` carries per-call accounting.
 All serving consumers (``DisaggregatedEngine``, launchers, benchmarks,
-examples) go through this API — the free functions in
-:mod:`repro.serving.transfer` are deprecation shims over a one-shot plan.
+examples) go through this API.
 
-Five execution paths, selected by the plan and the entry point:
+Three execution paths, selected by the plan:
 
 * **local / tensor** (``mesh=None, n_chunks == 1``): per-leaf encode ->
   hand-off -> decode, per-tensor raw fallback, geometric capacity retries.
@@ -23,27 +22,16 @@ Five execution paths, selected by the plan and the entry point:
   the traced program, not just modeled.  In-graph execution cannot branch on
   the concrete ``ok`` flag, so the mesh path encodes once at plan capacity;
   overflow is detected off-graph exactly as on the whole-tensor path.
-* **persistent** (``save(path)`` / ``load(path)``): per-leaf SZ02 wire
-  frames on disk plus a plan-derived JSON manifest
-  (docs/wire_format.md §9).  Loads re-verify Fletcher-32 per file AND the
-  payload's own integrity-frame table; mismatches re-fetch down the plan's
-  retry budget and raise :class:`~repro.core.wire.WireIntegrityError` when
-  the corruption is persistent.  distributed/checkpoint.py is a thin
-  wrapper over this executor.
-* **collective** (``ring_reduce(stacked)``): grad_compress's rotating-ring
-  ppermute exchange over compressed streams, traced inside ``shard_map``
-  over the plan's pod axis with the mesh executor's bit-pinned permutes.
-  training/grad_compress.py is a thin wrapper over this executor.
+
+Checkpoint files (``distributed/checkpoint.py``) and the compressed
+gradient ring (``training/grad_compress.py``) run the same plans in
+executors of their own.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import json
-import os
-import shutil
-import tempfile
 from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
@@ -52,18 +40,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from jax import shard_map
-from repro.core.backend import CodecBackend, WireCompressed, get_backend
+from repro.core.backend import CodecBackend, get_backend
 from repro.core.pipeline import ChunkSchedule
 from repro.core.spans import host_read, span
-from repro.core.wire import WireIntegrityError, WireStats, fletcher32
 from repro.serving.faults import FaultChannel, resolve_faults
 from repro.serving.plan import TransferPlan, TransferStats, leaf_key
 
 _WIRE_INT = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}
-
-# persistent-executor manifest (docs/wire_format.md §9)
-PERSIST_MANIFEST = "manifest.json"
-PERSIST_FORMAT = "szpersist-1"
 
 # hard ceiling on wire attempts per unit (initial ship + re-fetches).  The
 # default FaultPlan stops randomized faults at max_attempt=8, so only an
@@ -91,7 +74,7 @@ def _backend_for(comp_obj, be: CodecBackend) -> CodecBackend:
     return be if be.jittable else get_backend("xla")
 
 
-def _permute_leaf(x: jax.Array, axis_name: str, src: int, dst: int) -> jax.Array:
+def permute_leaf(x: jax.Array, axis_name: str, perm) -> jax.Array:
     """ppermute with the payload pinned to its exact bit width.
 
     XLA CPU (and some TPU paths) upcast small-float collectives — doubling
@@ -102,9 +85,9 @@ def _permute_leaf(x: jax.Array, axis_name: str, src: int, dst: int) -> jax.Array
     if jnp.issubdtype(x.dtype, jnp.floating) and x.dtype.itemsize in _WIRE_INT:
         w = _WIRE_INT[x.dtype.itemsize]
         y = jax.lax.ppermute(jax.lax.bitcast_convert_type(x, w), axis_name,
-                             perm=[(src, dst)])
+                             perm=perm)
         return jax.lax.bitcast_convert_type(y, x.dtype)
-    return jax.lax.ppermute(x, axis_name, perm=[(src, dst)])
+    return jax.lax.ppermute(x, axis_name, perm=perm)
 
 
 # ---------------------------------------------------------------------------
@@ -386,12 +369,8 @@ class TransferSession:
         self._retained = None
         # prefix-delta state: session-id -> _PrefixEntry (see transfer_delta)
         self._prefix_index: Optional[PrefixIndex] = None
-        # executor closures, built on first use: a mesh plan may only ever
-        # run the collective executor (ring specs don't fit the send/recv
-        # out_specs convention), so neither shard_map is constructed eagerly
+        # the mesh program, built on first use
         self._mesh_fn = None
-        self._ring_fns = {}         # frozenset(raw-forced leaf idx) -> fn
-        self._ring_routes = None    # per-participant routes for the ring
 
     def _object_checksum(self, obj) -> int:
         """Fletcher-32 over any wire object — compressed (backend leaves or
@@ -722,394 +701,6 @@ class TransferSession:
             self._mesh_fn = self._build_mesh_fn()
         leaves = jax.tree_util.tree_leaves(cache)
         return self._mesh_fn.lower(*leaves).compile().as_text()
-
-    # -- persistent executor -------------------------------------------------
-    def save(self, path: str, tree, *, extra: Optional[Dict] = None,
-             check: bool = True) -> str:
-        """Write ``tree`` to ``path`` as one SZ02 wire frame per routed leaf
-        plus a plan-derived JSON manifest (docs/wire_format.md §9).
-
-        Routes execute exactly as on the wire: 'splitzip' leaves become SZ02
-        payloads (with their embedded Fletcher-32 integrity sections), fp32
-        hi/lo leaves an SZ02 hi-half payload followed by the raw lo bytes,
-        'fp8' leaves an SZ02 payload under the fp8 codebook, 'raw' leaves
-        their exact bytes.  Atomicity rule: everything is written into a
-        temp directory next to ``path`` and renamed into place, so a
-        directory named ``path`` is either absent or complete.  Returns
-        ``path``; per-call accounting in ``last_stats``."""
-        if self.plan.mesh is not None:
-            raise ValueError("save/load run on host files; build the plan "
-                             "with mesh=None")
-        if check:
-            self._check_structure(tree)
-        self._uid += 1
-        plan, tc = self.plan, self.plan.tc
-        wire_be = get_backend("wire")
-        stats = TransferStats(chunk_wire_bytes=[], chunk_ok=[],
-                              raw_passthrough_bytes=0.0,
-                              n_elements=plan.stream_len)
-        flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-        parent = os.path.dirname(os.path.abspath(path)) or "."
-        os.makedirs(parent, exist_ok=True)
-        tmp = tempfile.mkdtemp(dir=parent, prefix=".tmp_persist_")
-        entries = []
-        try:
-            for i, ((_, leaf), r) in enumerate(zip(flat, plan.routes)):
-                fname = f"leaf_{i:05d}.szc"
-                payload, tail = b"", b""
-                if r.route == "splitzip":
-                    ct = wire_be.encode(leaf, tc.codebook, chunk=tc.chunk)
-                    payload = ct.payload
-                    stats.leaf_wire_bytes[r.key] = float(len(payload))
-                    stats.leaf_ok[r.key] = True
-                elif r.route == "fp32_hilo":
-                    u = jax.lax.bitcast_convert_type(leaf, jnp.uint32)
-                    hi = jax.lax.bitcast_convert_type(
-                        (u >> 16).astype(jnp.uint16), jnp.bfloat16)
-                    ct = wire_be.encode(hi, tc.codebook, chunk=tc.chunk)
-                    payload = ct.payload
-                    tail = np.asarray((u & 0xFFFF).astype(jnp.uint16)).tobytes()
-                    stats.leaf_wire_bytes[r.key] = float(len(payload))
-                    stats.leaf_ok[r.key] = True
-                    stats.fp32_lo_wire_bytes += float(len(tail))
-                elif r.route == "fp8":
-                    ct = wire_be.encode(leaf, plan.fp8_codebook, chunk=tc.chunk)
-                    payload = ct.payload
-                    stats.fp8_wire_bytes += float(len(payload))
-                    stats.leaf_ok[r.key] = True
-                else:
-                    tail = np.asarray(leaf).tobytes()
-                    stats.raw_passthrough_bytes += float(len(tail))
-                blob = payload + tail
-                with open(os.path.join(tmp, fname), "wb") as f:
-                    f.write(blob)
-                entries.append({
-                    "key": r.key, "file": fname, "route": r.route,
-                    "shape": list(r.shape), "dtype": r.dtype,
-                    "sz_bytes": len(payload),
-                    "checksum": int(fletcher32(np.frombuffer(blob, np.uint8))),
-                })
-            manifest = {"format": PERSIST_FORMAT,
-                        "codebook": {"fmt": tc.codebook.fmt,
-                                     "exponents": list(tc.codebook.exponents)},
-                        "extra": extra or {}, "leaves": entries}
-            with open(os.path.join(tmp, PERSIST_MANIFEST), "w") as f:
-                json.dump(manifest, f)
-            if os.path.exists(path):
-                shutil.rmtree(path)
-            os.rename(tmp, path)
-        except BaseException:
-            shutil.rmtree(tmp, ignore_errors=True)
-            raise
-        self.last_stats = stats
-        self._account()
-        return path
-
-    def load(self, path: str) -> Tuple[object, Dict]:
-        """Read a :meth:`save` directory back into the plan's pytree,
-        bit-exactly.  Returns ``(tree, extra)``.
-
-        Every leaf file is verified twice: Fletcher-32 over the file bytes
-        against the manifest, then the SZ02 payload's own integrity-frame
-        table during decode.  A mismatch (or an injected ``faults=`` frame
-        fault) re-fetches the file down the plan's retry budget
-        (``retry_doublings + 1`` re-reads, counted in
-        ``last_stats.refetches``); persistent corruption raises
-        :class:`~repro.core.wire.WireIntegrityError` — the caller
-        (distributed/checkpoint.py) falls back to the previous step."""
-        if self.plan.mesh is not None:
-            raise ValueError("save/load run on host files; build the plan "
-                             "with mesh=None")
-        plan, tc = self.plan, self.plan.tc
-        self._uid += 1
-        with open(os.path.join(path, PERSIST_MANIFEST)) as f:
-            manifest = json.load(f)
-        entries = manifest["leaves"]
-        if manifest.get("format") != PERSIST_FORMAT:
-            raise ValueError(f"unknown persistent format "
-                             f"{manifest.get('format')!r} at {path}")
-        if len(entries) != len(plan.routes):
-            raise ValueError(
-                f"{path} holds {len(entries)} leaves; this plan expects "
-                f"{len(plan.routes)} — rebuild the plan for the structure")
-        wire_ver = get_backend("wire-verify")
-        stats = TransferStats(chunk_wire_bytes=[], chunk_ok=[],
-                              raw_passthrough_bytes=0.0,
-                              n_elements=plan.stream_len)
-        leaves = []
-        for i, (r, meta) in enumerate(zip(plan.routes, entries)):
-            if (meta["key"] != r.key or meta["route"] != r.route
-                    or tuple(meta["shape"]) != r.shape
-                    or meta["dtype"] != r.dtype):
-                raise ValueError(
-                    f"leaf {i} ({meta['key']!r}) does not match the plan "
-                    f"route {r.key!r}; structure drifted since save")
-            try:
-                blob = self._read_verified(os.path.join(path, meta["file"]),
-                                           meta, i, stats)
-            except WireIntegrityError:
-                # Publish the partial accounting (verify failures, re-fetch
-                # bytes burned on the abandoned candidate) before bubbling up
-                # to the fallback policy in distributed/checkpoint.py.
-                stats.leaf_ok[r.key] = False
-                self.last_stats = stats
-                self._account()
-                raise
-            sz = meta["sz_bytes"]
-            if r.route == "splitzip":
-                ct = self._persist_comp(blob[:sz], r, tc.codebook.fmt,
-                                        r.dtype)
-                leaves.append(jnp.asarray(wire_ver.decode(ct)))
-                stats.leaf_wire_bytes[r.key] = float(sz)
-                stats.leaf_ok[r.key] = True
-            elif r.route == "fp32_hilo":
-                ct = self._persist_comp(blob[:sz], r, tc.codebook.fmt,
-                                        "bfloat16")
-                hi = jax.lax.bitcast_convert_type(
-                    jnp.asarray(wire_ver.decode(ct)), jnp.uint16)
-                lo = np.frombuffer(blob[sz:], np.uint16).reshape(r.shape)
-                u = ((hi.astype(jnp.uint32) << 16)
-                     | jnp.asarray(lo).astype(jnp.uint32))
-                leaves.append(jax.lax.bitcast_convert_type(u, jnp.float32))
-                stats.leaf_wire_bytes[r.key] = float(sz)
-                stats.leaf_ok[r.key] = True
-                stats.fp32_lo_wire_bytes += float(len(blob) - sz)
-            elif r.route == "fp8":
-                ct = self._persist_comp(blob[:sz], r, plan.fp8_codebook.fmt,
-                                        r.dtype)
-                leaves.append(jnp.asarray(wire_ver.decode(ct)))
-                stats.fp8_wire_bytes += float(sz)
-                stats.leaf_ok[r.key] = True
-            else:
-                arr = np.frombuffer(blob, dtype=jnp.dtype(r.dtype))
-                leaves.append(jnp.asarray(arr.reshape(r.shape)))
-                stats.raw_passthrough_bytes += float(len(blob))
-        tree = jax.tree_util.tree_unflatten(plan.treedef, leaves)
-        self.last_stats = stats
-        self._account()
-        return tree, manifest.get("extra", {})
-
-    @staticmethod
-    def _persist_comp(payload: bytes, r, fmt: str, dtype: str) -> WireCompressed:
-        stats = WireStats(n_elements=r.n_elements, n_escapes=0,
-                          payload_bytes=len(payload),
-                          raw_bytes=int(r.raw_bytes))
-        return WireCompressed(payload=payload, shape=r.shape, dtype=dtype,
-                              fmt=fmt, stats=stats)
-
-    def _read_verified(self, fpath: str, meta: Dict, ci: int,
-                       stats: TransferStats) -> bytes:
-        """One leaf file off disk, Fletcher-verified against the manifest,
-        optionally through the session's :class:`FaultChannel` (so injected
-        wire faults exercise the re-fetch path on CPU).  Re-reads follow the
-        plan's capacity-schedule length — ``retry_doublings + 1`` re-fetches
-        — then raise :class:`WireIntegrityError` with the leaf index."""
-        budget = self.plan.tc.retry_doublings + 2
-        for attempt in range(budget):
-            with open(fpath, "rb") as f:
-                blob = f.read()
-            intact = True
-            if self._channel is not None:
-                frame = self._channel.ship(
-                    jnp.asarray(np.frombuffer(blob, np.uint8)),
-                    self._uid, ci, attempt)
-                payload, intact = self._channel.deliver(frame)
-                stats.fault_delay_s += frame.delay_s
-                blob = (np.asarray(payload).tobytes()
-                        if payload is not None else b"")
-            if intact and fletcher32(np.frombuffer(blob, np.uint8)) == \
-                    meta["checksum"]:
-                return blob
-            stats.verify_failures += 1
-            if attempt + 1 < budget:
-                stats.refetches += 1
-                stats.refetch_wire_bytes += float(len(blob))
-        raise WireIntegrityError((ci,))
-
-    # -- collective executor (compressed ring all-reduce) --------------------
-    def ring_reduce(self, stacked, *, axis: str = "pod", mean: bool = True,
-                    ratio: Optional[float] = None, check: bool = True):
-        """Rotating-ring compressed all-reduce over ``axis``: each
-        participant's pod-partial contribution circles the ring as a
-        compressed stream ((n_pod - 1) hops, decode + fp32 accumulate per
-        hop), exactly grad_compress's exchange but planned, routed, and
-        accounted here.  Input leaves carry a leading ``axis`` dimension
-        (sharded ``P(axis)``); output leaves drop it and are replicated.
-
-        In-graph execution cannot branch on escape overflow, so every hop
-        also emits an ``ok`` flag; a leaf whose compressed hops overflowed
-        anywhere on the ring is re-run on a raw (bit-pinned) ring — the one
-        overflow story: detected off-graph, healed by the raw fallback,
-        recorded in ``last_stats.leaf_ok``.  In-graph wire bytes live in
-        the lowered HLO (``lower_hlo``); for host-side reports
-        ``last_stats`` carries the plan's analytic estimate via
-        :meth:`TransferPlan.collective_wire_bytes` — pass ``ratio`` (a
-        calibrated profile's codec ratio) to price the compressed hops,
-        else they're counted raw."""
-        plan = self.plan
-        if plan.mesh is None or axis not in plan.mesh.shape:
-            raise ValueError(f"ring_reduce needs a mesh plan with a "
-                             f"{axis!r} axis")
-        if check:
-            self._check_structure(stacked)
-        self._uid += 1
-        n_pod = plan.mesh.shape[axis]
-        expected = n_pod * (n_pod - 1)      # ok hops per leaf, psum'd
-        leaves = jax.tree_util.tree_leaves(stacked)
-        fn = self._ring_fns.get(frozenset())
-        if fn is None:
-            fn = self._ring_fns.setdefault(
-                frozenset(), self._build_ring_fn(axis, mean, frozenset()))
-        out, oks = fn(*leaves)
-        failed = frozenset(j for j, ok in enumerate(oks)
-                           if int(ok) != expected)
-        if failed:
-            fb = self._ring_fns.get(failed)
-            if fb is None:
-                fb = self._ring_fns.setdefault(
-                    failed, self._build_ring_fn(axis, mean, failed))
-            out, _ = fb(*leaves)
-        self.last_stats = self._ring_stats(axis, ratio, failed)
-        self._account()
-        return jax.tree_util.tree_unflatten(plan.treedef, out)
-
-    def _ring_participant_routes(self, axis: str):
-        """Per-participant routes: the plan was built over ``axis``-stacked
-        leaves, so re-resolve on the stripped shapes (the per-hop payloads)
-        — this is where ``tc.min_compress_elems`` bites."""
-        if self._ring_routes is None:
-            n = self.plan.mesh.shape[axis]
-            local = []
-            for r in self.plan.routes:
-                if not r.shape or r.shape[0] % n:
-                    raise ValueError(
-                        f"ring_reduce leaf {r.key!r} has no leading "
-                        f"{axis}-divisible dimension (shape {r.shape})")
-                local.append(jax.ShapeDtypeStruct(
-                    (r.shape[0] // n,) + r.shape[1:], jnp.dtype(r.dtype)))
-            lp = TransferPlan.build(
-                jax.tree_util.tree_unflatten(self.plan.treedef, local),
-                self.plan.tc, granularity="tensor")
-            self._ring_routes = lp.routes
-        return self._ring_routes
-
-    def _build_ring_fn(self, axis: str, mean: bool, force_raw: frozenset):
-        from jax.sharding import PartitionSpec as P
-        plan, tc = self.plan, self.plan.tc
-        n_pod = plan.mesh.shape[axis]
-        routes = self._ring_participant_routes(axis)
-        for r in routes:
-            if r.route == "fp32_hilo":
-                raise ValueError(
-                    "ring_reduce does not take the fp32 hi/lo route (build "
-                    "the gradient plan with compress_fp32=False); fp32 "
-                    "leaves ship raw, bit-pinned")
-        perm = [(i, (i + 1) % n_pod) for i in range(n_pod)]
-
-        def ring(x, codebook, cap, compress):
-            # bit-pinned rotate-and-accumulate; encode/decode per hop keeps
-            # only the compressed stream on the wire.  ``ok`` counts hops
-            # whose escape capacity held — the traced flag the host checks.
-            acc = x.astype(jnp.float32)
-            rotating = x
-            ok = jnp.int32(0)
-            for _ in range(n_pod - 1):
-                if compress:
-                    ct = plan.backend.encode(rotating, codebook,
-                                             chunk=tc.chunk, cap=cap,
-                                             layout=tc.layout)
-                    ok = ok + plan.backend.ok(ct).astype(jnp.int32)
-                    moved = jax.tree.map(
-                        lambda s: jax.lax.ppermute(s, axis, perm), ct)
-                    rotating = jnp.asarray(
-                        plan.backend.decode(moved)).reshape(x.shape)
-                else:
-                    ok = ok + 1
-                    w = _WIRE_INT.get(x.dtype.itemsize)
-                    if jnp.issubdtype(x.dtype, jnp.floating) and w is not None:
-                        y = jax.lax.ppermute(
-                            jax.lax.bitcast_convert_type(rotating, w),
-                            axis, perm)
-                        rotating = jax.lax.bitcast_convert_type(y, x.dtype)
-                    else:
-                        rotating = jax.lax.ppermute(rotating, axis, perm)
-                acc = acc + rotating.astype(jnp.float32)
-            return acc, jax.lax.psum(ok, axis)
-
-        def body(*leaves_flat):
-            out, oks = [], []
-            for j, (lf, r) in enumerate(zip(leaves_flat, routes)):
-                x = lf[0]    # local slice of the stacked leaf, leading dim 1
-                if r.route == "splitzip" and j not in force_raw:
-                    total, ok = ring(x, tc.codebook, r.cap, True)
-                elif r.route == "fp8" and j not in force_raw:
-                    total, ok = ring(x, plan.fp8_codebook, r.cap, True)
-                else:
-                    total, ok = ring(x, None, 0, False)
-                if mean:
-                    total = total / n_pod
-                out.append(total.astype(x.dtype))
-                oks.append(ok)
-            return tuple(out), tuple(oks)
-
-        n_leaves = self.plan.treedef.num_leaves
-        specs = lambda s: tuple(s for _ in range(n_leaves))
-        return shard_map(body, mesh=plan.mesh,
-                         in_specs=specs(P(axis)),
-                         out_specs=(specs(P()), specs(P())),
-                         check_vma=False)
-
-    def _ring_stats(self, axis: str, ratio: Optional[float],
-                    failed: frozenset = frozenset()) -> TransferStats:
-        """Analytic per-call accounting for the collective executor (the
-        traced HLO is the ground truth; this is the host-side estimate all
-        consumers report through)."""
-        n_pod = self.plan.mesh.shape[axis]
-        hops = n_pod - 1
-        routes = self._ring_participant_routes(axis)
-        stats = TransferStats(chunk_wire_bytes=[], chunk_ok=[],
-                              raw_passthrough_bytes=0.0,
-                              n_elements=sum(r.n_elements for r in routes
-                                             if r.route != "raw"))
-        rho = ratio if ratio is not None else 1.0
-        for j, r in enumerate(routes):
-            if r.route == "raw":
-                stats.raw_passthrough_bytes += r.raw_bytes * hops
-            elif j in failed:
-                # overflowed: the wasted compressed attempt shipped, then
-                # the raw re-run (charged as a raw re-fetch)
-                stats.leaf_wire_bytes[r.key] = r.raw_bytes / rho * hops
-                stats.leaf_ok[r.key] = False
-                stats.refetches += 1
-                stats.raw_refetches += 1
-                stats.refetch_wire_bytes += r.raw_bytes * hops
-            elif r.route == "fp8":
-                stats.fp8_wire_bytes += r.raw_bytes / rho * hops
-                stats.leaf_ok[r.key] = True
-            else:
-                stats.leaf_wire_bytes[r.key] = r.raw_bytes / rho * hops
-                stats.leaf_ok[r.key] = True
-        return stats
-
-    # -- reshard hop (elastic scaling) ---------------------------------------
-    def reshard(self, tree, dst_shardings, *, check: bool = True,
-                verify: Optional[bool] = None):
-        """One elastic reshard hop: encode every routed leaf to splitzip
-        streams, ship them through this session's wire (integrity framing
-        and re-fetches included when the session carries ``verify=`` /
-        ``faults=``), decode, and place the result on ``dst_shardings``
-        (a pytree of shardings matching ``tree``; see
-        ``distributed/elastic.reshard``).  Bit-exact end to end."""
-        if self.plan.mesh is not None:
-            raise ValueError(
-                "reshard ships host-staged streams (the old mesh may not "
-                "exist anymore); build the plan with mesh=None")
-        self._set_verify(verify)
-        self.send(tree, check=check)
-        out = self.recv()
-        if dst_shardings is not None:
-            out = jax.device_put(out, dst_shardings)
-        return out
 
     # -- internals -----------------------------------------------------------
     def _check_structure(self, cache) -> None:
@@ -1475,8 +1066,8 @@ class TransferSession:
             # are the per-shard views, so segmentation/routing re-resolves
             # here (trace-time only — once per compilation, not per call)
             lp = TransferPlan.build(local, tc, granularity=plan.granularity)
-            perm = lambda x: _permute_leaf(x, "pod", plan.src_pod,
-                                           plan.dst_pod)
+            perm = lambda x: permute_leaf(x, "pod",
+                                          [(plan.src_pod, plan.dst_pod)])
             if lp.granularity == "chunked":
                 out = self._mesh_chunked_body(lp, local, perm)
             else:

@@ -16,7 +16,8 @@ import pytest
 from repro.core import backend as B
 from repro.core import codebook as cbm
 from repro.core import codec as C
-from repro.serving import transfer as T
+from repro.serving.plan import TransferConfig, TransferPlan
+from repro.serving.session import decode_leaves
 from repro.serving.scheduler import (DisaggregatedScheduler, Request,
                                      SchedulerConfig, summarize)
 from repro.core.pipeline import CodecProfile
@@ -161,6 +162,12 @@ def _cache_cb(cache):
     return cbm.calibrate(leaves, k=16)
 
 
+def _chunked_transfer(cache, tc):
+    """One chunked-granularity transfer on a fresh plan: (cache, stats)."""
+    sess = TransferPlan.build(cache, tc, granularity="chunked").session()
+    return sess.transfer(cache), sess.last_stats
+
+
 def _assert_bit_identical(a_tree, b_tree):
     for a, b in zip(jax.tree.leaves(a_tree), jax.tree.leaves(b_tree)):
         w = {2: jnp.uint16, 4: jnp.uint32}[a.dtype.itemsize]
@@ -175,8 +182,8 @@ class TestChunkedPipelinedTransfer:
     def test_chunked_matches_unchunked_bit_exact(self, backend, n_chunks):
         cache = _toy_cache(seed=5)
         cb = _cache_cb(cache)
-        tc = T.TransferConfig(codebook=cb, backend=backend, n_chunks=n_chunks)
-        out, stats = T.transfer_cache_chunked(cache, tc)
+        tc = TransferConfig(codebook=cb, backend=backend, n_chunks=n_chunks)
+        out, stats = _chunked_transfer(cache, tc)
         _assert_bit_identical(cache, out)
         assert len(stats.chunk_wire_bytes) == n_chunks
         assert stats.all_ok
@@ -198,8 +205,8 @@ class TestChunkedPipelinedTransfer:
                  "b": jax.lax.bitcast_convert_type(jnp.asarray(good),
                                                    jnp.bfloat16)}
         cb = cbm.Codebook(fmt="bf16", exponents=tuple(range(118, 134)))
-        tc = T.TransferConfig(codebook=cb, cap=4, n_chunks=4)
-        out, stats = T.transfer_cache_chunked(cache, tc)
+        tc = TransferConfig(codebook=cb, cap=4, n_chunks=4)
+        out, stats = _chunked_transfer(cache, tc)
         _assert_bit_identical(cache, out)
         assert not stats.all_ok and any(stats.chunk_ok)
         for okc, wb in zip(stats.chunk_ok, stats.chunk_wire_bytes):
@@ -238,27 +245,34 @@ class TestChunkedPipelinedTransfer:
         np.testing.assert_array_equal(np.asarray(toks8), np.asarray(toks1))
 
 
+def _tensor_transfer(cache, tc):
+    """One tensor-path transfer stopped at the streams: (comp, raw, stats)."""
+    sess = TransferPlan.build(cache, tc).session()
+    comp, raw = sess.transfer_compressed(cache)
+    return comp, raw, sess.last_stats
+
+
 class TestWireBytesAccounting:
-    """Unit tests for the per-tensor raw-fallback accounting (the former
-    ``* 0 + ok`` hack, now a plain ``jnp.where``)."""
+    """The tensor path's per-leaf wire accounting: compressed bytes where
+    the escape capacity held, raw bytes where the schedule fell back."""
 
     def test_ok_tensor_charged_compressed_bytes(self):
         x = _bf16_input(seed=7, n=4096, specials=False)
         cb = cbm.calibrate(
             [np.asarray(jax.lax.bitcast_convert_type(x, jnp.uint16))], k=16)
-        comp, raw = T.compress_cache({"x": x}, T.TransferConfig(codebook=cb))
-        total = float(T.compressed_wire_bytes(comp, raw))
-        assert total == pytest.approx(
+        comp, _, stats = _tensor_transfer({"x": x}, TransferConfig(codebook=cb))
+        assert stats.wire_bytes == pytest.approx(
             float(C.compressed_bytes(comp["x"])))
 
     def test_overflowed_tensor_charged_raw_bytes(self):
         bits = jnp.full((4096,), np.uint16(7 << 7), dtype=jnp.uint16)
         x = jax.lax.bitcast_convert_type(bits, jnp.bfloat16)
         cb = cbm.Codebook(fmt="bf16", exponents=tuple(range(118, 134)))
-        tc = T.TransferConfig(codebook=cb, cap=4)
-        comp, raw = T.compress_cache({"x": x}, tc)
-        assert not bool(comp["x"].ok)
-        assert float(T.compressed_wire_bytes(comp, raw)) == pytest.approx(
+        tc = TransferConfig(codebook=cb, cap=4)
+        comp, raw, stats = _tensor_transfer({"x": x}, tc)
+        assert "x" not in comp and "x" in raw     # shipped raw
+        assert stats.leaf_ok["x"] is False
+        assert stats.wire_bytes == pytest.approx(
             2.0 * 4096)  # raw bf16 bytes, not the (useless) compressed size
 
     def test_fp32_hi_overflow_falls_back_to_raw_leaf(self):
@@ -284,17 +298,16 @@ class TestWireBytesAccounting:
         assert eng.stats.wire_bytes == pytest.approx(4.0 * 4096)
 
     def test_backend_mismatch_is_corrected_per_object(self):
-        """decompress_cache with the wrong backend= argument still decodes:
+        """decode_leaves with the wrong backend= argument still decodes:
         dispatch follows the compressed object's type, not the argument."""
         x = _bf16_input(seed=8, n=2048, specials=False)
         cb = cbm.calibrate(
             [np.asarray(jax.lax.bitcast_convert_type(x, jnp.uint16))], k=16)
-        comp, raw = T.compress_cache(
-            {"x": x}, T.TransferConfig(codebook=cb, backend="wire"))
-        out = T.decompress_cache(comp, raw, {"x": x})  # default 'xla' arg
+        comp, raw, stats = _tensor_transfer(
+            {"x": x}, TransferConfig(codebook=cb, backend="wire"))
+        out = decode_leaves(comp, raw, {"x": x})  # default 'xla' arg
         _assert_bit_identical({"x": x}, out)
-        assert float(T.compressed_wire_bytes(comp, raw)) == pytest.approx(
-            float(T.compressed_wire_bytes(comp, raw, backend="wire")))
+        assert stats.wire_bytes == pytest.approx(float(len(comp["x"].payload)))
 
     def test_mixed_tree_sums_per_tensor(self):
         good_bits = jnp.full((2048,), np.uint16(120 << 7), dtype=jnp.uint16)
@@ -303,9 +316,9 @@ class TestWireBytesAccounting:
                  "bad": jax.lax.bitcast_convert_type(bad_bits, jnp.bfloat16),
                  "raw32": jnp.zeros((100,), jnp.float32)}
         cb = cbm.Codebook(fmt="bf16", exponents=tuple(range(118, 134)))
-        comp, raw = T.compress_cache(cache, T.TransferConfig(codebook=cb,
-                                                             cap=4))
-        total = float(T.compressed_wire_bytes(comp, raw))
+        comp, _, stats = _tensor_transfer(cache, TransferConfig(codebook=cb,
+                                                               cap=4))
+        total = stats.wire_bytes
         expect = (float(C.compressed_bytes(comp["good"]))  # ok -> compressed
                   + 2.0 * 2048                             # overflow -> raw
                   + 400.0)                                 # fp32 passthrough

@@ -1,8 +1,8 @@
-"""The bulk-data plane (multi_layer_refactor acceptance): checkpoint,
-elastic resharding, resilient training, and gradient compression all ride
-TransferPlan/TransferSession — persistent executor (save/load SZ02 frames +
-manifest), collective executor (compressed ring all-reduce), reshard hop,
-and the consumer seams: corrupt-frame fallback is bit-exact, ring gradients
+"""The bulk-data plane: checkpoint, elastic resharding, resilient training,
+and gradient compression all ride TransferPlan — the checkpoint module's
+persistent executor (save_tree/load_tree: SZ02 frames + manifest), the
+gradient module's compressed ring all-reduce, the reshard hop through a
+TransferSession, and the consumer seams: corrupt-frame fallback is bit-exact, ring gradients
 match jnp.mean bitwise, reshard round-trips a train state, and recovery
 surfaces non-zero TransferStats.refetches under injected faults."""
 
@@ -24,7 +24,7 @@ from repro.distributed import checkpoint as CKPT
 from repro.distributed import elastic as EL
 from repro.distributed.fault_tolerance import FaultConfig, ResilientTrainer
 from repro.serving.faults import FaultPlan
-from repro.serving.plan import TransferConfig, TransferPlan
+from repro.serving.plan import TransferConfig, TransferPlan, TransferStats
 from repro.training import grad_compress as GC
 
 
@@ -55,7 +55,7 @@ def _subprocess_env():
 
 
 # ---------------------------------------------------------------------------
-# persistent executor: session.save / session.load
+# persistent executor: checkpoint.save_tree / checkpoint.load_tree
 # ---------------------------------------------------------------------------
 
 class TestPersistentExecutor:
@@ -63,12 +63,11 @@ class TestPersistentExecutor:
         state = _train_state()
         tc = TransferConfig(codebook=CKPT.CKPT_CODEBOOK, backend="wire",
                             compress_fp32=True, min_compress_elems=64)
-        sess = TransferPlan.build(state, tc).session()
-        sess.save(str(tmp_path / "ck"), state, extra={"note": "x"})
-        tree, extra = sess.load(str(tmp_path / "ck"))
+        plan = TransferPlan.build(state, tc)
+        CKPT.save_tree(str(tmp_path / "ck"), state, plan, extra={"note": "x"})
+        tree, extra, s = CKPT.load_tree(str(tmp_path / "ck"), plan)
         _assert_bit_identical(tree, state)
         assert extra == {"note": "x"}
-        s = sess.last_stats
         # routes: w -> splitzip stream, m -> fp32 hi/lo, tiny -> raw (below
         # min_compress_elems), step -> raw
         assert s.leaf_ok.get("params/w") is True
@@ -88,32 +87,36 @@ class TestPersistentExecutor:
         state = _train_state()
         tc = TransferConfig(codebook=CKPT.CKPT_CODEBOOK, backend="wire",
                             compress_fp32=True)
-        sess = TransferPlan.build(state, tc).session()
-        path = sess.save(str(tmp_path / "ck"), state)
+        plan = TransferPlan.build(state, tc)
+        path = str(tmp_path / "ck")
+        CKPT.save_tree(path, state, plan)
         fname = max((f for f in os.listdir(path) if f.endswith(".szc")),
                     key=lambda f: os.path.getsize(os.path.join(path, f)))
         fpath = os.path.join(path, fname)
         blob = bytearray(open(fpath, "rb").read())
         blob[len(blob) // 2] ^= 0x55
         open(fpath, "wb").write(bytes(blob))
+        stats = TransferStats(chunk_wire_bytes=[], chunk_ok=[],
+                              raw_passthrough_bytes=0.0, n_elements=0)
         with pytest.raises(WireIntegrityError):
-            sess.load(path)
+            CKPT.load_tree(path, plan, stats=stats)
         # the abandoned load still accounts: the fallback policy upstream
-        # (distributed/checkpoint.py) aggregates these
-        assert sess.last_stats.verify_failures > 0
-        assert False in sess.last_stats.leaf_ok.values()
+        # (Checkpointer.restore) aggregates these
+        assert stats.verify_failures > 0
+        assert False in stats.leaf_ok.values()
 
     def test_injected_wire_faults_heal_via_refetch(self, tmp_path):
         state = _train_state()
         tc = TransferConfig(codebook=CKPT.CKPT_CODEBOOK, backend="wire",
                             compress_fp32=True)
-        sess = TransferPlan.build(state, tc).session(
+        plan = TransferPlan.build(state, tc)
+        CKPT.save_tree(str(tmp_path / "ck"), state, plan)
+        tree, _, s = CKPT.load_tree(
+            str(tmp_path / "ck"), plan,
             faults=FaultPlan(corrupt_chunks=(0,), persistent_attempts=1))
-        sess.save(str(tmp_path / "ck"), state)
-        tree, _ = sess.load(str(tmp_path / "ck"))
         _assert_bit_identical(tree, state)
-        assert sess.last_stats.refetches > 0
-        assert sess.last_stats.faults_injected > 0
+        assert s.refetches > 0
+        assert s.faults_injected > 0
 
 
 # ---------------------------------------------------------------------------

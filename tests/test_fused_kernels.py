@@ -23,7 +23,7 @@ from repro.core import backend as B
 from repro.core import codebook as cbm
 from repro.core import codec as C
 from repro.kernels import ops, splitzip_decode, splitzip_encode, twostage
-from repro.serving import transfer as T
+from repro.serving.plan import TransferConfig, TransferPlan
 
 CODEBOOK = tuple(range(118, 134))
 BF16_CB = cbm.Codebook(fmt="bf16", exponents=CODEBOOK)
@@ -245,7 +245,7 @@ class TestAutoBackend:
 
     def test_auto_roundtrip_through_transfer_config(self):
         x = _bf16_specials_input(seed=9, n=4096)
-        tc = T.TransferConfig(codebook=BF16_CB, backend="auto")
+        tc = TransferConfig(codebook=BF16_CB, backend="auto")
         be = tc.get_backend()
         np.testing.assert_array_equal(
             np.asarray(C.to_bits(x, "bf16")),
@@ -264,6 +264,12 @@ def _toy_cache(seed=0):
             "ssm": jnp.asarray(rng.normal(size=(4, 8, 16)), jnp.float32)}
 
 
+def _chunked_transfer(cache, tc):
+    """One chunked-granularity transfer on a fresh plan: (cache, stats)."""
+    sess = TransferPlan.build(cache, tc, granularity="chunked").session()
+    return sess.transfer(cache), sess.last_stats
+
+
 def _assert_bit_identical(a_tree, b_tree):
     for a, b in zip(jax.tree.leaves(a_tree), jax.tree.leaves(b_tree)):
         w = {2: jnp.uint16, 4: jnp.uint32}[a.dtype.itemsize]
@@ -279,12 +285,12 @@ class TestChunkedEngineWithFusedBackend:
         leaves = [np.asarray(jax.lax.bitcast_convert_type(x, jnp.uint16)).ravel()
                   for x in jax.tree.leaves(cache) if x.dtype == jnp.bfloat16]
         cb = cbm.calibrate(leaves, k=16)
-        out_p, st_p = T.transfer_cache_chunked(
-            cache, T.TransferConfig(codebook=cb, backend="pallas",
-                                    n_chunks=n_chunks))
-        out_x, st_x = T.transfer_cache_chunked(
-            cache, T.TransferConfig(codebook=cb, backend="xla",
-                                    n_chunks=n_chunks))
+        out_p, st_p = _chunked_transfer(
+            cache, TransferConfig(codebook=cb, backend="pallas",
+                                  n_chunks=n_chunks))
+        out_x, st_x = _chunked_transfer(
+            cache, TransferConfig(codebook=cb, backend="xla",
+                                  n_chunks=n_chunks))
         _assert_bit_identical(cache, out_p)
         _assert_bit_identical(out_x, out_p)
         assert st_p.chunk_wire_bytes == st_x.chunk_wire_bytes
@@ -302,9 +308,9 @@ class TestChunkedEngineWithFusedBackend:
         bits[esc] = np.uint16(7 << 7)
         cache = {"a": jax.lax.bitcast_convert_type(jnp.asarray(bits),
                                                    jnp.bfloat16)}
-        tc = T.TransferConfig(codebook=BF16_CB, cap=32, n_chunks=4,
-                              backend="pallas")
-        out, stats = T.transfer_cache_chunked(cache, tc)
+        tc = TransferConfig(codebook=BF16_CB, cap=32, n_chunks=4,
+                            backend="pallas")
+        out, stats = _chunked_transfer(cache, tc)
         _assert_bit_identical(cache, out)
         assert stats.all_ok
         assert stats.n_retries >= 1
@@ -325,9 +331,9 @@ class TestChunkedEngineWithFusedBackend:
         bits[:200] = np.uint16(7 << 7)
         cache = {"a": jax.lax.bitcast_convert_type(jnp.asarray(bits),
                                                    jnp.bfloat16)}
-        tc = T.TransferConfig(codebook=BF16_CB, layout="global",
-                              backend="pallas", n_chunks=2)
-        out, stats = T.transfer_cache_chunked(cache, tc)
+        tc = TransferConfig(codebook=BF16_CB, layout="global",
+                            backend="pallas", n_chunks=2)
+        out, stats = _chunked_transfer(cache, tc)
         _assert_bit_identical(cache, out)
         assert stats.all_ok
         assert stats.n_retries == 1
@@ -341,9 +347,9 @@ class TestChunkedEngineWithFusedBackend:
                                                  ).astype(np.uint16)
         cache = {"a": jax.lax.bitcast_convert_type(jnp.asarray(bad),
                                                    jnp.bfloat16)}
-        tc = T.TransferConfig(codebook=BF16_CB, cap=4, n_chunks=2,
-                              backend="pallas")
-        out, stats = T.transfer_cache_chunked(cache, tc)
+        tc = TransferConfig(codebook=BF16_CB, cap=4, n_chunks=2,
+                            backend="pallas")
+        out, stats = _chunked_transfer(cache, tc)
         _assert_bit_identical(cache, out)
         assert not stats.all_ok
         assert stats.n_retries == len([ok for ok in stats.chunk_ok if not ok])

@@ -14,9 +14,10 @@ from repro.core import codebook as cbm
 from repro.core.pipeline import (CodecProfile, additive_transfer_time,
                                  native_transfer_time, pipelined_transfer_time)
 from repro.models import model as M
-from repro.serving import transfer as T
 from repro.serving.engine import DisaggregatedEngine
-from repro.serving.plan import TransferConfig, TransferPlan
+from repro.serving.plan import (TransferConfig, TransferPlan, raw_wire_bytes,
+                                transfer_report)
+from repro.serving.session import decode_leaves
 from repro.serving.scheduler import DisaggregatedScheduler, Request, SchedulerConfig, summarize
 
 SHAPE = ShapeConfig("smoke", seq_len=24, global_batch=2, kind="train")
@@ -88,19 +89,19 @@ def test_fp32_state_compression_bit_exact():
     cache = {"ssm": jnp.asarray(rng.normal(size=(4, 2, 8, 16, 32)), jnp.float32),
              "k": jnp.asarray(rng.normal(size=(4, 2, 64, 2, 16)), jnp.bfloat16)}
     cb = cbm.Codebook(fmt="bf16", exponents=tuple(range(115, 131)))
-    tc = T.TransferConfig(codebook=cb, layout="global", compress_fp32=True,
-                          global_budget=0.05)
-    comp, raw = T.compress_cache(cache, tc)
+    tc = TransferConfig(codebook=cb, layout="global", compress_fp32=True,
+                        global_budget=0.05)
+    sess = TransferPlan.build(cache, tc).session()
+    comp, raw = sess.transfer_compressed(cache)
     assert "ssm#hi" in comp and "ssm#lo" in raw   # split happened
-    out = T.decompress_cache(comp, raw, cache)
+    out = decode_leaves(comp, raw, cache)
     for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(out)):
         w = jnp.uint32 if a.dtype == jnp.float32 else jnp.uint16
         np.testing.assert_array_equal(
             np.asarray(jax.lax.bitcast_convert_type(a, w)),
             np.asarray(jax.lax.bitcast_convert_type(b, w)))
     # wire accounting: fp32 leaf now ships < raw bytes
-    wire = float(T.compressed_wire_bytes(comp, raw))
-    assert wire < T.raw_wire_bytes(cache)
+    assert sess.last_stats.wire_bytes < raw_wire_bytes(cache)
 
 
 def test_cache_roundtrip_bit_exact_all_leaves():
@@ -110,9 +111,8 @@ def test_cache_roundtrip_bit_exact_all_leaves():
               if k != "labels"}
     _, state = M.prefill(params, prompt, cfg, max_seq=16)
     cb = _kv_codebook(state.cache)
-    tc = T.TransferConfig(codebook=cb)
-    comp, raw = T.compress_cache(state.cache, tc)
-    back = T.decompress_cache(comp, raw, state.cache)
+    tc = TransferConfig(codebook=cb)
+    back = TransferPlan.build(state.cache, tc).session().transfer(state.cache)
     for a, b in zip(jax.tree.leaves(state.cache), jax.tree.leaves(back)):
         if a.dtype == jnp.bfloat16:
             np.testing.assert_array_equal(
@@ -129,9 +129,10 @@ def test_wire_bytes_close_to_four_thirds():
               if k != "labels"}
     _, state = M.prefill(params, prompt, cfg, max_seq=16)
     cb = _kv_codebook(state.cache)
-    comp, raw = T.compress_cache(state.cache, T.TransferConfig(codebook=cb))
-    wire = float(T.compressed_wire_bytes(comp, raw))
-    rawb = T.raw_wire_bytes(state.cache)
+    sess = TransferPlan.build(state.cache, TransferConfig(codebook=cb)).session()
+    sess.transfer(state.cache)
+    wire = sess.last_stats.wire_bytes
+    rawb = raw_wire_bytes(state.cache)
     assert 1.2 < rawb / wire <= 4 / 3 + 1e-6
 
 
@@ -142,13 +143,13 @@ def test_transfer_report_matches_paper_structure():
     raw = 1.75e9
     # RoCE 4x200G regime: transfer dominates, codec visible but minor
     p_fast = CodecProfile(g_enc=613.3e9, g_dec=2181.8e9, ratio=1.324, link_bw=87.5e9)
-    rep = T.transfer_report(raw, raw / 1.324, p_fast)
+    rep = transfer_report(raw, raw / 1.324, p_fast)
     assert rep.speedup > 1.0
     assert rep.t_transfer / rep.t_splitzip > 0.75
     assert (rep.t_encode + rep.t_decode) / rep.t_splitzip < 0.25
     # 100GbE-class inter-cluster regime: codec fully amortized, speedup ≈ ρ
     p_slow = CodecProfile(g_enc=613.3e9, g_dec=2181.8e9, ratio=1.324, link_bw=12.5e9)
-    rep2 = T.transfer_report(raw, raw / 1.324, p_slow)
+    rep2 = transfer_report(raw, raw / 1.324, p_slow)
     assert rep2.speedup > 1.25
     assert rep2.t_transfer / rep2.t_splitzip > 0.95
 

@@ -18,9 +18,9 @@ from repro.core import codebook as cbm
 from repro.core import codec as C
 from repro.core.pipeline import (CodecProfile, pipeline_makespan,
                                  pipelined_transfer_time)
-from repro.serving import transfer as T
 from repro.serving.plan import (FP8_DEFAULT_CODEBOOK, TransferConfig,
-                                TransferPlan)
+                                TransferPlan, transfer_report)
+from repro.serving.session import decode_leaves
 
 BF16_CB = cbm.Codebook(fmt="bf16", exponents=tuple(range(118, 134)))
 
@@ -44,6 +44,12 @@ def _cache_cb(cache):
     leaves = [np.asarray(jax.lax.bitcast_convert_type(x, jnp.uint16)).ravel()
               for x in jax.tree.leaves(cache) if x.dtype == jnp.bfloat16]
     return cbm.calibrate(leaves, k=16)
+
+
+def _chunked_transfer(cache, tc):
+    """One chunked-granularity transfer on a fresh plan: (cache, stats)."""
+    sess = TransferPlan.build(cache, tc, granularity="chunked").session()
+    return sess.transfer(cache), sess.last_stats
 
 
 def _assert_bit_identical(a_tree, b_tree):
@@ -149,7 +155,7 @@ class TestCapacitySchedule:
                                                    jnp.bfloat16)}
         tc = TransferConfig(codebook=BF16_CB, cap=4, n_chunks=2,
                             retry_doublings=0)
-        out, st = T.transfer_cache_chunked(cache, tc)
+        out, st = _chunked_transfer(cache, tc)
         _assert_bit_identical(cache, out)
         assert not st.all_ok and st.n_retry_steps == 0  # fail-fast to raw
 
@@ -215,24 +221,32 @@ class TestExecutionParity:
         assert sess.calls == 2
         assert sess.total_wire_bytes == pytest.approx(2 * one)
 
-    def test_shim_matches_session(self):
-        cache = _mixed_cache(seed=4)
+    def test_chunk_bytes_plus_passthrough_equal_wire_bytes(self):
+        """With no fp32 or fp8 sidecars, a chunked transfer's wire bytes are
+        its pipeline chunks plus the raw passthrough, nothing else."""
+        full = _mixed_cache(seed=4)
+        cache = {k: full[k] for k in ("k", "v", "pos")}
         tc = TransferConfig(codebook=_cache_cb(cache), n_chunks=4)
-        out_shim, st_shim = T.transfer_cache_chunked(cache, tc)
-        sess = TransferPlan.build(cache, tc,
-                                  granularity="chunked").session()
-        out_sess = sess.transfer(cache)
-        _assert_bit_identical(out_shim, out_sess)
-        assert st_shim.chunk_wire_bytes == sess.last_stats.chunk_wire_bytes
+        out, st = _chunked_transfer(cache, tc)
+        _assert_bit_identical(cache, out)
+        assert len(st.chunk_wire_bytes) == 4 and st.all_ok
+        assert st.raw_passthrough_bytes == cache["pos"].size * 4
+        assert sum(st.chunk_wire_bytes) + st.raw_passthrough_bytes == \
+            pytest.approx(st.wire_bytes)
 
     def test_compress_cache_shim_roundtrips_fp8(self):
+        """A tensor-path session round trip over the fp8 + fp32 tree: the
+        fp8 leaf rides the e5m2 repack route, the fp32 leaf the hi/lo
+        split, and the decoded tree is bit-exact."""
         cache = _mixed_cache(seed=5)
         tc = TransferConfig(codebook=_cache_cb(cache), compress_fp32=True)
-        comp, raw = T.compress_cache(cache, tc)
+        sess = TransferPlan.build(cache, tc).session()
+        comp, raw = sess.transfer_compressed(cache)
         assert "act8" in comp              # fp8 e5m2 repack route
         assert "ssm#hi" in comp and "ssm#lo" in raw
-        out = T.decompress_cache(comp, raw, cache)
+        out = decode_leaves(comp, raw, cache)
         _assert_bit_identical(cache, out)
+        _assert_bit_identical(cache, sess.transfer(cache))
 
 
 class TestGeometricRetry:
@@ -250,7 +264,7 @@ class TestGeometricRetry:
         # all fail; global 5% pool (256 for a 4096 segment) absorbs them
         bits[:40] = np.uint16(7 << 7)
         tc = TransferConfig(codebook=BF16_CB, cap=4, chunk=1024, n_chunks=2)
-        out, st = T.transfer_cache_chunked(self._stream_cache(bits), tc)
+        out, st = _chunked_transfer(self._stream_cache(bits), tc)
         _assert_bit_identical(self._stream_cache(bits), out)
         assert st.chunk_ok[0] and st.all_ok
         assert st.chunk_retried[0] is True
@@ -263,7 +277,7 @@ class TestGeometricRetry:
         bits = rng.integers(0, 1 << 16, 4096).astype(np.uint16)  # all-escape
         tc = TransferConfig(codebook=BF16_CB, cap=4, n_chunks=2)
         cache = self._stream_cache(bits)
-        out, st = T.transfer_cache_chunked(cache, tc)
+        out, st = _chunked_transfer(cache, tc)
         _assert_bit_identical(cache, out)
         assert not st.all_ok
         # every failing chunk walked the whole schedule before shipping raw
@@ -348,16 +362,16 @@ class TestPlanAwarePipelineModel:
         plan = TransferPlan.build(cache, tc)
         p = CodecProfile(g_enc=600e9, g_dec=2000e9, ratio=1.33, link_bw=50e9)
         raw = plan.raw_bytes()
-        one = T.transfer_report(raw, raw / 1.33, p, n_chunks=4, plan=plan)
-        many = T.transfer_report(8 * raw, 8 * raw / 1.33, p, n_chunks=4,
-                                 plan=plan)
+        one = transfer_report(raw, raw / 1.33, p, n_chunks=4, plan=plan)
+        many = transfer_report(8 * raw, 8 * raw / 1.33, p, n_chunks=4,
+                               plan=plan)
         assert many.speedup == pytest.approx(one.speedup)
         assert many.t_splitzip == pytest.approx(8 * one.t_splitzip)
         # all-raw fallback (wire == raw) must cost more than compressed wire
-        degraded = T.transfer_report(raw, raw, p, n_chunks=4, plan=plan)
+        degraded = transfer_report(raw, raw, p, n_chunks=4, plan=plan)
         assert degraded.t_splitzip > one.t_splitzip
         # pipeline overlap: still cheaper than the additive accounting
-        additive = T.transfer_report(raw, raw / 1.33, p, n_chunks=1)
+        additive = transfer_report(raw, raw / 1.33, p, n_chunks=1)
         assert one.t_splitzip < additive.t_splitzip
 
 
